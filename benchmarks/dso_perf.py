@@ -88,6 +88,13 @@
 
 Legacy paper-comparison section (pointwise vs tile) runs with ``--full``.
 
+Sections 9 and 10 run a host-device mesh in a child process.  They refuse
+to start unless this process runs on the CPU (a child cannot share a chip
+this process holds), and a failed child raises, so the run exits non-zero.
+Their timings are CPU timings, never chip numbers.
+The persistent compilation cache is on (``JAX_COMPILATION_CACHE_DIR``, else
+``<repo>/.jax_cache``).
+
     PYTHONPATH=src python -m benchmarks.dso_perf [--full] [--sparse]
 """
 
@@ -150,6 +157,28 @@ def append_history(record: dict, *, path: str | None = None,
     with open(path, "a") as f:
         f.write(json.dumps(entry) + "\n")
     return entry
+
+
+def _require_cpu_parent(phase: str) -> None:
+    """Phases that build a host-device mesh run it in a child process
+    (``XLA_FLAGS`` must be set before JAX starts).  A chip belongs to one
+    process, and this one holds it once it has touched JAX on a TPU, so
+    such a child could only fail or hang: refuse to start instead."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{phase} runs a host-device mesh in a child process, which "
+            f"cannot share this process's {backend!r} device; run it with "
+            f"JAX_PLATFORMS=cpu")
+
+
+def _child_failed(phase: str, proc) -> RuntimeError:
+    return RuntimeError(
+        f"{phase}: child process failed (exit {proc.returncode})\n"
+        f"--- stdout tail ---\n{proc.stdout[-2000:]}\n"
+        f"--- stderr tail ---\n{proc.stderr[-2000:]}")
 
 
 def _run(fn, epochs, **kw):
@@ -887,19 +916,18 @@ def bench_overlap(m=64, d=1024, density=0.05, p=8, epochs=24, repeats=7,
     """
     import subprocess
 
+    _require_cpu_parent("dso_overlap")
     spec = dict(m=m, d=d, density=density, impl=impl, epochs=epochs,
                 repeats=repeats)
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(
         [sys.executable, "-c", _OVERLAP_SCRIPT, json.dumps(spec)],
         capture_output=True, text=True, timeout=timeout_s, env=env)
     if proc.returncode != 0:
-        return {"gate": {"metric": "overlapped pipeline", "pass": False,
-                         "error": "subprocess failed"},
-                "stdout_tail": proc.stdout[-2000:],
-                "stderr_tail": proc.stderr[-2000:]}
+        raise _child_failed("dso_overlap", proc)
     line = next(ln for ln in proc.stdout.splitlines()
                 if ln.startswith("OVERLAP_JSON "))
     rec = json.loads(line[len("OVERLAP_JSON "):])
@@ -956,18 +984,17 @@ def bench_chaos(timeout_s=900):
     import subprocess
     import tempfile
 
+    _require_cpu_parent("dso_chaos")
     script = os.path.join(REPO, "examples", "elastic_dso.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     with tempfile.TemporaryDirectory() as td:
         ledger_path = os.path.join(td, "ledger.json")
         proc = subprocess.run(
             [sys.executable, script, "--chaos", "--ledger-out", ledger_path],
-            capture_output=True, text=True, timeout=timeout_s, cwd=td)
-        ok = proc.returncode == 0 and "CHAOS_OK" in proc.stdout
-        if not ok:
-            return {"gate": {"metric": "chaos gauntlet", "pass": False,
-                             "error": "example failed"},
-                    "stdout_tail": proc.stdout[-2000:],
-                    "stderr_tail": proc.stderr[-2000:]}
+            capture_output=True, text=True, timeout=timeout_s, cwd=td,
+            env=env)
+        if proc.returncode != 0 or "CHAOS_OK" not in proc.stdout:
+            raise _child_failed("dso_chaos", proc)
         with open(ledger_path) as f:
             rec = json.load(f)
     ff, pr = rec["fault_free_s_per_epoch"], rec["post_replan_s_per_epoch"]
@@ -1045,6 +1072,8 @@ def main(argv=None):
                          "evaluated")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.join(REPO, ".jax_cache"))
     if args.smoke:
         out = {
             "mode": "smoke — no-gate dry run, nothing written",

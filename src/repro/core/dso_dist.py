@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.saddle import Problem, duality_gap, primal_objective
 from repro.engine.backends import get_backend
@@ -266,14 +265,14 @@ def _epoch_shardmap(mesh: Mesh, p: int, db: int, loss_name: str,
     out_specs = (P("dso"), P("dso"), P("dso"), P("dso"))
     if telemetry:
         out_specs = out_specs + (P(None, None, "dso"),)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         epochs_body, mesh=mesh,
         in_specs=(P("dso"),) * (n_data + 4) + (P(None),)
         + (P("dso"),) * 4 + (P(), P(), P(), P(), P(), P()),
         out_specs=out_specs,
-        # pallas_call has no shard_map replication rule; the outputs are
-        # all "dso"-sharded anyway, so the check adds nothing here
-        check_rep="pallas" not in backend_name,
+        # pallas_call has no varying-manual-axes rule; the outputs are all
+        # "dso"-sharded anyway, so the check adds nothing here
+        check_vma="pallas" not in backend_name,
     )
     donate = tuple(range(n_data + 5, n_data + 9))   # w, gw, alpha, ga
     return jax.jit(sharded, donate_argnums=donate)
@@ -425,12 +424,12 @@ def _epoch_shardmap_p2p(mesh: Mesh, p: int, db: int, loss_name: str,
     out_specs = (P("dso"), P("dso"), P("dso"), P("dso"))
     if telemetry:
         out_specs = out_specs + (P(None, None, "dso"),)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         epochs_body, mesh=mesh,
         in_specs=(P("dso"),) * (n_data + 4) + (P(None),)
         + (P("dso"),) * 4 + (P(), P(), P(), P(), P(), P()),
         out_specs=out_specs,
-        check_rep="pallas" not in backend_name,
+        check_vma="pallas" not in backend_name,
     )
     donate = tuple(range(n_data + 5, n_data + 9))   # w, gw, alpha, ga
     return jax.jit(sharded, donate_argnums=donate)

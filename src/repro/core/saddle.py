@@ -22,6 +22,13 @@ from repro.core.regularizers import Regularizer, get_regularizer
 Array = jax.Array
 
 
+def matvec(A: Array, x: Array) -> Array:
+    """``A @ x`` at full float32 precision.  At the default precision a
+    TPU may run an f32 dot as reduced-precision (bf16) passes; the
+    objectives and the dense tile step need the float32 result."""
+    return jnp.matmul(A, x, precision=jax.lax.Precision.HIGHEST)
+
+
 class Problem(NamedTuple):
     """A regularized-risk instance, stored block-dense.
 
@@ -70,7 +77,7 @@ def make_problem(X, y, lam: float, loss: str = "hinge", reg: str = "l2") -> Prob
 
 def primal_objective(prob: Problem, w: Array) -> Array:
     """P(w) of Eq. (1)."""
-    u = prob.X @ w
+    u = matvec(prob.X, w)
     risk = jnp.mean(prob.loss.value(u, prob.y))
     return prob.lam * jnp.sum(prob.reg.value(w)) + risk
 
@@ -79,7 +86,8 @@ def saddle_objective(prob: Problem, w: Array, alpha: Array) -> Array:
     """f(w, alpha) of Sec. 2."""
     m = prob.m
     reg = prob.lam * jnp.sum(prob.reg.value(w))
-    coupling = -jnp.dot(alpha, prob.X @ w) / m
+    coupling = -jnp.dot(alpha, matvec(prob.X, w),
+                        precision=jax.lax.Precision.HIGHEST) / m
     dual_payoff = jnp.sum(prob.loss.neg_conjugate(alpha, prob.y)) / m
     return reg + coupling + dual_payoff
 
@@ -87,7 +95,7 @@ def saddle_objective(prob: Problem, w: Array, alpha: Array) -> Array:
 def dual_objective(prob: Problem, alpha: Array) -> Array:
     """D(alpha) = min_w f(w, alpha), closed form via the separable phi."""
     m = prob.m
-    c = (prob.X.T @ alpha) / m  # (d,)
+    c = matvec(prob.X.T, alpha) / m  # (d,)
     wmin = jnp.sum(prob.reg.conjugate_min(c, prob.lam))
     dual_payoff = jnp.sum(prob.loss.neg_conjugate(alpha, prob.y)) / m
     return wmin + dual_payoff
@@ -102,7 +110,7 @@ def argmin_w(prob: Problem, alpha: Array) -> Array:
     """Closed-form minimizer of f(., alpha) for the L2 regularizer."""
     if prob.reg_name != "l2":
         raise ValueError("closed-form argmin_w only for l2")
-    return (prob.X.T @ alpha) / (2.0 * prob.lam * prob.m)
+    return matvec(prob.X.T, alpha) / (2.0 * prob.lam * prob.m)
 
 
 def project_w(prob: Problem, w: Array) -> Array:
@@ -146,8 +154,8 @@ def grads_tile(prob: Problem, X_tile: Array, y_tile: Array, w_blk: Array,
     """
     m = prob.m
     g_w = (prob.lam * prob.reg.grad(w_blk) * tile_col_nnz / col_nnz_blk
-           - (X_tile.T @ alpha_blk) / m)
+           - matvec(X_tile.T, alpha_blk) / m)
     g_a = (-prob.loss.dual_grad(alpha_blk, y_tile) * tile_row_nnz
            / (m * row_nnz_tile)
-           - (X_tile @ w_blk) / m)
+           - matvec(X_tile, w_blk) / m)
     return g_w, g_a

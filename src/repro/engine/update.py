@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.core.losses import get_loss
 from repro.core.regularizers import get_regularizer
+from repro.core.saddle import matvec
 
 
 def block_tile_step(*, X_tile, y_tile, w_blk, alpha_blk, gw_blk, ga_blk,
@@ -45,10 +46,10 @@ def block_tile_step(*, X_tile, y_tile, w_blk, alpha_blk, gw_blk, ga_blk,
         tile_col_nnz = nz.sum(axis=0)      # n_j within this tile
         tile_row_nnz = nz.sum(axis=1)      # n_i within this tile
     g_w = (lam * reg.grad(w_blk) * tile_col_nnz / col_nnz_blk
-           - (X_tile.T @ alpha_blk) / m)
+           - matvec(X_tile.T, alpha_blk) / m)
     g_a = (-loss.dual_grad(alpha_blk, y_tile) * tile_row_nnz
            / (m * row_nnz_tile)
-           - (X_tile @ w_blk) / m)
+           - matvec(X_tile, w_blk) / m)
     # rows with no nonzero in this tile have g_a = 0 automatically
     # (tile_row_nnz = 0 and the X_tile @ w term vanishes).
     return eq8_apply(loss, w_blk, alpha_blk, gw_blk, ga_blk, y_tile,

@@ -24,11 +24,13 @@ exactly the fused tile step and the general case equals scanning
 ``core.dso.sparse_tile_step`` (which in turn equals the dense
 ``block_tile_step`` to float32 reduction order).
 
-The scatter-add (``.at[].add``) and the 2-D gather lower through the Pallas
-interpreter on CPU (this container) and through XLA under ``interpret=True``
-everywhere; on a real TPU Mosaic's scatter support is the gating feature —
-the jnp path (``impl='sparse'``) provides the same nnz-proportional math
-through XLA's native scatter/gather in the meantime.
+The in-kernel gather (``jnp.take`` of the w block at a 2-D index array) and
+scatter-add (``.at[].add``) run only under ``interpret=True``: the TPU
+compiler (Mosaic) refuses both ("Only 2D gather is supported"), so on a TPU
+the ``ops`` wrappers raise ``ValueError`` after the
+``ops.mosaic_sparse_gather_error`` probe instead of compiling these kernels.
+The XLA backends (``sparse_jnp`` / ``sparse_bucketed_jnp``) run the same
+nnz-proportional math through XLA's native gather and scatter.
 
 The per-tile nonzero counts are precomputed (``SparseGridData``) and passed
 in, exactly like the dense kernels.
@@ -95,7 +97,7 @@ def dso_sparse_block_step_pallas(cols, vals, y, w, alpha, gw, ga,
                                  tile_row_nnz, tile_col_nnz, row_nnz,
                                  col_nnz, scalars, *, row_batches: int,
                                  loss_name: str, reg_name: str,
-                                 interpret: bool = True):
+                                 interpret: bool):
     """All ``row_batches`` sequential tile steps of one active block from
     its packed block-ELL tile.  cols/vals (M, K) with block-local column
     indices; w/gw/col_nnz (db,); alpha/ga/y/row_nnz/tile_row_nnz (M,);
@@ -126,7 +128,7 @@ def dso_sparse_block_step_pallas(cols, vals, y, w, alpha, gw, ga,
             pl.BlockSpec((1, db), lambda mi: (0, 0)),     # gw
             pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # ga
             pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # tile row nnz
-            pl.BlockSpec((1, db), lambda mi: (mi, 0)),    # tile col nnz
+            pl.BlockSpec((None, 1, db), lambda mi: (mi, 0, 0)),  # t col nnz
             pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # |Omega_i|
             pl.BlockSpec((1, db), lambda mi: (0, 0)),     # |Omega-bar_j|
             pl.BlockSpec((1, 5), lambda mi: (0, 0)),      # scalars
@@ -148,7 +150,7 @@ def dso_sparse_block_step_pallas(cols, vals, y, w, alpha, gw, ga,
     )(cols, vals, y.reshape(M, 1), w.reshape(1, db), alpha.reshape(M, 1),
       gw.reshape(1, db), ga.reshape(M, 1),
       tile_row_nnz.reshape(M, 1).astype(jnp.float32),
-      tile_col_nnz.reshape(n_mt, db).astype(jnp.float32),
+      tile_col_nnz.reshape(n_mt, 1, db).astype(jnp.float32),
       row_nnz.reshape(M, 1), col_nnz.reshape(1, db), scalars.reshape(1, 5))
     return (w2.reshape(db), a2.reshape(M), gw2.reshape(db), ga2.reshape(M))
 
@@ -248,7 +250,7 @@ def dso_bucketed_block_step_pallas(cols_fl, vals_fl, lut, cnt, y, w, alpha,
                                    gw, ga, tile_row_nnz, tile_col_nnz,
                                    row_nnz, col_nnz, scalars, *,
                                    row_batches: int, loss_name: str,
-                                   reg_name: str, interpret: bool = True):
+                                   reg_name: str, interpret: bool):
     """All ``row_batches`` sequential tile steps of one active block from
     the flat chunk view.  cols_fl/vals_fl (n_chunks, M, K_CHUNK) with
     block-local column indices; ``lut`` (n_kc,) clamped chunk indices of
@@ -283,7 +285,8 @@ def dso_bucketed_block_step_pallas(cols_fl, vals_fl, lut, cnt, y, w, alpha,
             pl.BlockSpec((1, db), lambda mi, kc, info: (0, 0)),     # gw
             pl.BlockSpec((bm, 1), lambda mi, kc, info: (mi, 0)),    # ga
             pl.BlockSpec((bm, 1), lambda mi, kc, info: (mi, 0)),    # t row nnz
-            pl.BlockSpec((1, db), lambda mi, kc, info: (mi, 0)),    # t col nnz
+            pl.BlockSpec((None, 1, db),
+                         lambda mi, kc, info: (mi, 0, 0)),          # t col nnz
             pl.BlockSpec((bm, 1), lambda mi, kc, info: (mi, 0)),    # |Omega_i|
             pl.BlockSpec((1, db), lambda mi, kc, info: (0, 0)),     # |O-bar_j|
             pl.BlockSpec((1, 5), lambda mi, kc, info: (0, 0)),      # scalars
@@ -315,7 +318,7 @@ def dso_bucketed_block_step_pallas(cols_fl, vals_fl, lut, cnt, y, w, alpha,
     )(info, cols_fl, vals_fl, y.reshape(M, 1), w.reshape(1, db),
       alpha.reshape(M, 1), gw.reshape(1, db), ga.reshape(M, 1),
       tile_row_nnz.reshape(M, 1).astype(jnp.float32),
-      tile_col_nnz.reshape(n_mt, db).astype(jnp.float32),
+      tile_col_nnz.reshape(n_mt, 1, db).astype(jnp.float32),
       row_nnz.reshape(M, 1), col_nnz.reshape(1, db), scalars.reshape(1, 5))
     return (w2.reshape(db), a2.reshape(M), gw2.reshape(db), ga2.reshape(M))
 

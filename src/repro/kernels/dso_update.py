@@ -116,6 +116,13 @@ DEFAULT_BD = 512  # cols per X block
 _ADA_EPS = 1e-8
 
 
+def _dot(a, b):
+    """float32 mat-vec at full precision (a TPU may run a default-precision
+    f32 dot as reduced-precision bf16 passes)."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _reg_grad(reg_name: str, w):
     if reg_name == "l2":
         return 2.0 * w
@@ -187,8 +194,8 @@ def _fused_tile_kernel(x_ref, y_ref, w_ref, alpha_ref, gw_ref, ga_ref,
     def _init_row():
         row_acc_ref[...] = jnp.zeros_like(a)
 
-    col_acc_ref[pl.ds(dj, 1), :] += a.T @ x     # partial X^T alpha
-    row_acc_ref[...] += x @ w.T                 # partial X w
+    col_acc_ref[pl.ds(dj, 1), :] += _dot(a.T, x)   # partial X^T alpha
+    row_acc_ref[...] += _dot(x, w.T)               # partial X w
 
     # keep the output windows well-defined on every flush: default to the
     # pre-update values, overwritten below at the finalize steps
@@ -244,11 +251,11 @@ def _fused_block_kernel(x_ref, y_ref, w_ref, alpha_ref, gw_ref, ga_ref,
     def _init_row():
         row_acc_ref[...] = jnp.zeros_like(a)
 
-    row_acc_ref[...] += x @ w.T         # dual mat-vec with pre-update w
+    row_acc_ref[...] += _dot(x, w.T)    # dual mat-vec with pre-update w
 
     # primal update of this column slice from this row tile alone
     w_new, gw_new = _primal_update(
-        reg_name, w, gw_st_ref[pl.ds(dj, 1), :], a.T @ x,
+        reg_name, w, gw_st_ref[pl.ds(dj, 1), :], _dot(a.T, x),
         tcn_ref[...], cn_ref[...], scal_ref[...])
     w_st_ref[pl.ds(dj, 1), :] = w_new
     gw_st_ref[pl.ds(dj, 1), :] = gw_new
@@ -284,9 +291,13 @@ def _fused_call(kernel, X, y, w, alpha, gw, ga, trn, tcn, rn, cn, scalars,
             pl.BlockSpec((bm, 1), lambda mi, dj: (mi, 0)),     # ga
             pl.BlockSpec((bm, 1), lambda mi, dj: (mi, 0)),     # tile row nnz
             # tile col nnz: per row tile for the block kernel, total for the
-            # tile kernel (callers pass a (1, D) or (n_mt, D) array)
-            pl.BlockSpec((1, bd), (lambda mi, dj: (mi, dj))
-                         if tcn.shape[0] == n_mt else (lambda mi, dj: (0, dj))),
+            # tile kernel (callers pass a (1, 1, D) or (n_mt, 1, D) array).
+            # The row-tile axis is squeezed so each block is (1, bd) over a
+            # unit second-to-last dim: Mosaic refuses a (1, bd) block of an
+            # (n_mt, D) array whose n_mt is neither 1 nor a multiple of 8
+            pl.BlockSpec((None, 1, bd), (lambda mi, dj: (mi, 0, dj))
+                         if tcn.shape[0] == n_mt
+                         else (lambda mi, dj: (0, 0, dj))),
             pl.BlockSpec((bm, 1), lambda mi, dj: (mi, 0)),     # |Omega_i|
             pl.BlockSpec((1, bd), lambda mi, dj: (0, dj)),     # |Omega-bar_j|
             pl.BlockSpec((1, 5), lambda mi, dj: (0, 0)),       # scalars
@@ -340,7 +351,7 @@ def dso_tile_step_pallas(X, y, w, alpha, gw, ga, row_nnz, col_nnz, scalars,
     w2, a2, gw2, ga2 = _fused_call(
         _fused_tile_kernel, X, y.reshape(M, 1), w.reshape(1, D),
         alpha.reshape(M, 1), gw.reshape(1, D), ga.reshape(M, 1),
-        tile_row_nnz.reshape(M, 1), tile_col_nnz.reshape(1, D),
+        tile_row_nnz.reshape(M, 1), tile_col_nnz.reshape(1, 1, D),
         row_nnz.reshape(M, 1), col_nnz.reshape(1, D), scalars.reshape(1, 5),
         bm=bm, bd=bd, n_mt=n_mt, n_dt=n_dt, scratch=scratch,
         loss_name=loss_name, reg_name=reg_name, interpret=interpret)
@@ -374,7 +385,7 @@ def dso_block_step_pallas(X, y, w, alpha, gw, ga, tile_row_nnz, tile_col_nnz,
     w2, a2, gw2, ga2 = _fused_call(
         _fused_block_kernel, X, y.reshape(M, 1), w.reshape(1, D),
         alpha.reshape(M, 1), gw.reshape(1, D), ga.reshape(M, 1),
-        tile_row_nnz.reshape(M, 1), tile_col_nnz.reshape(n_mt, D),
+        tile_row_nnz.reshape(M, 1), tile_col_nnz.reshape(n_mt, 1, D),
         row_nnz.reshape(M, 1), col_nnz.reshape(1, D), scalars.reshape(1, 5),
         bm=bm, bd=bd, n_mt=n_mt, n_dt=n_dt, scratch=scratch,
         loss_name=loss_name, reg_name=reg_name, interpret=interpret)
@@ -398,7 +409,7 @@ def _primal_kernel(x_ref, alpha_ref, w_ref, gw_ref, cn_ref, scal_ref,
 
     x = x_ref[...]                      # (bm, bd)
     a = alpha_ref[...]                  # (bm, 1)
-    acc_ref[...] += (a.T @ x)           # (1, bd) partial X^T alpha
+    acc_ref[...] += _dot(a.T, x)        # (1, bd) partial X^T alpha
     cnt_ref[...] += (x != 0).astype(jnp.float32).sum(axis=0, keepdims=True)
 
     @pl.when(mi == n_mt - 1)
@@ -422,7 +433,7 @@ def _dual_kernel(x_ref, w_ref, alpha_ref, ga_ref, y_ref, rn_ref, scal_ref,
 
     x = x_ref[...]                      # (bm, bd)
     w = w_ref[...]                      # (1, bd)
-    acc_ref[...] += (x @ w.T)           # (bm, 1) partial X w
+    acc_ref[...] += _dot(x, w.T)        # (bm, 1) partial X w
     cnt_ref[...] += (x != 0).astype(jnp.float32).sum(axis=1, keepdims=True)
 
     @pl.when(di == n_dt - 1)

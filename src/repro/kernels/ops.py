@@ -1,13 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels (padding + dispatch).
 
-On this CPU container the kernels run with ``interpret=True``; on a real TPU
-set ``interpret=False`` (the default flips on backend detection).
+The kernels compile (Mosaic) where the computation runs on a TPU and run in
+the Pallas interpreter on every other platform; a caller may still pass
+``interpret=True`` explicitly.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -15,26 +15,27 @@ import jax.numpy as jnp
 from repro.kernels import dso_update, ssd_scan as _ssd, swa_attention as _swa
 
 
+def _platform() -> str:
+    """Platform the next computation runs on: the ``jax.default_device``
+    in effect (a ``with jax.default_device(cpu):`` block on a TPU host runs
+    on the CPU), else the default backend."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+    return _platform() == "tpu"
 
 
 def _resolve_interpret(interpret: bool | None) -> bool:
-    """``interpret=None`` -> backend auto-detection: compiled (Mosaic) on a
-    real TPU, the Pallas interpreter everywhere else.  Every kernel wrapper
-    resolves through here so the default is pinned in one place.
-
-    ``REPRO_FORCE_INTERPRET=1`` (or ``0``) in the environment overrides the
-    auto-detection — but never an explicit ``interpret=`` argument — so a
-    whole run can be forced onto the interpreter (TPU triage) or onto the
-    compiled path (capturing Mosaic errors in CI) without threading a flag
-    through every call site.
-    """
+    """``interpret=None`` -> compiled (Mosaic) where the computation runs on
+    a TPU, the Pallas interpreter everywhere else.  Every kernel wrapper
+    resolves through here so the default is pinned in one place; nothing
+    but an explicit ``interpret=`` argument overrides the platform."""
     if interpret is not None:
         return interpret
-    env = os.environ.get("REPRO_FORCE_INTERPRET")
-    if env is not None and env.strip() != "":
-        return env.strip() not in ("0", "false", "False")
     return not _on_tpu()
 
 
@@ -188,9 +189,9 @@ def dso_block_step(X, y, w, alpha, gw, ga, tile_row_nnz, tile_col_nnz,
 
 
 def mosaic_sparse_gather_error() -> str | None:
-    """Probe the *current* default backend for the sparse kernels' gating
-    ops (2-D gather + scatter-add).  Returns ``None`` when the backend
-    lowers them, else the lowering error string — the ROADMAP
+    """Probe the platform the next computation runs on (``_platform``) for
+    the sparse kernels' gating ops (2-D gather + scatter-add).  Returns
+    ``None`` when it lowers them, else the lowering error string — the ROADMAP
     "Mosaic-native scatter/gather" seam: fall back LOUDLY instead of
     surfacing an opaque Mosaic error from inside the real kernel.
 
@@ -199,13 +200,13 @@ def mosaic_sparse_gather_error() -> str | None:
     under a running JAX, and a probe verdict for ``cpu`` must not be served
     for ``tpu`` or vice versa.
     """
-    return _mosaic_sparse_gather_error(jax.default_backend())
+    return _mosaic_sparse_gather_error(_platform())
 
 
 @functools.lru_cache(maxsize=None)
 def _mosaic_sparse_gather_error(platform: str) -> str | None:
-    """Run the probe on ``platform`` (assumed to be the current default
-    backend — the cache key merely scopes the verdict).
+    """Run the probe on ``platform`` (assumed to be ``_platform()`` — the
+    cache key merely scopes the verdict).
 
     Compiles (and runs) a minimal Pallas kernel exercising exactly what
     ``kernels/dso_sparse.py`` needs beyond the dense kernels: a 2-D gather
@@ -257,7 +258,7 @@ def dso_sparse_block_step(cols, vals, y, w, alpha, gw, ga, tile_row_nnz,
         if err is not None:
             raise ValueError(
                 f"sparse Pallas kernel requested compiled "
-                f"(interpret=False) but the {jax.default_backend()!r} "
+                f"(interpret=False) but the {_platform()!r} "
                 f"backend cannot lower its scatter-add / 2-D gather "
                 f"(probe failed: {err.splitlines()[0]}); use the "
                 f"'sparse_jnp' backend (identical nnz-proportional math "
@@ -297,7 +298,7 @@ def dso_bucketed_block_step(cols_fl, vals_fl, lut, cnt, y, w, alpha, gw, ga,
         if err is not None:
             raise ValueError(
                 f"bucketed one-kernel Pallas backend requested compiled "
-                f"(interpret=False) but the {jax.default_backend()!r} "
+                f"(interpret=False) but the {_platform()!r} "
                 f"backend cannot lower its scatter-add / 2-D gather "
                 f"(probe failed: {err.splitlines()[0]}); use the "
                 f"'sparse_bucketed_jnp' backend (bit-identical math "

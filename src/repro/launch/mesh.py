@@ -6,29 +6,20 @@ jax device state."""
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6 has explicit axis types; 0.4.x does not
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions: pass ``axis_types=Auto`` when
-    the installed jax supports it (identical semantics either way)."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips (one v5e pod) or 2x16x16 = 512 chips (two pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU smoke)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

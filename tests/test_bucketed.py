@@ -19,8 +19,8 @@ Six groups, mirroring the PR 4 / PR 8 acceptance gates:
                   and bucket counts 1-4, and within 1e-5 of the legacy
                   ``lax.switch`` backends; the ops wrapper matches the
                   independent ``dso_bucketed_block_step_ref`` oracle, and
-                  ``REPRO_FORCE_INTERPRET`` / the per-platform Mosaic
-                  probe cache behave (PR 8 gates).
+                  the platform-only interpret resolution / the
+                  per-platform Mosaic probe cache behave.
   4. schedules  — the LPT schedule is a valid (n_epochs, p, p) permutation
                   array (never two workers on one block), covers every
                   (worker, block) pair per epoch, balances a skewed cost
@@ -263,21 +263,27 @@ def test_bucketed_block_step_matches_ref_oracle():
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-5)
 
 
-def test_force_interpret_env_override(monkeypatch):
-    """REPRO_FORCE_INTERPRET=0/1 overrides the platform auto-detection of
-    ``interpret=None`` but never an explicit ``interpret=`` argument."""
+def test_interpret_follows_platform_only(monkeypatch):
+    """``interpret=None`` resolves from the platform the computation runs
+    on and from nothing else: the environment cannot force the interpreter
+    onto a TPU, an explicit ``interpret=`` argument always wins, and a
+    ``jax.default_device`` block counts as the platform."""
+    import jax
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    assert ops._resolve_interpret(None) is False        # platform default
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
-    assert ops._resolve_interpret(None) is True         # env wins
-    assert ops._resolve_interpret(False) is False       # explicit arg wins
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
-    assert ops._resolve_interpret(None) is False
+    assert ops._resolve_interpret(None) is False        # compiled on TPU
+    assert ops._resolve_interpret(True) is True         # explicit arg wins
     monkeypatch.setattr(ops, "_on_tpu", lambda: False)
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
     assert ops._resolve_interpret(None) is True
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "")     # empty = unset
-    assert ops._resolve_interpret(None) is True         # back to platform
+    assert ops._resolve_interpret(False) is False
+    monkeypatch.undo()
+    with jax.default_device(jax.devices("cpu")[0]):
+        assert ops._platform() == "cpu"
+        assert ops._resolve_interpret(None) is True
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops._platform() == "tpu"
+    assert ops._resolve_interpret(None) is False
+    with jax.default_device("cpu"):                     # platform string
+        assert ops._resolve_interpret(None) is True
 
 
 def test_mosaic_probe_cached_per_platform(monkeypatch):
