@@ -105,9 +105,12 @@ def staged_step(backend: TileBackend, meta, staged, w_blk, gw_blk, alpha_q,
     blk_id, col_nnz_blk, trn_blk, tcn_blk = staged
     db = w_blk.shape[0]
     block = backend.select_block(arrays_q, blk_id, blk_id * db, db)
-    return backend.block_step(meta, block, y_q, w_blk, alpha_q, gw_blk,
-                              ga_q, rn_q, col_nnz_blk, trn_blk, tcn_blk,
-                              eta_t, row_batches)
+    # the scope names the tile step's ops in the compiled program's
+    # metadata (``.../vmap(tile_step)/...``), where a profile finds them
+    with jax.named_scope("tile_step"):
+        return backend.block_step(meta, block, y_q, w_blk, alpha_q, gw_blk,
+                                  ga_q, rn_q, col_nnz_blk, trn_blk, tcn_blk,
+                                  eta_t, row_batches)
 
 
 def inner_iteration(backend: TileBackend, meta, col_nnz, blk_id, w_blk,
@@ -323,6 +326,15 @@ def _next_multiple(t: int, k: int) -> int:
 # no obs work and allocates nothing when obs is None.
 
 
+def _enter(obs, name: str, **attrs):
+    """Open span ``name`` by hand and return it for its ``__exit__``:
+    manual enter/exit (not contextlib), so the obs-off path allocates
+    nothing."""
+    span = obs.span(name, **attrs)
+    span.__enter__()
+    return span
+
+
 def _obs_throughput(obs, *, rows: float, nnz: float, payload_bytes: float):
     """Bind the static per-epoch work totals once per run; returns the
     per-chunk callback recording the throughput gauges."""
@@ -408,15 +420,20 @@ def solve(source, *, backend="auto", schedule="cyclic", p: int = 4,
     paper-exact ``solve_serial`` safe mode (Problem sources only).
 
     Observability seam (``repro.obs``): ``obs`` (duck-typed, e.g.
-    ``obs.RunRecorder``) receives, per chunk, a ``span("epoch_chunk")``
-    (the chunk is synced with ``block_until_ready`` so the span times
-    completed epochs, not async dispatch) plus rows/s, nnz/s, packed
-    payload bytes/s, and eta gauges; ``span("eval")`` /
-    ``span("snapshot_save")`` / ``span("restore")`` around those
+    ``obs.RunRecorder``) receives one ``span("solve")`` per call, the
+    request every span below carries the id of: ``solve_setup`` (tile
+    checks, tiling, initial state, PRNG key, obs set-up), then per chunk
+    ``epoch_chunk`` with children ``chunk_schedule`` (permutation draw and
+    step sizes), ``chunk_dispatch`` (the epoch program's enqueue) and
+    ``chunk_wait`` (``block_until_ready``, so the chunk times completed
+    epochs, and the rows/s, nnz/s, packed payload bytes/s and eta
+    gauges); ``eval`` with child ``eval_gather`` (w and alpha off the
+    grid) around the hook; ``snapshot_save`` / ``restore`` around those
     boundaries; every evaluation-history field as an ``eval.<key>``
     gauge; and (when ``health`` is given without its own recorder) the
-    health guard's ledger events.  ``obs=None`` (default) is a true
-    no-op: no obs calls, no allocations, bit-identical trajectories.
+    health guard's ledger events.  ``solve`` and ``eval`` close when the
+    hook raises.  ``obs=None`` (default) is a true no-op: no obs calls,
+    no allocations, bit-identical trajectories.
 
     Telemetry seam (``repro.obs.telemetry``): ``telemetry`` (duck-typed,
     e.g. ``TelemetrySpec``) turns on the device-resident telemetry lane —
@@ -442,215 +459,246 @@ def solve(source, *, backend="auto", schedule="cyclic", p: int = 4,
     if store is not None and checkpoint_every < 1:
         raise ValueError("a snapshot store needs checkpoint_every >= 1 to "
                          "know its boundaries")
-    sched = get_schedule(schedule)
-    if isinstance(source, Problem):
-        given = [k for k, v in (("loss_name", loss_name),
-                                ("reg_name", reg_name), ("lam", lam),
-                                ("m", m), ("d", d)) if v is not None]
-        if given:
-            raise ValueError(
-                f"{given} conflict with the Problem source (its own "
-                f"loss/reg/lam/shape are used); either drop them or pass "
-                f"pre-built grid data instead of the Problem")
-        prob = source
-        be, data = resolve_backend_and_build(prob, backend, p, row_batches)
-        loss_name, reg_name = prob.loss_name, prob.reg_name
-        m, d = prob.m, prob.d
-        lam_f, m_f, _, _, _, w_lo, w_hi = prob_meta(prob)
-        if eval_hook == "auto":
-            eval_hook = problem_eval_hook(prob)
-    else:
-        data = source
-        missing = [k for k, v in (("loss_name", loss_name),
-                                  ("reg_name", reg_name), ("lam", lam),
-                                  ("m", m), ("d", d)) if v is None]
-        if missing:
-            raise ValueError(f"solving from pre-built grid data requires "
-                             f"{missing} (no Problem to read them from)")
-        be = resolve_backend_for_layout(backend,
-                                        as_tile_data(data).layout)
-        loss = get_loss(loss_name)
-        box = loss.w_box(lam) if loss.w_box is not None else np.inf
-        lam_f, m_f = jnp.float32(lam), jnp.float32(m)
-        w_lo, w_hi = jnp.float32(-box), jnp.float32(box)
-        if eval_hook == "auto":
-            eval_hook = None
-    check_tile_stats(data, row_batches)
-    tile = as_tile_data(data, bucketed_payload=be.payload)
-    p_, mb_, db = tile_dims(tile)
-    kw = dict(backend=be.name, loss_name=loss_name, reg_name=reg_name,
-              use_adagrad=use_adagrad, row_batches=row_batches, p=p_, db=db)
-
-    chunk = eval_every if eval_hook is not None else epochs
-    if scan_epochs:
-        warn_ragged_eval(epochs, chunk)
-    # balanced schedules (lpt) weigh the per-tile nnz; computed once here
-    sched_ctx = ({"tile_nnz": np.asarray(tile.tile_row_nnz_g).sum(axis=-1)}
-                 if sched.balanced else {})
-    # the complete run record a snapshot carries (runtime.resume rebuilds
-    # the solver call from it; runtime.reshard rewrites p/mb/db)
-    cfg = dict(backend=be.name, schedule=sched.name, p=p_, mb=mb_, db=db,
-               m=int(m), d=int(d), loss_name=loss_name, reg_name=reg_name,
-               lam=float(lam_f), row_batches=row_batches, eta0=float(eta0),
-               use_adagrad=bool(use_adagrad), alpha0=float(alpha0),
-               seed=int(seed), eval_every=int(eval_every),
-               checkpoint_every=int(checkpoint_every), layout=be.layout,
-               inner_iteration=0)
-    if health is not None:   # backoff params ride in every snapshot too
-        cfg.update(eta_decay=float(health.eta_decay),
-                   max_retries=int(health.max_retries))
-    if init is not None:
-        got = tuple(init.state.w_grid.shape)
-        if got != (p_, db):
-            raise ValueError(
-                f"snapshot state has w grid {got}, this run's grid is "
-                f"({p_}, {db}) — resuming across a different p needs "
-                f"repro.runtime.reshard first")
-        # copied, not aliased: the epoch scan donates its state, and the
-        # caller's snapshot must survive the resumed run (re-reshard, etc.)
-        state = jax.tree.map(lambda a: jnp.array(a, copy=True), init.state)
-        key = jnp.asarray(init.key)
-        t = int(init.epochs_done)
-        history = list(init.history)
-    else:
-        state = init_state_data(loss_name, data, alpha0)
-        key = jax.random.PRNGKey(seed)
-        t, history = 0, []
-    eta_live = float(eta0)   # backed off per rollback under a health guard
     if obs is not None:
-        # static per-epoch work totals, computed once: every epoch touches
-        # every nonzero exactly once, streaming the layout payload once
-        obs.record(type="meta", phase="solve", epochs=int(epochs), **cfg)
-        record_chunk = _obs_throughput(
-            obs, rows=float(m),
-            nnz=float(np.asarray(tile.row_nnz_g * tile.row_valid).sum()),
-            payload_bytes=float(sum(getattr(a, "nbytes", 0)
-                                    for a in tile.arrays)))
-        if health is not None and getattr(health, "obs", None) is None:
-            health.obs = obs   # ledger events join the same stream
-    while t < epochs:
-        if health is not None:
-            state = health.inject(state, t)
-        stops = [epochs]
-        if eval_hook is not None:
-            stops.append(_next_multiple(t, chunk))
-        if checkpoint_every:
-            stops.append(_next_multiple(t, checkpoint_every))
-        n = min(stops) - t
-        key, perms = sched.draw(key, t, n, p_, **sched_ctx)
-        etas = eta_schedule(eta_live, t, n, use_adagrad)
-        # manual enter/exit (not contextlib) so the obs-off loop body
-        # allocates nothing — the metrics-off contract
-        span = obs.span("epoch_chunk", t0=t, epochs=n) \
-            if obs is not None else None
-        if span is not None:
-            span.__enter__()
-            t_chunk = time.perf_counter()
-        if telemetry is not None:
-            t_tel = time.perf_counter()
-            state, tbuf = run_epochs_telemetry(tile, state, perms, etas,
-                                               lam_f, m_f, w_lo, w_hi, **kw)
-        elif scan_epochs:
-            state = run_epochs(tile, state, perms, etas, lam_f, m_f,
-                               w_lo, w_hi, **kw)
+        span_solve = _enter(obs, "solve")
+        span = _enter(obs, "solve_setup")
+    try:
+        sched = get_schedule(schedule)
+        if isinstance(source, Problem):
+            given = [k for k, v in (("loss_name", loss_name),
+                                    ("reg_name", reg_name), ("lam", lam),
+                                    ("m", m), ("d", d)) if v is not None]
+            if given:
+                raise ValueError(
+                    f"{given} conflict with the Problem source (its own "
+                    f"loss/reg/lam/shape are used); either drop them or "
+                    f"pass pre-built grid data instead of the Problem")
+            prob = source
+            be, data = resolve_backend_and_build(prob, backend, p,
+                                                 row_batches)
+            loss_name, reg_name = prob.loss_name, prob.reg_name
+            m, d = prob.m, prob.d
+            lam_f, m_f, _, _, _, w_lo, w_hi = prob_meta(prob)
+            if eval_hook == "auto":
+                eval_hook = problem_eval_hook(prob)
         else:
-            for k in range(n):
-                state = run_epoch(tile, state, perms[k], etas[k], lam_f,
-                                  m_f, w_lo, w_hi, **kw)
-        if span is not None:
-            # sync so the span times completed epochs, not async dispatch
-            jax.block_until_ready(state)
-            record_chunk(n, time.perf_counter() - t_chunk, eta_live)
+            data = source
+            missing = [k for k, v in (("loss_name", loss_name),
+                                      ("reg_name", reg_name), ("lam", lam),
+                                      ("m", m), ("d", d)) if v is None]
+            if missing:
+                raise ValueError(f"solving from pre-built grid data "
+                                 f"requires {missing} (no Problem to read "
+                                 f"them from)")
+            be = resolve_backend_for_layout(backend,
+                                            as_tile_data(data).layout)
+            loss = get_loss(loss_name)
+            box = loss.w_box(lam) if loss.w_box is not None else np.inf
+            lam_f, m_f = jnp.float32(lam), jnp.float32(m)
+            w_lo, w_hi = jnp.float32(-box), jnp.float32(box)
+            if eval_hook == "auto":
+                eval_hook = None
+        check_tile_stats(data, row_batches)
+        tile = as_tile_data(data, bucketed_payload=be.payload)
+        p_, mb_, db = tile_dims(tile)
+        kw = dict(backend=be.name, loss_name=loss_name, reg_name=reg_name,
+                  use_adagrad=use_adagrad, row_batches=row_batches, p=p_,
+                  db=db)
+
+        chunk = eval_every if eval_hook is not None else epochs
+        if scan_epochs:
+            warn_ragged_eval(epochs, chunk)
+        # balanced schedules (lpt) weigh the per-tile nnz; computed once
+        sched_ctx = ({"tile_nnz":
+                      np.asarray(tile.tile_row_nnz_g).sum(axis=-1)}
+                     if sched.balanced else {})
+        # the complete run record a snapshot carries (runtime.resume
+        # rebuilds the solver call from it; runtime.reshard rewrites
+        # p/mb/db)
+        cfg = dict(backend=be.name, schedule=sched.name, p=p_, mb=mb_,
+                   db=db, m=int(m), d=int(d), loss_name=loss_name,
+                   reg_name=reg_name, lam=float(lam_f),
+                   row_batches=row_batches, eta0=float(eta0),
+                   use_adagrad=bool(use_adagrad), alpha0=float(alpha0),
+                   seed=int(seed), eval_every=int(eval_every),
+                   checkpoint_every=int(checkpoint_every), layout=be.layout,
+                   inner_iteration=0)
+        if health is not None:  # backoff params ride in every snapshot too
+            cfg.update(eta_decay=float(health.eta_decay),
+                       max_retries=int(health.max_retries))
+        if init is not None:
+            got = tuple(init.state.w_grid.shape)
+            if got != (p_, db):
+                raise ValueError(
+                    f"snapshot state has w grid {got}, this run's grid is "
+                    f"({p_}, {db}) — resuming across a different p needs "
+                    f"repro.runtime.reshard first")
+            # copied, not aliased: the epoch scan donates its state, and
+            # the caller's snapshot must survive the resumed run
+            # (re-reshard, etc.)
+            state = jax.tree.map(lambda a: jnp.array(a, copy=True),
+                                 init.state)
+            key = jnp.asarray(init.key)
+            t = int(init.epochs_done)
+            history = list(init.history)
+        else:
+            state = init_state_data(loss_name, data, alpha0)
+            key = jax.random.PRNGKey(seed)
+            t, history = 0, []
+        eta_live = float(eta0)  # backed off per rollback under a health guard
+        if obs is not None:
+            # static per-epoch work totals, computed once: every epoch
+            # touches every nonzero exactly once, streaming the layout
+            # payload once
+            obs.record(type="meta", phase="solve", epochs=int(epochs), **cfg)
+            record_chunk = _obs_throughput(
+                obs, rows=float(m),
+                nnz=float(np.asarray(tile.row_nnz_g * tile.row_valid).sum()),
+                payload_bytes=float(sum(getattr(a, "nbytes", 0)
+                                        for a in tile.arrays)))
+            if health is not None and getattr(health, "obs", None) is None:
+                health.obs = obs   # ledger events join the same stream
             span.__exit__(None, None, None)
-        if telemetry is not None:
-            # drain outside the span: the device->host copy is host obs
-            # work, not epoch time (the buffer fetch syncs the chunk)
-            jax.block_until_ready(state)
-            telemetry.drain(tbuf, t0=t, etas=etas, perms=np.asarray(perms),
-                            db=db,
-                            transport="ring" if sched.ring else "p2p",
-                            wall_s=time.perf_counter() - t_tel)
-        t_new = t + n
-        failure = None
-        if health is not None:
-            # state first: a poisoned iterate must never reach the eval
-            # hook or the snapshot store
-            failure = health.check_state(state)
-        if failure is None and eval_hook is not None and (
-                t_new % chunk == 0 or t_new == epochs):
-            span = obs.span("eval", epoch=t_new) if obs is not None else None
-            if span is not None:
-                span.__enter__()
-            entry = eval_hook(t_new, gather_w(state, d),
-                              gather_alpha(state, m))
-            history.append(entry)
-            if span is not None:
-                _obs_eval(obs, entry)
-                span.__exit__(None, None, None)
+        while t < epochs:
             if health is not None:
-                failure = health.check_history(history)
-        if failure is not None:
-            health.retries += 1
-            if health.retries > health.max_retries:
-                if health.exhausted(failure=failure, epoch=t_new,
-                                    eta0=eta_live,
-                                    can_degrade=isinstance(source,
-                                                           Problem)
-                                    ) == "serial":
-                    return solve_serial(source, epochs=epochs,
-                                        eta0=eta_live, seed=seed,
-                                        use_adagrad=use_adagrad,
-                                        alpha0=alpha0,
-                                        eval_every=eval_every, obs=obs)
-            span = obs.span("restore", epoch=t_new, failure=failure) \
-                if obs is not None else None
-            if span is not None:
-                span.__enter__()
-            snap = None
-            if store is not None:
-                try:
-                    snap = store.load()   # latest-VALID-wins
-                except FileNotFoundError:
-                    snap = None
-            if snap is None:
-                snap = init               # may still be None: fresh start
-            eta_live *= health.eta_decay
-            cfg["eta0"] = eta_live
-            if snap is not None:
-                state = jax.tree.map(lambda a: jnp.array(a, copy=True),
-                                     snap.state)
-                key = jnp.asarray(snap.key)
-                resumed = int(snap.epochs_done)
-                history = list(snap.history)
+                state = health.inject(state, t)
+            stops = [epochs]
+            if eval_hook is not None:
+                stops.append(_next_multiple(t, chunk))
+            if checkpoint_every:
+                stops.append(_next_multiple(t, checkpoint_every))
+            n = min(stops) - t
+            # every statement of the chunk sits in one child span, so the
+            # chunk's self time is the recorder's own bookkeeping
+            if obs is not None:
+                span_chunk = _enter(obs, "epoch_chunk", t0=t, epochs=n)
+                span = _enter(obs, "chunk_schedule")
+            key, perms = sched.draw(key, t, n, p_, **sched_ctx)
+            etas = eta_schedule(eta_live, t, n, use_adagrad)
+            if obs is not None:
+                span.__exit__(None, None, None)
+                span = _enter(obs, "chunk_dispatch")
+                t_chunk = time.perf_counter()
+            if telemetry is not None:
+                t_tel = time.perf_counter()
+                state, tbuf = run_epochs_telemetry(tile, state, perms, etas,
+                                                   lam_f, m_f, w_lo, w_hi,
+                                                   **kw)
+            elif scan_epochs:
+                state = run_epochs(tile, state, perms, etas, lam_f, m_f,
+                                   w_lo, w_hi, **kw)
             else:
-                state = init_state_data(loss_name, data, alpha0)
-                key = jax.random.PRNGKey(seed)
-                resumed, history = 0, []
-            health.note(kind="health", epoch=t_new, action="rollback",
-                        epochs_lost=t_new - resumed, retry=health.retries,
-                        failure=failure, resumed_from=resumed,
-                        eta0=eta_live)
-            if span is not None:
+                for k in range(n):
+                    state = run_epoch(tile, state, perms[k], etas[k], lam_f,
+                                      m_f, w_lo, w_hi, **kw)
+            if obs is not None:
                 span.__exit__(None, None, None)
-            t = resumed
-            continue
-        t = t_new
-        if store is not None and (t % checkpoint_every == 0 or t == epochs):
-            span = obs.span("snapshot_save", epoch=t) \
-                if obs is not None else None
-            if span is not None:
-                span.__enter__()
-            store.save(state=state, key=key, epochs_done=t,
-                       history=list(history), config=cfg)
-            if span is not None:
+                span = _enter(obs, "chunk_wait")
+                # sync so the chunk times completed epochs, not the enqueue
+                jax.block_until_ready(state)
+                record_chunk(n, time.perf_counter() - t_chunk, eta_live)
                 span.__exit__(None, None, None)
-    if store is not None and hasattr(store, "flush"):
-        # async-write stores overlap serialization with the chunk loop;
-        # drain (and surface any write failure) before declaring the run
-        # durable
-        store.flush()
-    return SolveResult(gather_w(state, d), gather_alpha(state, m), history,
-                       state)
+                span_chunk.__exit__(None, None, None)
+            if telemetry is not None:
+                # drain outside the span: the device->host copy is host obs
+                # work, not epoch time (the buffer fetch syncs the chunk)
+                jax.block_until_ready(state)
+                telemetry.drain(tbuf, t0=t, etas=etas,
+                                perms=np.asarray(perms), db=db,
+                                transport="ring" if sched.ring else "p2p",
+                                wall_s=time.perf_counter() - t_tel)
+            t_new = t + n
+            failure = None
+            if health is not None:
+                # state first: a poisoned iterate must never reach the eval
+                # hook or the snapshot store
+                failure = health.check_state(state)
+            if failure is None and eval_hook is not None and (
+                    t_new % chunk == 0 or t_new == epochs):
+                if obs is not None:
+                    span_eval = _enter(obs, "eval", epoch=t_new)
+                    span = _enter(obs, "eval_gather")
+                # the hook may end the solve by raising: eval closes anyway
+                try:
+                    w_now = gather_w(state, d)
+                    alpha_now = gather_alpha(state, m)
+                    if obs is not None:
+                        span.__exit__(None, None, None)
+                    entry = eval_hook(t_new, w_now, alpha_now)
+                    # held past the hook, the gathered copies would add
+                    # their bytes to the next chunk's peak device memory
+                    del w_now, alpha_now
+                    history.append(entry)
+                    if obs is not None:
+                        _obs_eval(obs, entry)
+                finally:
+                    if obs is not None:
+                        span_eval.__exit__(None, None, None)
+                if health is not None:
+                    failure = health.check_history(history)
+            if failure is not None:
+                health.retries += 1
+                if health.retries > health.max_retries:
+                    if health.exhausted(failure=failure, epoch=t_new,
+                                        eta0=eta_live,
+                                        can_degrade=isinstance(source,
+                                                               Problem)
+                                        ) == "serial":
+                        return solve_serial(source, epochs=epochs,
+                                            eta0=eta_live, seed=seed,
+                                            use_adagrad=use_adagrad,
+                                            alpha0=alpha0,
+                                            eval_every=eval_every, obs=obs)
+                if obs is not None:
+                    span = _enter(obs, "restore", epoch=t_new,
+                                  failure=failure)
+                snap = None
+                if store is not None:
+                    try:
+                        snap = store.load()   # latest-VALID-wins
+                    except FileNotFoundError:
+                        snap = None
+                if snap is None:
+                    snap = init               # may still be None: fresh start
+                eta_live *= health.eta_decay
+                cfg["eta0"] = eta_live
+                if snap is not None:
+                    state = jax.tree.map(lambda a: jnp.array(a, copy=True),
+                                         snap.state)
+                    key = jnp.asarray(snap.key)
+                    resumed = int(snap.epochs_done)
+                    history = list(snap.history)
+                else:
+                    state = init_state_data(loss_name, data, alpha0)
+                    key = jax.random.PRNGKey(seed)
+                    resumed, history = 0, []
+                health.note(kind="health", epoch=t_new, action="rollback",
+                            epochs_lost=t_new - resumed, retry=health.retries,
+                            failure=failure, resumed_from=resumed,
+                            eta0=eta_live)
+                if obs is not None:
+                    span.__exit__(None, None, None)
+                t = resumed
+                continue
+            t = t_new
+            if store is not None and (t % checkpoint_every == 0
+                                      or t == epochs):
+                if obs is not None:
+                    span = _enter(obs, "snapshot_save", epoch=t)
+                store.save(state=state, key=key, epochs_done=t,
+                           history=list(history), config=cfg)
+                if obs is not None:
+                    span.__exit__(None, None, None)
+        if store is not None and hasattr(store, "flush"):
+            # async-write stores overlap serialization with the chunk loop;
+            # drain (and surface any write failure) before declaring the run
+            # durable
+            store.flush()
+        return SolveResult(gather_w(state, d), gather_alpha(state, m),
+                           history, state)
+    finally:
+        if obs is not None:
+            span_solve.__exit__(None, None, None)
 
 
 # ------------------------------------------- paper-exact serial driver --
@@ -716,58 +764,80 @@ def solve_serial(prob: Problem, epochs: int = 10, eta0: float = 0.1,
                  eval_hook="auto", obs=None) -> SolveResult:
     """Paper-exact Algorithm 1 with p=1 (sequential pointwise updates),
     driven through the engine's evaluation-chunk loop.  ``obs`` is the
-    same duck-typed observability seam as ``solve`` (chunk spans +
-    throughput gauges + eval metrics; None = true no-op)."""
+    same duck-typed observability seam as ``solve`` (the same spans where
+    the step exists — ``solve``, ``solve_setup``, ``epoch_chunk`` with
+    ``chunk_schedule``/``chunk_dispatch``/``chunk_wait``, ``eval`` — plus
+    throughput gauges and eval metrics; None = true no-op)."""
     if eval_every < 1:
         raise ValueError(f"eval_every must be >= 1, got {eval_every}")
-    ii, jj, vv = _coords(prob)
-    ii, jj, vv = jnp.asarray(ii), jnp.asarray(jj), jnp.asarray(vv)
-    nnz = ii.shape[0]
-    w = jnp.zeros(prob.d, jnp.float32)
-    alpha = project_alpha(prob, jnp.full(prob.m, alpha0, jnp.float32))
-    gw = jnp.zeros_like(w)
-    ga = jnp.zeros_like(alpha)
-    loss = get_loss(prob.loss_name)
-    box = loss.w_box(prob.lam) if loss.w_box is not None else np.inf
-    hook = problem_eval_hook(prob) if eval_hook == "auto" else eval_hook
-    warn_ragged_eval(epochs, eval_every)
-    key = jax.random.PRNGKey(seed)
-    history = []
-    t = 0
     if obs is not None:
-        obs.record(type="meta", phase="solve_serial", epochs=int(epochs),
-                   m=prob.m, d=prob.d, nnz=int(nnz), eta0=float(eta0),
-                   loss_name=prob.loss_name, reg_name=prob.reg_name,
-                   seed=int(seed))
-        record_chunk = _obs_throughput(obs, rows=float(prob.m),
-                                       nnz=float(nnz),
-                                       payload_bytes=float(12 * nnz))
-    while t < epochs:
-        n = min(eval_every, epochs - t)
-        perms = []
-        for _ in range(n):
-            key, sk = jax.random.split(key)
-            perms.append(jax.random.permutation(sk, nnz))
-        span = obs.span("epoch_chunk", t0=t, epochs=n) \
-            if obs is not None else None
-        if span is not None:
-            span.__enter__()
-            t_chunk = time.perf_counter()
-        w, alpha, gw, ga = _serial_epochs(
-            ii, jj, vv, jnp.stack(perms), eta_schedule(eta0, t, n,
-                                                       use_adagrad),
-            w, alpha, gw, ga, prob.y, prob.row_nnz, prob.col_nnz,
-            jnp.float32(prob.lam), jnp.float32(-box), jnp.float32(box),
-            loss_name=prob.loss_name, reg_name=prob.reg_name, m=prob.m,
-            use_adagrad=use_adagrad)
-        if span is not None:
-            jax.block_until_ready((w, alpha))
-            record_chunk(n, time.perf_counter() - t_chunk, eta0)
+        span_solve = _enter(obs, "solve")
+        span = _enter(obs, "solve_setup")
+    try:
+        ii, jj, vv = _coords(prob)
+        ii, jj, vv = jnp.asarray(ii), jnp.asarray(jj), jnp.asarray(vv)
+        nnz = ii.shape[0]
+        w = jnp.zeros(prob.d, jnp.float32)
+        alpha = project_alpha(prob, jnp.full(prob.m, alpha0, jnp.float32))
+        gw = jnp.zeros_like(w)
+        ga = jnp.zeros_like(alpha)
+        loss = get_loss(prob.loss_name)
+        box = loss.w_box(prob.lam) if loss.w_box is not None else np.inf
+        hook = problem_eval_hook(prob) if eval_hook == "auto" else eval_hook
+        warn_ragged_eval(epochs, eval_every)
+        key = jax.random.PRNGKey(seed)
+        history = []
+        t = 0
+        if obs is not None:
+            obs.record(type="meta", phase="solve_serial",
+                       epochs=int(epochs), m=prob.m, d=prob.d, nnz=int(nnz),
+                       eta0=float(eta0), loss_name=prob.loss_name,
+                       reg_name=prob.reg_name, seed=int(seed))
+            record_chunk = _obs_throughput(obs, rows=float(prob.m),
+                                           nnz=float(nnz),
+                                           payload_bytes=float(12 * nnz))
             span.__exit__(None, None, None)
-        t += n
-        if hook is not None:
-            entry = hook(t, w, alpha)
-            history.append(entry)
+        while t < epochs:
+            n = min(eval_every, epochs - t)
             if obs is not None:
-                _obs_eval(obs, entry)
-    return SolveResult(w, alpha, history, None)
+                span_chunk = _enter(obs, "epoch_chunk", t0=t, epochs=n)
+                span = _enter(obs, "chunk_schedule")
+            perms = []
+            for _ in range(n):
+                key, sk = jax.random.split(key)
+                perms.append(jax.random.permutation(sk, nnz))
+            perms = jnp.stack(perms)
+            etas = eta_schedule(eta0, t, n, use_adagrad)
+            if obs is not None:
+                span.__exit__(None, None, None)
+                span = _enter(obs, "chunk_dispatch")
+                t_chunk = time.perf_counter()
+            w, alpha, gw, ga = _serial_epochs(
+                ii, jj, vv, perms, etas, w, alpha, gw, ga, prob.y,
+                prob.row_nnz, prob.col_nnz, jnp.float32(prob.lam),
+                jnp.float32(-box), jnp.float32(box),
+                loss_name=prob.loss_name, reg_name=prob.reg_name, m=prob.m,
+                use_adagrad=use_adagrad)
+            if obs is not None:
+                span.__exit__(None, None, None)
+                span = _enter(obs, "chunk_wait")
+                jax.block_until_ready((w, alpha))
+                record_chunk(n, time.perf_counter() - t_chunk, eta0)
+                span.__exit__(None, None, None)
+                span_chunk.__exit__(None, None, None)
+            t += n
+            if hook is not None:
+                if obs is not None:
+                    span_eval = _enter(obs, "eval", epoch=t)
+                try:
+                    entry = hook(t, w, alpha)
+                    history.append(entry)
+                    if obs is not None:
+                        _obs_eval(obs, entry)
+                finally:
+                    if obs is not None:
+                        span_eval.__exit__(None, None, None)
+        return SolveResult(w, alpha, history, None)
+    finally:
+        if obs is not None:
+            span_solve.__exit__(None, None, None)

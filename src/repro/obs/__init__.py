@@ -9,12 +9,13 @@ summary dict:
      |           MetricRegistry bound to the recorder
      |               rows/s, nnz/s, packed bytes/s, eta, primal, pd_gap,
      |               ingest rows/malformed/quarantined, serving tokens
-   trace.py      SpanTracer: nested host spans on perf_counter
-     |               span("epoch_chunk") / ("snapshot_save") / ("restore")
-     |               / ("reshard") / ("eval") ... -> JSONL span events +
-     |               Chrome trace-event export (Perfetto); optional
-     |               jax.profiler.TraceAnnotation pass-through so device
-     |               timelines line up with host spans
+   trace.py      SpanTracer: nested host spans on perf_counter, each
+     |           with an integer id, its parent's id and the id of the
+     |           enclosing ``solve`` (the request id) -> JSONL span
+     |           events; optional jax.profiler.TraceAnnotation
+     |           pass-through carrying the same ids, so the JSONL log
+     |           joins the profiler's trace (the device's clock) by id;
+     |           python_gc annotations for collector pauses
    recorder.py   RunRecorder: the ONE sink; also absorbs the runtime's
                  typed LedgerEvent stream (record_ledger), so health and
                  replan decisions land between the throughput samples
@@ -32,8 +33,11 @@ summary dict:
 Seams (all duck-typed ``obs=``, default ``None`` — the layers below never
 import this package):
 
-  engine.solve(..., obs=rec)       chunk spans + per-chunk throughput
-                                   gauges + eval metrics (primal, pd_gap)
+  engine.solve(..., obs=rec)       solve > solve_setup | epoch_chunk >
+                                   (chunk_schedule | chunk_dispatch |
+                                   chunk_wait) | eval > eval_gather spans,
+                                   per-chunk throughput gauges, eval
+                                   metrics (primal, pd_gap)
   engine.solve_serial(..., obs=rec)
   runtime.Supervisor(..., obs=rec) same stream: epoch_chunk/snapshot_save/
                                    restore/reshard spans, ledger events
@@ -57,8 +61,8 @@ Event schema — one JSON object per line, ``seq`` (monotone int) and
   {"seq", "ts", "type": "meta",   ...run identity (free-form)}
   {"seq", "ts", "type": "metric", "name", "kind": "counter"|"gauge"|
       "histogram", "value"[, "labels"]}
-  {"seq", "ts", "type": "span",   "name", "t0", "dur_s", "depth"
-      [, "attrs"]}
+  {"seq", "ts", "type": "span",   "name", "t0", "dur_s", "depth", "id",
+      "parent", "solve"[, "attrs"]}
   {"seq", "ts", "type": "ledger", "kind", "epoch", "action",
       "epochs_lost", "retry", ...detail fields}
   {"seq", "ts", "type": "telemetry", "kind": "chunk", "t0", "epochs",
@@ -79,8 +83,8 @@ chunk loop performs no obs calls and allocates nothing for obs, and
 trajectories are bit-identical to a recorder-on run (the recorder only
 observes; it never touches solver state) — both pinned by
 tests/test_obs.py.  With a recorder on, the per-chunk cost is a handful
-of dict appends, gated <= 2% of epoch wall time as ``obs_overhead`` in
-BENCH_dso.json.
+of dict appends: tests/test_obs.py pins the number of events one chunk
+emits, and PERF.md keeps the traced cost measured on the chip.
 """
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, Metric,
@@ -89,13 +93,12 @@ from repro.obs.recorder import RunRecorder, iter_events, read_events
 from repro.obs.telemetry import (TELEMETRY_FIELDS, TelemetrySpec,
                                  comm_bytes_matrix, nnz_throughput,
                                  render_heatmap, wall_balance)
-from repro.obs.trace import (WELL_KNOWN_SPANS, SpanTracer,
-                             chrome_trace_events)
+from repro.obs.trace import SpanTracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Metric", "MetricRegistry",
     "RunRecorder", "iter_events", "read_events",
     "TELEMETRY_FIELDS", "TelemetrySpec", "comm_bytes_matrix",
     "nnz_throughput", "render_heatmap", "wall_balance",
-    "SpanTracer", "chrome_trace_events", "WELL_KNOWN_SPANS",
+    "SpanTracer",
 ]

@@ -9,6 +9,7 @@ producers:
   obs.metrics   — ``rec.metrics.gauge("rows_per_s").set(...)`` (the
                   registry is bound to the recorder at construction)
   obs.trace     — ``with rec.span("epoch_chunk", epochs=4): ...``
+                  (each span with its ``id``, ``parent`` and ``solve`` ids)
   runtime ledger— ``rec.record_ledger(LedgerEvent(...))`` (the supervisor
                   and ``HealthGuard`` forward every typed recovery event)
 
@@ -22,10 +23,16 @@ Event schema (one JSON object per line; ``seq``/``ts`` on every event):
   {"seq": N, "ts": s, "type": "metric", "name": ..., "kind":
       "counter"|"gauge"|"histogram", "value": v[, "labels": {...}]}
   {"seq": N, "ts": s, "type": "span", "name": ..., "t0": s, "dur_s": s,
-      "depth": D[, "attrs": {...}]}
+      "depth": D, "id": I, "parent": I | null, "solve": I | null
+      [, "attrs": {...}]}
   {"seq": N, "ts": s, "type": "ledger", "kind": ..., "epoch": E,
       "action": ..., "epochs_lost": L, "retry": R, ...detail}
   {"seq": N, "ts": s, "type": "meta", ...}
+
+With ``jax_annotations=True`` every span is also a
+``jax.profiler.TraceAnnotation`` carrying the same ids (``obs.trace``), and
+while the recorder is open a ``gc.callbacks`` hook brackets each collector
+pause in a ``python_gc`` annotation; ``close`` unregisters it.
 
 The recorder is the duck-typed object every ``obs=`` seam accepts; the
 layers below (engine, runtime, sparse, serving) never import this module.
@@ -33,12 +40,13 @@ layers below (engine, runtime, sparse, serving) never import this module.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import time
 
 from repro.obs.metrics import MetricRegistry
-from repro.obs.trace import SpanTracer, chrome_trace_events
+from repro.obs.trace import SpanTracer, gc_annotation
 
 
 def _jsonable(v):
@@ -66,9 +74,11 @@ class RunRecorder:
     ``path`` — when given, every event is appended to the JSONL file as it
     is recorded (line-buffered via flush, so a crashed run still leaves a
     readable prefix); with ``path=None`` events stay in memory until
-    ``write``.  ``jax_annotations`` passes host span names through to
-    ``jax.profiler.TraceAnnotation``.  ``meta`` is recorded as the first
-    event (run config / shape / seed — whatever identifies the run).
+    ``write``.  ``jax_annotations`` passes host spans, with their ids,
+    through to ``jax.profiler.TraceAnnotation`` and names collector
+    pauses (``python_gc``) until ``close``.  ``meta`` is recorded as the
+    first event (run config / shape / seed — whatever identifies the
+    run).
     """
 
     def __init__(self, path: str | None = None, *,
@@ -85,6 +95,9 @@ class RunRecorder:
         self.tracer.epoch0 = self.epoch0      # one shared time origin
         self.metrics = MetricRegistry(self)
         self.ledger: list = []                # the typed events, verbatim
+        self._gc_hook = gc_annotation() if jax_annotations else None
+        if self._gc_hook is not None:
+            gc.callbacks.append(self._gc_hook)
         if meta is not None:
             self.record(type="meta", **meta)
 
@@ -165,14 +178,10 @@ class RunRecorder:
                 f.write(json.dumps(ev) + "\n")
         return path
 
-    def write_chrome_trace(self, path: str) -> str:
-        """Chrome trace-event JSON of the recorded spans + counters —
-        drag into Perfetto / chrome://tracing."""
-        with open(path, "w") as f:
-            json.dump(chrome_trace_events(self.events), f)
-        return path
-
     def close(self):
+        if self._gc_hook is not None:
+            gc.callbacks.remove(self._gc_hook)
+            self._gc_hook = None
         if self._file is not None:
             self._file.close()
             self._file = None

@@ -1,45 +1,123 @@
-"""Span tracer: host-side timed regions as nested ``span(...)`` contexts.
+"""Span tracer: host-side timed regions as nested spans with ids.
 
-``SpanTracer.span("epoch_chunk", epochs=4)`` times a ``with`` region on
+``SpanTracer.span("epoch_chunk", epochs=4)`` times a region on
 ``time.perf_counter`` and emits ONE event at exit (``type="span"`` with
-``t0``/``dur_s``/``depth``), so a span costs two clock reads plus one
-sink append — nothing on entry beyond a stack push.  Nesting is tracked
-per tracer (``depth``), which is what lets the Chrome-trace export stack
-child spans under their parents on one timeline row.
+``t0``/``dur_s``/``depth``/``id``/``parent``/``solve``), so a span costs
+two clock reads plus one sink append.  A span is entered either with
+``with`` or by hand (``__enter__``/``__exit__``, as the engine's chunk
+loop does so that its obs-off path allocates nothing).
 
-Two consumers:
-
-* the run-event log — spans interleave with metric samples and ledger
-  events in ``RunRecorder``'s ordered JSONL stream;
-* Perfetto / chrome://tracing — ``chrome_trace_events`` converts recorded
-  span events into Chrome trace-event dicts (``ph="X"`` complete events,
-  microsecond timestamps), written by ``RunRecorder.write_chrome_trace``.
+Ids: each span takes the tracer's next integer ``id`` at entry and records
+``parent``, the id of the span it was opened in (None at the top), and
+``solve``, the id of the enclosing ``REQUEST_SPAN`` (its own id for that
+span; None outside one) — the shared request id of one ``engine.solve``
+call.  A span that exits while children it opened are still open (a raise
+skipped their exits) closes them first, innermost first, so the nesting
+stack never outlives the region that owns it.
 
 ``jax_annotations=True`` additionally enters a
-``jax.profiler.TraceAnnotation(name)`` for the span's duration, so when a
-device profile is being captured the host spans line up with the XLA
-timeline; it is pass-through only (no-op without an active profiler
-session) and degrades silently when the profiler API is unavailable.
+``jax.profiler.TraceAnnotation(name, id=, parent=, solve=)`` for the
+span's duration.  Under an active profiler session it lands in the
+profiler's own trace, on the clock the device ops share, as a host event
+with the bare span name and those ids as stats; the JSONL span and the
+trace event then join by ``id``, and any joined pair gives the offset
+between the two clocks.  Without a session it is a no-op, and it degrades
+silently when the profiler API is unavailable.  ``gc_annotation`` is the
+matching hook for collector pauses (``python_gc``), registered by
+``RunRecorder``.
 """
 
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
 
-#: standard span names the engine/runtime emit (open set — callers may
-#: invent more; the report renders any name)
-WELL_KNOWN_SPANS = ("epoch_chunk", "snapshot_save", "restore", "reshard",
-                    "eval", "ingest_pass1", "ingest_pass2", "serve_batch")
+#: the span one ``engine.solve`` call opens: every span inside it carries
+#: its id as ``solve``
+REQUEST_SPAN = "solve"
 
 
-def _trace_annotation(name: str):
+def _trace_annotation(name: str, **stats):
     """``jax.profiler.TraceAnnotation`` when available, else None."""
     try:
         from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
+        return TraceAnnotation(name, **stats)
     except Exception:
         return None
+
+
+def gc_annotation():
+    """A ``gc.callbacks`` hook that brackets each collector pause in a
+    ``python_gc`` TraceAnnotation (opened on "start", closed on "stop"),
+    so a pause inside a traced region is named in the profiler's trace
+    instead of being charged to the span it interrupted."""
+    open_: list = []
+
+    def hook(phase, info):
+        if phase == "start":
+            ann = _trace_annotation("python_gc",
+                                    generation=info["generation"])
+            if ann is not None:
+                ann.__enter__()
+                open_.append(ann)
+        elif open_:
+            open_.pop().__exit__(None, None, None)
+
+    return hook
+
+
+class Span:
+    """One timed region of a ``SpanTracer``; ``id``/``parent``/``solve``
+    are set when it is entered."""
+
+    __slots__ = ("_tracer", "name", "attrs", "id", "parent", "solve",
+                 "_depth", "_ann", "_t0")
+
+    def __init__(self, tracer: "SpanTracer", name: str, attrs: dict):
+        self._tracer = tracer
+        self.name = name
+        self.attrs = attrs
+        self.id = self.parent = self.solve = None
+        self._ann = None
+
+    def __enter__(self):
+        tr = self._tracer
+        stack = tr._stack
+        top = stack[-1] if stack else None
+        self.id = tr._next_id
+        tr._next_id += 1
+        self.parent = top.id if top is not None else None
+        self.solve = (self.id if self.name == REQUEST_SPAN
+                      else top.solve if top is not None else None)
+        if tr._jax:
+            ids = {k: v for k, v in (("id", self.id),
+                                     ("parent", self.parent),
+                                     ("solve", self.solve))
+                   if v is not None}
+            self._ann = _trace_annotation(self.name, **ids)
+            if self._ann is not None:
+                self._ann.__enter__()
+        self._depth = len(stack)
+        stack.append(self)
+        self._t0 = tr._clock()
+        return self
+
+    def __exit__(self, *exc):
+        tr = self._tracer
+        stack = tr._stack
+        # children a raise left open close with (just before) their parent
+        while stack[-1] is not self:
+            stack[-1].__exit__(None, None, None)
+        dur = tr._clock() - self._t0
+        stack.pop()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if tr._sink is not None:
+            tr._sink.record(type="span", name=self.name,
+                            t0=self._t0 - tr.epoch0, dur_s=dur,
+                            depth=self._depth, id=self.id,
+                            parent=self.parent, solve=self.solve,
+                            **({"attrs": self.attrs} if self.attrs else {}))
+        return False
 
 
 class SpanTracer:
@@ -56,6 +134,7 @@ class SpanTracer:
         self._clock = clock
         self._jax = jax_annotations
         self._stack: list = []
+        self._next_id = 0
         #: origin of the tracer's relative timeline (t0 fields are offsets
         #: from this, so JSONL stays small and runs are comparable)
         self.epoch0 = clock()
@@ -64,58 +143,10 @@ class SpanTracer:
     def depth(self) -> int:
         return len(self._stack)
 
-    @contextmanager
-    def span(self, name: str, **attrs):
-        """Time a region; emits one span event at exit.
+    def span(self, name: str, **attrs) -> Span:
+        """A region to time; emits one span event at exit.
 
         ``attrs`` ride along verbatim (epoch counts, byte counts, worker
         ids) — keep them JSON-serializable.
         """
-        ann = _trace_annotation(name) if self._jax else None
-        if ann is not None:
-            ann.__enter__()
-        depth = len(self._stack)
-        t0 = self._clock()
-        self._stack.append(name)
-        try:
-            yield self
-        finally:
-            dur = self._clock() - t0
-            self._stack.pop()
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            if self._sink is not None:
-                self._sink.record(type="span", name=name,
-                                  t0=t0 - self.epoch0, dur_s=dur,
-                                  depth=depth,
-                                  **({"attrs": attrs} if attrs else {}))
-
-
-def chrome_trace_events(events, *, pid: int = 0) -> dict:
-    """Recorded run events -> Chrome trace-event JSON (Perfetto-loadable).
-
-    Span events become ``ph="X"`` complete events (timestamps in
-    microseconds, one ``tid`` per nesting depth so overlapping siblings
-    stay readable); metric events become ``ph="C"`` counter samples on the
-    same timeline, so throughput dips line up with the spans causing them.
-    Non-span, non-numeric-metric events (ledger, meta) are skipped — the
-    JSONL log is their home.
-    """
-    out = []
-    for ev in events:
-        if ev.get("type") == "span":
-            out.append({
-                "name": ev["name"], "ph": "X", "pid": pid,
-                "tid": ev.get("depth", 0),
-                "ts": round(ev["t0"] * 1e6, 3),
-                "dur": round(ev["dur_s"] * 1e6, 3),
-                "args": ev.get("attrs", {}),
-            })
-        elif ev.get("type") == "metric" and isinstance(
-                ev.get("value"), (int, float)) and "ts" in ev:
-            out.append({
-                "name": ev["name"], "ph": "C", "pid": pid, "tid": 0,
-                "ts": round(ev["ts"] * 1e6, 3),
-                "args": {ev["name"]: ev["value"]},
-            })
-    return {"traceEvents": out, "displayTimeUnit": "ms"}
+        return Span(self, name, attrs)
