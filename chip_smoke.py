@@ -6,9 +6,11 @@
 Phase A (agreement at small size): ``engine.solve`` on the chip and on the
 in-process CPU device for the dense, sparse and bucketed XLA backends and
 the dense Pallas backends, on a hinge/L2, a logistic/L2 and a square/L1
-problem; primal, dual, gap and saddle value must agree to 1e-4 relative.  The sparse
-Pallas backends must be refused with ``ValueError`` while the TPU compiler
-cannot lower their in-kernel gather and scatter-add.
+problem; primal, dual, gap and saddle value must agree to 1e-4 relative.  The
+one-hot sparse Pallas kernel (``sparse_pallas``) runs compiled on the chip
+against the same backend in the CPU's interpreter, to the same tolerance.
+The bucketed sparse Pallas backend must be refused with ``ValueError``
+while the TPU compiler cannot lower its in-kernel gather and scatter-add.
 
 Phase B (the deployment): real-sim's published shape (72,309 rows x 20,958
 features, 51 nonzeros per row, power-law column popularity alpha=1.1, hinge
@@ -18,7 +20,11 @@ evaluation every 5.  The primal must be finite and fall.
 
 ``--four-chips`` runs only ``ShardedDSO`` on a 4-chip mesh (cyclic ring,
 then lpt over point-to-point routes) against the grid simulator on one chip
-of the same process: max|dw| and max|dalpha| <= 1e-5 (Lemma 2).
+of the same process: max|dw| and max|dalpha| <= 1e-5 (Lemma 2).  It does so
+twice at real-sim's shape: with the power-law columns above (skewed tiles,
+so ``auto`` takes the K-bucketed layout) and with uniform column popularity
+(tile-K skew near 1, so ``auto`` keeps the uniform block-ELL layout, whose
+kernel on a TPU is the one-hot ``sparse_pallas``).
 
 Everything runs in this one process (a chip belongs to one process).  The
 script fails, printing no result, when JAX finds no TPU.  Its last line of
@@ -43,10 +49,15 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 #: hinge/L2 at lambda=1e-4 as ``configs.dso_problems.SVM_REALSIM``
 REALSIM = dict(m=72_309, d=20_958, nnz_per_row=51, alpha=1.1, loss="hinge",
                reg="l2", lam=1e-4, p=4)
+#: the same shape with uniform column popularity: every tile has about the
+#: same K, so ``auto`` keeps the uniform block-ELL layout
+REALSIM_UNIFORM = dict(REALSIM, alpha=0.0)
 
 PHASE_A_BACKENDS = ("dense_jnp", "sparse_jnp", "sparse_bucketed_jnp",
                     "dense_pallas_fused", "dense_pallas_block")
-SPARSE_PALLAS_BACKENDS = ("sparse_pallas", "sparse_bucketed_pallas")
+SPARSE_PALLAS_BACKENDS = ("sparse_pallas",)
+#: refused on the chip while Mosaic cannot lower their gather/scatter-add
+REFUSED_BACKENDS = ("sparse_bucketed_pallas",)
 RTOL = 1e-4          # Phase A: chip vs CPU, relative
 LEMMA2_ATOL = 1e-5   # four chips: sharded vs grid simulator, max |diff|
 
@@ -160,7 +171,7 @@ def objectives_hook(prob):
 
 
 def _solve_on(device, factory, backend: str, *, row_batches: int,
-              epochs: int):
+              epochs: int, use_adagrad: bool = True):
     import jax
 
     from repro.engine import solve
@@ -168,8 +179,8 @@ def _solve_on(device, factory, backend: str, *, row_batches: int,
     with jax.default_device(device):
         prob = factory()
         res = solve(prob, backend=backend, p=4, epochs=epochs, eta0=0.5,
-                    row_batches=row_batches, eval_every=epochs,
-                    eval_hook=objectives_hook(prob))
+                    use_adagrad=use_adagrad, row_batches=row_batches,
+                    eval_every=epochs, eval_hook=objectives_hook(prob))
     ran_on = {d.platform for d in res.w.devices()}
     if ran_on != {device.platform}:
         raise SmokeFailure(f"{backend} was asked to run on "
@@ -185,20 +196,22 @@ def _rel(a: float, b: float) -> float:
 
 
 def phase_a(chip, cpu, *, backends=PHASE_A_BACKENDS, epochs: int = 8,
-            problems=None) -> list[dict]:
+            problems=None, use_adagrad: bool = True) -> list[dict]:
     """Solve every (problem, backend) pair on ``chip`` and on ``cpu``;
     raise ``SmokeFailure`` unless primal, dual and gap agree to ``RTOL``."""
     rows = []
+    kw = dict(epochs=epochs, use_adagrad=use_adagrad)
+    step = "" if use_adagrad else " (plain step)"
     for label, factory, rb in problems or phase_a_problems():
         for be in backends:
-            got = _solve_on(chip, factory, be, row_batches=rb, epochs=epochs)
-            ref = _solve_on(cpu, factory, be, row_batches=rb, epochs=epochs)
+            got = _solve_on(chip, factory, be, row_batches=rb, **kw)
+            ref = _solve_on(cpu, factory, be, row_batches=rb, **kw)
             rel = {k: _rel(got[k], ref[k])
                    for k in ("primal", "dual", "gap", "saddle")}
             worst = max(rel.values())
             ok = (math.isfinite(got["primal"]) and math.isfinite(
                 got["saddle"]) and worst <= RTOL)
-            log(f"phase A  {label:24s} rb={rb} {be:20s} "
+            log(f"phase A  {label:24s} rb={rb} {be:20s}{step} "
                 f"{chip.platform}: primal={got['primal']:.9g} "
                 f"dual={got['dual']:.9g} gap={got['gap']:.9g} "
                 f"saddle={got['saddle']:.9g} | cpu: "
@@ -215,23 +228,31 @@ def phase_a(chip, cpu, *, backends=PHASE_A_BACKENDS, epochs: int = 8,
     return rows
 
 
-def phase_a_sparse_pallas(chip, *, problems=None) -> dict:
-    """The sparse Pallas backends on the chip: print the Mosaic probe's
-    verdict; where it refuses, asking for the backend must raise its
-    ``ValueError`` (nothing else may run in its place)."""
+def phase_a_sparse_pallas(chip, cpu, *, epochs: int = 8,
+                          problems=None) -> dict:
+    """The sparse Pallas backends on the chip: the one-hot kernel compiled
+    against the CPU's interpreter (as ``phase_a``), with the AdaGrad step
+    and with the plain step; then the Mosaic probe's verdict, and where it
+    refuses, asking for the bucketed kernel must raise its ``ValueError``
+    (nothing else may run in its place)."""
     import jax
 
     from repro.engine import solve
     from repro.kernels import ops
 
-    label, factory, _ = (problems or phase_a_problems())[0]
-    out = {}
+    problems = problems or phase_a_problems()
+    out = {r["backend"]: "agrees"
+           for use_adagrad in (True, False)
+           for r in phase_a(chip, cpu, backends=SPARSE_PALLAS_BACKENDS,
+                            epochs=epochs, problems=problems,
+                            use_adagrad=use_adagrad)}
+    label, factory, _ = problems[0]
     with jax.default_device(chip):
         verdict = ops.mosaic_sparse_gather_error()
         first = "lowers" if verdict is None else verdict.splitlines()[0]
         log(f"phase A  mosaic gather/scatter probe on {chip.platform}: "
             f"{first}")
-        for be in SPARSE_PALLAS_BACKENDS:
+        for be in REFUSED_BACKENDS:
             try:
                 solve(factory(), backend=be, p=4, epochs=1, eta0=0.5,
                       eval_hook=None)
@@ -271,24 +292,21 @@ def phase_b(*, m: int, d: int, nnz_per_row: int, alpha: float, loss: str,
     csr, y = powerlaw_csr(m, d, nnz_per_row, alpha, seed)
     t_gen = time.perf_counter() - t0
     skew = tile_k_skew(csr_k_per_tile(csr, p))
-    picked = resolve_backend("auto", csr.density, k_skew=skew)
+    layout = resolve_backend("auto", csr.density, k_skew=skew).layout
     builders = {"sparse": sparse_grid_from_csr,
                 "bucketed": bucketed_grid_from_csr}
-    if picked.layout not in builders:
-        raise SmokeFailure(f"auto picked the {picked.layout} layout for "
+    if layout not in builders:
+        raise SmokeFailure(f"auto picked the {layout} layout for "
                            f"density {csr.density:.2e}")
     t0 = time.perf_counter()
-    data = builders[picked.layout](csr, y, p)
+    data = builders[layout](csr, y, p)
     jax.block_until_ready(as_tile_data(data))
     t_tile = time.perf_counter() - t0
-    ran = resolve_backend_for_layout("auto", picked.layout)
-    if ran.name != picked.name:
-        raise SmokeFailure(f"auto resolves {picked.name} from the data but "
-                           f"{ran.name} from its layout")
+    picked = resolve_backend_for_layout("auto", layout, data.db)
     log(f"phase B  m={m} d={d} nnz={csr.nnz} ({nnz_per_row}/row) "
         f"alpha={alpha} {loss}/{reg} lam={lam} p={p} seed={seed}: "
         f"generated in {t_gen:.2f} s, tiled in {t_tile:.2f} s; "
-        f"tile-K skew {skew:.2f} -> backend {picked.name}")
+        f"tile-K skew {skew:.2f} -> backend {picked.name} (db {data.db})")
 
     evaluate = make_csr_primal_eval(csr, y, lam, loss, reg)
     p0 = float(evaluate.primal(jnp.zeros(d, jnp.float32)))
@@ -377,7 +395,8 @@ def phase_four_chips(*, m: int, d: int, nnz_per_row: int, alpha: float,
                  for dv in devices]
         ok = (dw <= LEMMA2_ATOL and da <= LEMMA2_ATOL
               and len(set(owners)) == p and np.isfinite(w_s).all())
-        log(f"four chips  {label:22s} backend={opt.backend.name} "
+        log(f"four chips  alpha={alpha} {label:22s} "
+            f"backend={opt.backend.name} "
             f"shard devices={owners} max|dw|={dw:.3e} max|dalpha|={da:.3e} "
             f"(bound {LEMMA2_ATOL}) sharded set-up+run {t_sharded:.2f} s "
             f"(one-off) peak bytes per chip={peaks} "
@@ -426,10 +445,11 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     if args.four_chips:
         phase_four_chips(**REALSIM, seed=args.seed)
+        phase_four_chips(**REALSIM_UNIFORM, seed=args.seed)
     else:
         cpu = jax.devices("cpu")[0]
         phase_a(dev, cpu)
-        phase_a_sparse_pallas(dev)
+        phase_a_sparse_pallas(dev, cpu)
         phase_b(**REALSIM, seed=args.seed)
     log(f"wall seconds {time.perf_counter() - t0:.1f}")
     print(json.dumps({"ok": True, "device": {

@@ -46,7 +46,7 @@ from repro.engine.update import eq8_apply as _eq8_apply  # noqa: F401
 
 #: run_dso_grid / ShardedDSO layout-and-kernel selectors: dense jnp tile
 #: steps, dense fused Pallas kernel, sparse (block-ELL) gather tile steps,
-#: the sparse gather Pallas kernel, and density-based automatic choice.
+#: the sparse one-hot Pallas kernel, and density-based automatic choice.
 #: Canonical engine backend names are accepted everywhere too.
 IMPLS = ("jnp", "pallas", "sparse", "sparse_pallas", "auto")
 
@@ -56,7 +56,9 @@ def resolve_impl(impl: str, density: float) -> tuple[str, str]:
 
     ``auto`` picks the sparse layout when the problem density is below
     ``sparse.format.SPARSE_DENSITY_THRESHOLD`` (the paper's datasets are
-    well below it; dense synthetic ones are not).  Unknown selectors raise
+    well below it; dense synthetic ones are not), with the layout's jnp
+    kernel: ``auto``'s kernel is chosen from the built grid
+    (``engine.resolve_backend_for_layout``).  Unknown selectors raise
     ``ValueError`` naming the registered backends.
     """
     backend = resolve_backend(impl, density)
@@ -139,7 +141,7 @@ def run_dso_grid_from_data(data, *, loss_name: str, reg_name: str,
 
 def _impl_kw(data, impl, kw):
     layout = as_tile_data(data).layout
-    backend = resolve_backend_for_layout(impl, layout)
+    backend = resolve_backend_for_layout(impl, layout, tile_dims(data)[2])
     out = dict(kw)
     out["backend"] = backend.name
     return backend, out

@@ -23,7 +23,9 @@ Registered backends:
                           folded into the kernel grid, ONE launch per block
                           (falls back to the fused-kernel scan off-shape)
   sparse_jnp            — gather/scatter tile steps on block-ELL tiles
-  sparse_pallas         — gather-based Pallas sparse kernel
+  sparse_pallas         — one-hot Pallas sparse kernel: gather and
+                          scatter-add as factored one-hot matmuls on the
+                          MXU, the active tile read in place
   sparse_bucketed_jnp   — one-kernel math on the K-bucketed ragged layout's
                           *flat chunk view* in plain jnp: chunk staging via
                           the tile's lut + the staged Eq.-(8) step
@@ -54,7 +56,8 @@ stream — which is why it also wins wall-clock in the simulator
 (``benchmarks/dso_perf.py --bucketed-onekernel``).
 
 Legacy ``impl`` selectors ("jnp", "pallas", "sparse", "sparse_pallas",
-"auto") resolve through ``resolve_backend``; unknown names raise
+"auto") resolve through ``resolve_backend``, ``auto``'s kernel once the
+grid is built through ``resolve_backend_for_layout``; unknown names raise
 ``ValueError`` listing everything registered.
 """
 
@@ -66,7 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.engine.update import block_tile_step, sparse_tile_step
-from repro.sparse.format import (BUCKET_SKEW_THRESHOLD,
+from repro.sparse.format import (BUCKET_SKEW_THRESHOLD, ONEHOT_MAX_DB,
                                  SPARSE_DENSITY_THRESHOLD)
 
 
@@ -96,11 +99,12 @@ def _sparse_select(arrays_q, blk_id, blk_cols, db):
             jax.lax.dynamic_slice(vals_q, (blk_id, 0, 0), (1, mb, K))[0])
 
 
-def _bucketed_select(arrays_q, blk_id, blk_cols, db):
-    # the bucketed tile slice is width-dependent, so the whole payload
-    # (flat chunk view or per-bucket rectangles) rides through to the block
-    # step, which picks the tile's chunks via its lut row (flat) or its
-    # lax.switch branch (buckets); only the active block id is added here
+def _payload_select(arrays_q, blk_id, blk_cols, db):
+    # the whole payload rides through to the block step with the active
+    # block id added: the one-hot sparse kernel reads the tile in place
+    # (the id drives its index map, so no tile is sliced out in HBM), and
+    # the bucketed tile slice is width-dependent, picked by the step via
+    # its lut row (flat) or its lax.switch branch (buckets)
     return tuple(arrays_q) + (blk_id,)
 
 
@@ -228,19 +232,18 @@ def _make_bucketed_block_step(sparse_block_step):
 def _sparse_pallas_block_step(meta, block, y_q, w_blk, alpha_q, gw_blk, ga_q,
                               rn_q, col_nnz_blk, trn_blk, tcn_blk, eta_t,
                               row_batches):
+    """``block`` is ``(cols_q, vals_q, blk_id)`` (the processor's payload
+    and its active tile) or, from the bucket switch, one sliced
+    ``(cols_blk, vals_blk)`` tile."""
     from repro.kernels import ops
     lam, m, loss_name, reg_name, use_adagrad, w_lo, w_hi = meta
-    if not use_adagrad:
-        raise NotImplementedError(
-            "the sparse Pallas kernel implements the AdaGrad step; use "
-            "sparse_jnp for use_adagrad=False")
-    cols_blk, vals_blk = block
+    cols, vals, *blk_id = block
     scalars = jnp.stack([eta_t, lam, m, w_lo, w_hi]).astype(jnp.float32)
-    w_blk, alpha_q, gw_blk, ga_q = ops.dso_sparse_block_step(
-        cols_blk, vals_blk, y_q, w_blk, alpha_q, gw_blk, ga_q, trn_blk,
-        tcn_blk, rn_q, col_nnz_blk, scalars, row_batches=row_batches,
-        loss_name=loss_name, reg_name=reg_name)
-    return w_blk, alpha_q, gw_blk, ga_q
+    return ops.dso_sparse_block_step(
+        cols, vals, y_q, w_blk, alpha_q, gw_blk, ga_q, trn_blk, tcn_blk,
+        rn_q, col_nnz_blk, scalars, row_batches=row_batches,
+        loss_name=loss_name, reg_name=reg_name, use_adagrad=use_adagrad,
+        blk_id=blk_id[0] if blk_id else None)
 
 
 def _bucketed_flat_args(meta, block):
@@ -333,7 +336,7 @@ def resolve_backend(impl, density: float | None = None, *,
                     k_skew: float | None = None) -> TileBackend:
     """``impl`` selector (canonical or legacy) + problem stats -> backend.
 
-    ``auto`` picks the sparse layout when the problem density is below
+    ``auto`` picks the layout: sparse when the problem density is below
     ``sparse.format.SPARSE_DENSITY_THRESHOLD`` (the paper's datasets are
     well below it; dense synthetic ones are not); within the sparse
     regime, a per-tile-K skew (``sparse.format.tile_k_skew``) at or above
@@ -341,8 +344,10 @@ def resolve_backend(impl, density: float | None = None, *,
     (power-law feature distributions, where uniform max-K padding
     dominates the packed bytes).  ``k_skew=None`` means the caller did not
     probe the skew — ``auto`` then stays on the uniform sparse layout.
-    Unknown names raise ``ValueError`` listing the registry — nothing
-    falls through silently.
+    For ``auto`` the backend returned is the layout's jnp one: the
+    layout's kernel is chosen from the built grid, by
+    ``resolve_backend_for_layout``.  Unknown names raise ``ValueError``
+    listing the registry — nothing falls through silently.
     """
     if isinstance(impl, TileBackend):
         return impl
@@ -371,18 +376,33 @@ _LAYOUT_KERNELS = {
 }
 
 
-def resolve_backend_for_layout(impl, layout: str) -> TileBackend:
-    """Backend for pre-built grid data whose layout is already fixed.
+def _auto_kernel(layout: str, db: int) -> str:
+    """``auto``'s kernel for a built grid: on the uniform sparse layout the
+    one-hot Pallas kernel where the computation runs on a TPU and the block
+    is at most ``ONEHOT_MAX_DB`` columns wide, else XLA's gather and
+    scatter-add; the jnp kernel of the other layouts."""
+    if layout == "sparse" and db <= ONEHOT_MAX_DB:
+        from repro.kernels import ops
+        if ops._on_tpu():
+            return "sparse_pallas"
+    return _LAYOUT_KERNELS["jnp"][layout]
 
-    Legacy *kernel* selectors ("jnp"/"pallas"/"auto") pick the layout's
-    backend of that kernel; canonical names must match the data's layout
-    (a dense grid cannot run a sparse backend and vice versa).
+
+def resolve_backend_for_layout(impl, layout: str, db: int) -> TileBackend:
+    """Backend for built grid data: its layout is fixed and ``db`` is its
+    block width (columns per processor).
+
+    ``auto`` chooses the layout's kernel here, and only here
+    (``_auto_kernel``: platform and ``db``).  The legacy kernel selectors
+    "jnp"/"pallas" pick the layout's backend of that kernel; canonical
+    names must match the data's layout (a dense grid cannot run a sparse
+    backend and vice versa).
     """
     if not isinstance(impl, TileBackend):
-        if impl in ("auto", "jnp"):
-            return _BACKENDS[_LAYOUT_KERNELS["jnp"][layout]]
-        if impl == "pallas":
-            return _BACKENDS[_LAYOUT_KERNELS["pallas"][layout]]
+        if impl == "auto":
+            return _BACKENDS[_auto_kernel(layout, db)]
+        if impl in ("jnp", "pallas"):
+            return _BACKENDS[_LAYOUT_KERNELS[impl][layout]]
     backend = resolve_backend(impl)
     if backend.layout != layout:
         raise ValueError(
@@ -401,17 +421,17 @@ register_backend(TileBackend("dense_pallas_block", "dense", _dense_select,
                              _make_dense_pallas_block_step(force_scan=False)))
 register_backend(TileBackend("sparse_jnp", "sparse", _sparse_select,
                              _sparse_jnp_block_step))
-register_backend(TileBackend("sparse_pallas", "sparse", _sparse_select,
+register_backend(TileBackend("sparse_pallas", "sparse", _payload_select,
                              _sparse_pallas_block_step))
 register_backend(TileBackend(
-    "sparse_bucketed_jnp", "bucketed", _bucketed_select,
+    "sparse_bucketed_jnp", "bucketed", _payload_select,
     _make_bucketed_flat_block_step(use_pallas=False)))
 register_backend(TileBackend(
-    "sparse_bucketed_pallas", "bucketed", _bucketed_select,
+    "sparse_bucketed_pallas", "bucketed", _payload_select,
     _make_bucketed_flat_block_step(use_pallas=True)))
 register_backend(TileBackend(
-    "sparse_bucketed_jnp_switch", "bucketed", _bucketed_select,
+    "sparse_bucketed_jnp_switch", "bucketed", _payload_select,
     _make_bucketed_block_step(_sparse_jnp_block_step), payload="buckets"))
 register_backend(TileBackend(
-    "sparse_bucketed_pallas_switch", "bucketed", _bucketed_select,
+    "sparse_bucketed_pallas_switch", "bucketed", _payload_select,
     _make_bucketed_block_step(_sparse_pallas_block_step), payload="buckets"))

@@ -57,18 +57,20 @@ class SolveResult(NamedTuple):
 
 def resolve_backend_and_build(prob, impl, p: int, row_batches: int):
     """The one auto-probe + layout-builder dispatch behind both drivers
-    (``solve`` and ``core.dso_dist.ShardedDSO``): resolve the backend —
+    (``solve`` and ``core.dso_dist.ShardedDSO``): resolve the layout —
     probing the per-tile-K skew only when ``auto`` is already in the
     sparse density regime (the probe is a host pass over the nonzero
-    pattern) — then build the grid in that backend's layout."""
+    pattern) — build the grid in it, then resolve the backend for the
+    built grid (``auto``'s kernel is chosen there)."""
     k_skew = (tile_k_skew(problem_k_per_tile(prob, p))
               if impl == "auto"
               and density(prob) < SPARSE_DENSITY_THRESHOLD else None)
-    be = resolve_backend(impl, density(prob), k_skew=k_skew)
+    layout = resolve_backend(impl, density(prob), k_skew=k_skew).layout
     builders = {"dense": make_grid_data,
                 "sparse": make_sparse_grid_data,
                 "bucketed": make_bucketed_grid_data}
-    return be, builders[be.layout](prob, p, row_batches)
+    data = builders[layout](prob, p, row_batches)
+    return resolve_backend_for_layout(impl, layout, tile_dims(data)[2]), data
 
 
 # ----------------------------------------------------- inner iteration --
@@ -491,13 +493,16 @@ def solve(source, *, backend="auto", schedule="cyclic", p: int = 4,
                                  f"requires {missing} (no Problem to read "
                                  f"them from)")
             be = resolve_backend_for_layout(backend,
-                                            as_tile_data(data).layout)
+                                            as_tile_data(data).layout,
+                                            tile_dims(data)[2])
             loss = get_loss(loss_name)
             box = loss.w_box(lam) if loss.w_box is not None else np.inf
             lam_f, m_f = jnp.float32(lam), jnp.float32(m)
             w_lo, w_hi = jnp.float32(-box), jnp.float32(box)
             if eval_hook == "auto":
                 eval_hook = None
+        if obs is not None:    # which tile kernel this solve runs
+            span.set(backend=be.name)
         check_tile_stats(data, row_batches)
         tile = as_tile_data(data, bucketed_payload=be.payload)
         p_, mb_, db = tile_dims(tile)

@@ -1,39 +1,61 @@
-"""Gather-based Pallas kernel for the sparse (block-ELL) DSO tile step.
+"""Pallas kernels for the sparse (block-ELL) DSO tile step.
 
-Mirrors the dense ``_fused_block_kernel`` of ``dso_update.py`` on the packed
-tile format of ``repro.sparse.format``: one launch covers the whole active
-block, with the ``row_batches`` sub-scan folded into the kernel grid and the
-travelling w block + its AdaGrad accumulator living in VMEM scratch across
-the launch.  The difference is what streams from HBM: instead of the dense
-(mb, db) X block (4*mb*db bytes), the kernel reads the packed (mb, K)
-column-index + value arrays — 8*mb*K bytes, nnz-proportional (K is the
-padded max row nnz of the tile, sublane-aligned; sparse.format.choose_k).
+Uniform layout (``sparse_pallas``): the one-hot tile step
+----------------------------------------------------------
 
-Data flow per grid step ``mi`` (row tiles = sequential minibatch steps):
+One launch runs every ``row_batches`` sequential Eq.-(8) step of the active
+block of each processor, reading the packed (M, K) ``cols``/``vals`` tile
+straight from the grid (the block id is a prefetched scalar of the index
+map, so no tile is sliced out in HBM).  No index op is involved: the gather
+of the travelling w block and the scatter-add of its gradient are one-hot
+matmuls on the MXU.  A block-local column id ``c`` is factored as
+``hi = c >> 7``, ``lo = c & 127``, and w is viewed as ``W2 = (H, 128)``
+(H = ceil(db / 128), padded to a multiple of 16):
 
-    cols (rb, K) i32 ──┐          packed tile: the ONLY HBM matrix read
-    vals (rb, K) f32 ──┤          (8*rb*K bytes vs dense 4*rb*db)
-                       ├─> gather   sum_k vals*w_st[cols]  -> X w    (rb, 1)
-    w_st (1, db) VMEM ─┤               └ dual update of this alpha slice
-                       └─> scatter  add   vals*alpha at cols -> X^T a (1, db)
-    alpha (rb, 1) ─────┘               └ primal update, w_st advances
+    gather   w[c]         = sum_h [hi == h] * (W2 @ onehot_lo)[h]
+    scatter  (X^T v)[h, l] = sum_s [hi_s == h] * v_s * [lo_s == l]
+                           = ((onehot_hi * v) @ onehot_lo^T)[h, l]
 
-Both mat-vecs read the *pre-update* (w_st, alpha) of the step — the same
-Jacobi/Lemma-2 form as the dense kernels — so a ``row_batches=1`` launch is
-exactly the fused tile step and the general case equals scanning
-``core.dso.sparse_tile_step`` (which in turn equals the dense
-``block_tile_step`` to float32 reduction order).
+Exact in float32: the float32 operand (w for the gather, ``vals * alpha``
+for the scatter) is split into three bfloat16 parts whose float32 sum is
+the operand exactly, stacked as 3H rows of ONE default-precision bfloat16
+matmul with a float32 accumulator, and the three parts are summed after
+it.  A one-hot is exact in bfloat16, so every gathered value is ``w[c]``
+bit for bit; the scatter sums in float32 like XLA's scatter-add, in
+another order.
 
-The in-kernel gather (``jnp.take`` of the w block at a 2-D index array) and
-scatter-add (``.at[].add``) run only under ``interpret=True``: the TPU
-compiler (Mosaic) refuses both ("Only 2D gather is supported"), so on a TPU
-the ``ops`` wrappers raise ``ValueError`` after the
-``ops.mosaic_sparse_gather_error`` probe instead of compiling these kernels.
-The XLA backends (``sparse_jnp`` / ``sparse_bucketed_jnp``) run the same
-nnz-proportional math through XLA's native gather and scatter.
+Layout.  On a TPU the grid's (..., M, K) arrays are stored with the row
+axis minor (K = 32 would waste 3/4 of every 128-lane row otherwise), so the
+kernel reads their free (K, M) transpose: slots and rows are lane-dense,
+one 128-lane row holds one slot of 128 rows, and a row's sum over its K
+slots is a sublane sum.  Per-row vectors are (1, M) lane-dense rows.
 
-The per-tile nonzero counts are precomputed (``SparseGridData``) and passed
-in, exactly like the dense kernels.
+    cols/vals (K, C) ──> per 128 rows, per slot row k (1, 128):
+       onehot_lo (128, 128) bf16, [hi == h] (H, 128)
+       gather:  W3 (3H, 128) bf16 @ onehot_lo -> sum parts, select hi
+                xw += vals_k * w[c_k]                        (1, 128)
+       scatter: acc (3H, 128) += ([hi == h] * split3(vals_k * alpha))
+                                 @ onehot_lo^T
+    dual update of the 128 rows (pre-update w and alpha)
+    primal update at the end of each row batch (acc summed over parts)
+
+grid = (processors, row chunks of C lanes); w, gw, the split w and the
+accumulator stay in VMEM for the whole launch, and each row batch's column
+counts are copied in from HBM when the batch ends, so the kernel's VMEM
+does not grow with ``row_batches``.  Rows are walked in order
+and every row of a row batch reads the batch's pre-update (w, alpha), the
+Jacobi / Lemma-2 form of ``core.dso.sparse_tile_step``, so the kernel
+equals scanning that step over the row batches.  Rows past
+``row_batches * (M // row_batches)`` pass through unchanged.  Under
+``vmap`` (the grid simulator's processors) the kernel takes the batch as
+its leading grid axis (``custom_vmap``), so the processors' tiles are
+still read in place.
+
+K-bucketed layout (``sparse_bucketed_pallas``) — further below — still
+gathers with ``jnp.take`` and scatter-adds with ``.at[].add``; the TPU
+compiler (Mosaic) refuses both ("Only 2D gather is supported"), so on a
+TPU the ``ops`` wrapper raises ``ValueError`` after the
+``ops.mosaic_sparse_gather_error`` probe instead of compiling it.
 """
 
 from __future__ import annotations
@@ -47,112 +69,246 @@ from jax.experimental import pallas as pl
 from repro.kernels.dso_update import _dual_update, _primal_update
 from repro.sparse.format import K_CHUNK
 
+_LANES = 128
+#: rows per grid step of the one-hot kernel (a multiple of 128 lanes), at
+#: most: fewer for wide tiles, so that the double-buffered cols/vals blocks
+#: stay within ``_SLOT_BLOCK_BYTES`` of VMEM
+_ROW_CHUNK = 1024
+_SLOT_BLOCK_BYTES = 4 << 20
 
-def _sparse_block_kernel(cols_ref, vals_ref, y_ref, w_ref, alpha_ref,
-                         gw_ref, ga_ref, trn_ref, tcn_ref, rn_ref, cn_ref,
-                         scal_ref, w_out_ref, a_out_ref, gw_out_ref,
-                         ga_out_ref, w_st_ref, gw_st_ref,
-                         *, loss_name: str, reg_name: str):
-    """One active block: each grid step is one sequential minibatch step on
-    a packed (rb, K) row tile; the whole block width db sits in VMEM."""
-    mi = pl.program_id(0)   # row tiles = sequential minibatch steps
 
-    @pl.when(mi == 0)
+def _split3(x):
+    """float32 ``x`` as the float32 values of three bfloat16 parts whose
+    sum ``hi + (mid + lo)`` is ``x`` exactly (8 + 8 + 8 significant
+    bits)."""
+    hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+    r = x - hi
+    mid = r.astype(jnp.bfloat16).astype(jnp.float32)
+    lo = (r - mid).astype(jnp.bfloat16).astype(jnp.float32)
+    return hi, mid, lo
+
+
+def _sum3(x, h: int):
+    """(3h, 128) stacked parts -> (h, 128) float32 sum, hi + (mid + lo)."""
+    return x[:h] + (x[h:2 * h] + x[2 * h:])
+
+
+def _slot_row(w3, col, val, a_m, h: int):
+    """One slot row of 128 rows: the gathered ``w[col]`` (1, 128) and the
+    stacked (3h, 128) scatter-add of ``val * a_m`` at ``col``."""
+    iota_l = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+    iota_h = jax.lax.broadcasted_iota(jnp.int32, (h, _LANES), 0)
+    onehot = jnp.where(iota_l == (col & (_LANES - 1)), 1.0, 0.0) \
+        .astype(jnp.bfloat16)                              # [lo, slot]
+    sel = jnp.where(iota_h == (col >> 7), 1.0, 0.0)        # [hi, slot]
+    g = jax.lax.dot(w3, onehot, preferred_element_type=jnp.float32)
+    gathered = jnp.sum(sel * _sum3(g, h), axis=0, keepdims=True)
+    parts = jnp.concatenate([sel * p for p in _split3(val * a_m)],
+                            axis=0).astype(jnp.bfloat16)
+    scattered = jax.lax.dot_general(parts, onehot, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+    return gathered, scattered
+
+
+def _onehot_kernel(blk_ref, cols_ref, vals_ref, y_ref, a_ref, ga_ref,
+                   trn_ref, rn_ref, w_ref, gw_ref, tcn_ref, cn_ref, scal_ref,
+                   w_out_ref, gw_out_ref, a_out_ref, ga_out_ref,
+                   w_st, gw_st, w3_st, acc_st, tcn_st,
+                   *, rb: int, n_rb: int, loss_name: str, reg_name: str,
+                   use_adagrad: bool):
+    """One chunk of C rows of one processor's active tile; the processor's
+    w state lives in the scratch refs from its first chunk on.  ``tcn_ref``
+    stays in HBM: each row batch's (h, 128) row is copied in when the batch
+    ends, so fast memory does not grow with ``row_batches``."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    del blk_ref                        # consumed by the index maps
+    q, c = pl.program_id(0), pl.program_id(1)
+    h = w_st.shape[0]
+    K, C = cols_ref.shape
+
+    def load_w(w):
+        w_st[...] = w
+        w3_st[...] = jnp.concatenate(_split3(w), axis=0) \
+            .astype(jnp.bfloat16)
+
+    @pl.when(c == 0)
     def _load_state():
-        w_st_ref[...] = w_ref[...]
-        gw_st_ref[...] = gw_ref[...]
+        load_w(w_ref[...])
+        gw_st[...] = gw_ref[...]
+        acc_st[...] = jnp.zeros_like(acc_st)
 
-    cols = cols_ref[...]                # (rb, K) int32 — packed tile read
-    vals = vals_ref[...]                # (rb, K), 0.0 in padding slots
-    a = alpha_ref[...]                  # (rb, 1), pre-update
-    w = w_st_ref[...]                   # (1, db), state BEFORE this step
+    scal = scal_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
 
-    # dual mat-vec: gather the travelling w at the packed column indices
-    # (padding gathers w[0] * 0 = 0 exactly)
-    xw = jnp.sum(vals * jnp.take(w[0], cols, axis=0), axis=1,
-                 keepdims=True)         # (rb, 1) partial X w
-    a_new, ga_new = _dual_update(
-        loss_name, a, ga_ref[...], y_ref[...], xw, trn_ref[...],
-        rn_ref[...], scal_ref[...])
-    a_out_ref[...] = a_new
-    ga_out_ref[...] = ga_new
+    def slot_rows(k0, sl, in_b, a_m, xw):
+        """Eight slot rows k0..k0+7 of 128 rows: gather into ``xw``,
+        scatter-add ``vals * a_m`` into the accumulator."""
+        cols8 = jnp.where(in_b, cols_ref[pl.ds(k0, 8), sl], 0)
+        vals8 = jnp.where(in_b, vals_ref[pl.ds(k0, 8), sl], 0.0)
+        w3 = w3_st[...]
+        acc = jnp.zeros(acc_st.shape, jnp.float32)
+        for i in range(8):
+            val = vals8[i:i + 1]                               # (1, 128)
+            gathered, scattered = _slot_row(w3, cols8[i:i + 1], val, a_m, h)
+            xw = xw + val * gathered
+            acc = acc + scattered
+        acc_st[...] += acc
+        return xw
 
-    # primal mat-vec: scatter-add vals * alpha into the w-block accumulator
-    # (padding adds 0 at column 0 — a no-op)
-    acc = jnp.zeros_like(w).at[0, cols.reshape(-1)] \
-        .add((vals * a).reshape(-1))    # (1, db) X^T alpha of this tile
-    w_new, gw_new = _primal_update(
-        reg_name, w, gw_st_ref[...], acc, tcn_ref[...], cn_ref[...],
-        scal_ref[...])
-    w_st_ref[...] = w_new
-    gw_st_ref[...] = gw_new
-    w_out_ref[...] = w_new              # last row tile's flush is the result
-    gw_out_ref[...] = gw_new
+    def rows128(j, carry):
+        off = pl.multiple_of(j * _LANES, _LANES)
+        sl = pl.ds(off, _LANES)
+        r0 = c * C + off
+        rows = r0 + lane
+        a_pre, ga_pre = a_ref[:, sl], ga_ref[:, sl]
+        a_out_ref[:, sl] = a_pre           # rows of no batch pass through
+        ga_out_ref[:, sl] = ga_pre
+
+        def batch(s, carry):
+            in_b = (rows >= s * rb) & (rows < (s + 1) * rb)
+            a_m = jnp.where(in_b, a_pre, 0.0)
+            xw = jax.lax.fori_loop(
+                0, K // 8,
+                lambda g, xw: slot_rows(pl.multiple_of(g * 8, 8), sl, in_b,
+                                        a_m, xw),
+                jnp.zeros((1, _LANES), jnp.float32))
+            a_new, ga_new = _dual_update(
+                loss_name, a_pre, ga_pre, y_ref[:, sl], xw, trn_ref[:, sl],
+                rn_ref[:, sl], scal, use_adagrad)
+            a_out_ref[:, sl] = jnp.where(in_b, a_new, a_out_ref[:, sl])
+            ga_out_ref[:, sl] = jnp.where(in_b, ga_new, ga_out_ref[:, sl])
+
+            @pl.when((s + 1) * rb <= r0 + _LANES)
+            def _primal():             # batch s ends in these 128 rows
+                pltpu.sync_copy(tcn_ref.at[q, s], tcn_st)
+                w_new, gw_new = _primal_update(
+                    reg_name, w_st[...], gw_st[...], _sum3(acc_st[...], h),
+                    tcn_st[...], cn_ref[...], scal, use_adagrad)
+                load_w(w_new)
+                gw_st[...] = gw_new
+                acc_st[...] = jnp.zeros_like(acc_st)
+
+            return carry
+
+        s_lo = r0 // rb
+        s_hi = jnp.minimum((r0 + _LANES - 1) // rb, n_rb - 1)
+        return jax.lax.fori_loop(s_lo, s_hi + 1, batch, carry)
+
+    jax.lax.fori_loop(0, C // _LANES, rows128, 0)
+    w_out_ref[...] = w_st[...]
+    gw_out_ref[...] = gw_st[...]
+
+
+def _onehot_call(cols, vals, blk, y, w, alpha, gw, ga, trn, tcn, rn, cn,
+                 scalars, *, row_batches: int, loss_name: str,
+                 reg_name: str, use_adagrad: bool, interpret: bool):
+    """The kernel over P processors at once: cols/vals (P, n_blk, M, K),
+    blk (P,) active tile of each, w/gw/cn (P, db), y/alpha/ga/trn/rn
+    (P, M), tcn (P, row_batches, db), scalars (P, 5)."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    P, _, M, K = cols.shape
+    db = w.shape[1]
+    rb = M // row_batches
+    if rb < 1 or K % 8:
+        raise ValueError(f"the one-hot kernel needs row_batches <= M and K "
+                         f"a multiple of 8 (sparse.format.choose_k); got "
+                         f"M={M}, row_batches={row_batches}, K={K}")
+    h = -(-db // _LANES)
+    h = -(-h // 16) * 16               # bf16 packs 16 rows per vreg
+    dp = h * _LANES
+    C = _LANES * max(1, min(_ROW_CHUNK, -(-M // _LANES) * _LANES,
+                            _SLOT_BLOCK_BYTES // (16 * K)) // _LANES)
+
+    def wide(x, fill):                 # (P, ..., db) -> (P, ..., h, 128)
+        pad = [(0, 0)] * (x.ndim - 1) + [(0, dp - db)]
+        x = jnp.pad(x.astype(jnp.float32), pad, constant_values=fill)
+        return x.reshape(*x.shape[:-1], h, _LANES)
+
+    def row(x):                        # (P, M) -> (P, 1, M), lane-dense
+        return x.astype(jnp.float32).reshape(P, 1, M)
+
+    slots = pl.BlockSpec((None, None, K, C),
+                         lambda q, c, blk: (q, blk[q], 0, c))
+    rows = pl.BlockSpec((None, 1, C), lambda q, c, blk: (q, 0, c))
+    wvec = pl.BlockSpec((None, h, _LANES), lambda q, c, blk: (q, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(P, pl.cdiv(M, C)),
+        in_specs=[slots, slots, rows, rows, rows, rows, rows, wvec, wvec,
+                  pl.BlockSpec(memory_space=pl.ANY),        # tcn, in HBM
+                  wvec,
+                  pl.BlockSpec((None, 1, 5), lambda q, c, blk: (q, 0, 0))],
+        out_specs=[wvec, wvec, rows, rows],
+        scratch_shapes=[pltpu.VMEM((h, _LANES), jnp.float32),
+                        pltpu.VMEM((h, _LANES), jnp.float32),
+                        pltpu.VMEM((3 * h, _LANES), jnp.bfloat16),
+                        pltpu.VMEM((3 * h, _LANES), jnp.float32),
+                        pltpu.VMEM((h, _LANES), jnp.float32)])
+    w2, gw2, a2, ga2 = pl.pallas_call(
+        functools.partial(_onehot_kernel, rb=rb, n_rb=row_batches,
+                          loss_name=loss_name, reg_name=reg_name,
+                          use_adagrad=use_adagrad),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((P, h, _LANES), jnp.float32)] * 2
+        + [jax.ShapeDtypeStruct((P, 1, M), jnp.float32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(blk.astype(jnp.int32), jnp.swapaxes(cols, -1, -2),
+      jnp.swapaxes(vals, -1, -2), row(y), row(alpha), row(ga), row(trn),
+      row(rn), wide(w, 0.0), wide(gw, 0.0), wide(tcn, 0.0), wide(cn, 1.0),
+      scalars.astype(jnp.float32).reshape(P, 1, 5))
+    return (w2.reshape(P, dp)[:, :db], a2.reshape(P, M),
+            gw2.reshape(P, dp)[:, :db], ga2.reshape(P, M))
+
+
+@functools.lru_cache(maxsize=None)
+def _onehot_step(row_batches: int, loss_name: str, reg_name: str,
+                 use_adagrad: bool, interpret: bool):
+    """One processor's block step; under ``vmap`` the batch becomes the
+    kernel's leading grid axis instead of a loop over copied slices."""
+    call = functools.partial(_onehot_call, row_batches=row_batches,
+                             loss_name=loss_name, reg_name=reg_name,
+                             use_adagrad=use_adagrad, interpret=interpret)
+
+    @jax.custom_batching.custom_vmap
+    def step(*args):
+        return tuple(o[0] for o in call(*(a[None] for a in args)))
+
+    @step.def_vmap
+    def _batched(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size, *a.shape))
+                for a, b in zip(args, in_batched)]
+        return call(*args), (True,) * 4
+
+    return step
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("row_batches", "loss_name", "reg_name", "interpret"))
-def dso_sparse_block_step_pallas(cols, vals, y, w, alpha, gw, ga,
+    jax.jit, static_argnames=("row_batches", "loss_name", "reg_name",
+                              "use_adagrad", "interpret"))
+def dso_sparse_block_step_pallas(cols, vals, blk, y, w, alpha, gw, ga,
                                  tile_row_nnz, tile_col_nnz, row_nnz,
                                  col_nnz, scalars, *, row_batches: int,
                                  loss_name: str, reg_name: str,
-                                 interpret: bool):
-    """All ``row_batches`` sequential tile steps of one active block from
-    its packed block-ELL tile.  cols/vals (M, K) with block-local column
-    indices; w/gw/col_nnz (db,); alpha/ga/y/row_nnz/tile_row_nnz (M,);
-    ``tile_col_nnz`` (row_batches, db); scalars = [eta, lam, m, w_lo, w_hi].
+                                 interpret: bool, use_adagrad: bool = True):
+    """All ``row_batches`` sequential tile steps of one active block, read
+    in place from a processor's packed tiles.  cols/vals (n_blk, M, K) with
+    block-local column indices, ``blk`` () the active tile; w/gw/col_nnz
+    (db,); alpha/ga/y/row_nnz/tile_row_nnz (M,); ``tile_col_nnz``
+    (row_batches, db); scalars = [eta, lam, m, w_lo, w_hi].
 
-    M % row_batches == 0 (the ops wrapper truncates like the dense path).
-    Equivalent to scanning ``core.dso.sparse_tile_step`` over the row tiles.
+    Equivalent to scanning ``core.dso.sparse_tile_step`` over the row
+    batches of ``M // row_batches`` rows, with the AdaGrad step or, for
+    ``use_adagrad=False``, the plain ``eta`` step; trailing rows pass
+    through.
     """
-    M, K = cols.shape
-    db = w.shape[0]
-    assert M % row_batches == 0, (M, row_batches)
-    bm = M // row_batches
-    n_mt = row_batches
-
-    import jax.experimental.pallas.tpu as pltpu
-    scratch = [pltpu.VMEM((1, db), jnp.float32),   # travelling w state
-               pltpu.VMEM((1, db), jnp.float32)]   # its AdaGrad acc
-    w2, a2, gw2, ga2 = pl.pallas_call(
-        functools.partial(_sparse_block_kernel, loss_name=loss_name,
-                          reg_name=reg_name),
-        grid=(n_mt,),
-        in_specs=[
-            pl.BlockSpec((bm, K), lambda mi: (mi, 0)),    # cols
-            pl.BlockSpec((bm, K), lambda mi: (mi, 0)),    # vals
-            pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # y
-            pl.BlockSpec((1, db), lambda mi: (0, 0)),     # w
-            pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # alpha
-            pl.BlockSpec((1, db), lambda mi: (0, 0)),     # gw
-            pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # ga
-            pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # tile row nnz
-            pl.BlockSpec((None, 1, db), lambda mi: (mi, 0, 0)),  # t col nnz
-            pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # |Omega_i|
-            pl.BlockSpec((1, db), lambda mi: (0, 0)),     # |Omega-bar_j|
-            pl.BlockSpec((1, 5), lambda mi: (0, 0)),      # scalars
-        ],
-        out_specs=[
-            pl.BlockSpec((1, db), lambda mi: (0, 0)),     # w
-            pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # alpha
-            pl.BlockSpec((1, db), lambda mi: (0, 0)),     # gw
-            pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),    # ga
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, db), jnp.float32),
-            jax.ShapeDtypeStruct((M, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, db), jnp.float32),
-            jax.ShapeDtypeStruct((M, 1), jnp.float32),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(cols, vals, y.reshape(M, 1), w.reshape(1, db), alpha.reshape(M, 1),
-      gw.reshape(1, db), ga.reshape(M, 1),
-      tile_row_nnz.reshape(M, 1).astype(jnp.float32),
-      tile_col_nnz.reshape(n_mt, 1, db).astype(jnp.float32),
-      row_nnz.reshape(M, 1), col_nnz.reshape(1, db), scalars.reshape(1, 5))
-    return (w2.reshape(db), a2.reshape(M), gw2.reshape(db), ga2.reshape(M))
+    return _onehot_step(row_batches, loss_name, reg_name, use_adagrad,
+                        interpret)(
+        cols, vals, blk, y, w, alpha, gw, ga, tile_row_nnz, tile_col_nnz,
+        row_nnz, col_nnz, scalars)
 
 
 # --------------------------------------------------------------------------

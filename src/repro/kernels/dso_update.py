@@ -45,10 +45,11 @@ every step of every epoch.
 in VMEM scratch across the whole launch and are updated after every row
 tile), so one launch covers the whole active block.
 
-Sparse gather variant (``kernels/dso_sparse.py``) — same fused block step
-on the packed block-ELL tiles of ``repro.sparse.format``, where the dense
-(bm, bd) X read is replaced by the (bm, K) cols+vals arrays (K = padded max
-row nnz), making the streamed bytes nnz-proportional:
+Sparse variant (``kernels/dso_sparse.py``) — same fused block step on the
+packed block-ELL tiles of ``repro.sparse.format``, where the dense (bm, bd)
+X read is replaced by the (bm, K) cols+vals arrays (K = padded max row
+nnz), making the streamed bytes nnz-proportional; the gather of w and the
+scatter-add of X^T alpha run as factored one-hot matmuls on the MXU:
 
     cols (bm, K) i32 ──┐   packed tile, read ONCE (8*bm*K B vs 4*bm*bd B)
     vals (bm, K) f32 ──┤
@@ -150,20 +151,28 @@ def _project_alpha(loss_name: str, a, y):
     return a
 
 
-def _primal_update(reg_name: str, w, gw, acc, tcn, cn, scal):
-    """Eq. (8) primal side + AdaGrad + App. B box projection."""
+def _primal_update(reg_name: str, w, gw, acc, tcn, cn, scal,
+                   use_adagrad: bool = True):
+    """Eq. (8) primal side + AdaGrad (or the plain eta_t step, ``gw``
+    unchanged, as ``engine.update.eq8_apply``) + App. B box projection."""
     eta, lam, m = scal[0, 0], scal[0, 1], scal[0, 2]
     w_lo, w_hi = scal[0, 3], scal[0, 4]
     g_w = lam * _reg_grad(reg_name, w) * tcn / cn - acc / m
+    if not use_adagrad:
+        return jnp.clip(w - eta * g_w, w_lo, w_hi), gw
     gw_new = gw + g_w * g_w
     dw = eta * g_w * jax.lax.rsqrt(gw_new + _ADA_EPS)
     return jnp.clip(w - dw, w_lo, w_hi), gw_new
 
 
-def _dual_update(loss_name: str, a, ga, y, acc, trn, rn, scal):
-    """Eq. (8) dual side + AdaGrad + App. B domain projection."""
+def _dual_update(loss_name: str, a, ga, y, acc, trn, rn, scal,
+                 use_adagrad: bool = True):
+    """Eq. (8) dual side + AdaGrad (or the plain eta_t step, ``ga``
+    unchanged) + App. B domain projection."""
     eta, m = scal[0, 0], scal[0, 2]
     g_a = -_dual_grad(loss_name, a, y) * trn / (m * rn) - acc / m
+    if not use_adagrad:
+        return _project_alpha(loss_name, a + eta * g_a, y), ga
     ga_new = ga + g_a * g_a
     da = eta * g_a * jax.lax.rsqrt(ga_new + _ADA_EPS)
     return _project_alpha(loss_name, a + da, y), ga_new

@@ -190,7 +190,8 @@ def dso_block_step(X, y, w, alpha, gw, ga, tile_row_nnz, tile_col_nnz,
 
 def mosaic_sparse_gather_error() -> str | None:
     """Probe the platform the next computation runs on (``_platform``) for
-    the sparse kernels' gating ops (2-D gather + scatter-add).  Returns
+    the bucketed sparse kernel's gating ops (2-D gather + scatter-add; the
+    uniform kernel needs neither).  Returns
     ``None`` when it lowers them, else the lowering error string — the ROADMAP
     "Mosaic-native scatter/gather" seam: fall back LOUDLY instead of
     surfacing an opaque Mosaic error from inside the real kernel.
@@ -209,8 +210,9 @@ def _mosaic_sparse_gather_error(platform: str) -> str | None:
     cache key merely scopes the verdict).
 
     Compiles (and runs) a minimal Pallas kernel exercising exactly what
-    ``kernels/dso_sparse.py`` needs beyond the dense kernels: a 2-D gather
-    from a VMEM vector and a scatter-add back into it.
+    the bucketed kernel of ``kernels/dso_sparse.py`` needs beyond the dense
+    kernels: a 2-D gather from a VMEM vector and a scatter-add back into
+    it.
     """
     from jax.experimental import pallas as pl
 
@@ -234,49 +236,30 @@ def _mosaic_sparse_gather_error(platform: str) -> str | None:
 def dso_sparse_block_step(cols, vals, y, w, alpha, gw, ga, tile_row_nnz,
                           tile_col_nnz, row_nnz, col_nnz, scalars, *,
                           row_batches: int, loss_name: str, reg_name: str,
+                          use_adagrad: bool = True, blk_id=None,
                           interpret: bool | None = None):
     """Sparse (block-ELL) counterpart of ``dso_block_step``: all
-    ``row_batches`` sequential tile steps of an active block from its
-    packed (M, K) ``cols``/``vals`` tile (kernels/dso_sparse.py).
+    ``row_batches`` sequential tile steps of an active block, run by the
+    one-hot Pallas kernel (kernels/dso_sparse.py).
 
-    Same truncation semantics as the dense path: trailing rows beyond
-    ``row_batches * (M // row_batches)`` pass through unchanged.  The
-    packed tile needs no shape padding — K is already aligned by the
-    tiler (sparse.format.choose_k) and db is whatever the grid uses.
-
-    ``interpret=None`` auto-detects like the dense wrappers (compiled on a
-    real TPU, interpreter elsewhere — ROADMAP "Mosaic-native" seam,
-    step 1).  When compiled execution is requested on a platform whose
-    Mosaic build lacks scatter-add / 2-D gather lowering
-    (``mosaic_sparse_gather_error`` probe — seam step 2), this raises a
-    ValueError naming the ``sparse_jnp`` fallback instead of surfacing an
-    opaque Mosaic error from inside the kernel.
+    ``cols``/``vals`` are one packed (M, K) tile, or with ``blk_id`` a
+    processor's whole (n_blk, M, K) payload, which the kernel then reads
+    tile ``blk_id`` of in place.  Same truncation semantics as the dense
+    path: trailing rows beyond ``row_batches * (M // row_batches)`` pass
+    through unchanged.  ``use_adagrad=False`` takes the plain ``eta`` step
+    of ``engine.update.eq8_apply`` (gw and ga unchanged).
+    ``interpret=None`` compiles (Mosaic) on a TPU and interprets elsewhere,
+    like the dense wrappers.
     """
     interpret = _resolve_interpret(interpret)
-    if not interpret:
-        err = mosaic_sparse_gather_error()
-        if err is not None:
-            raise ValueError(
-                f"sparse Pallas kernel requested compiled "
-                f"(interpret=False) but the {_platform()!r} "
-                f"backend cannot lower its scatter-add / 2-D gather "
-                f"(probe failed: {err.splitlines()[0]}); use the "
-                f"'sparse_jnp' backend (identical nnz-proportional math "
-                f"through XLA's native scatter/gather) or pass "
-                f"interpret=True for the Pallas interpreter")
     from repro.kernels import dso_sparse
-    M = cols.shape[0]
-    rb = M // row_batches
-    Mk = rb * row_batches
-    w2, a2, gw2, ga2 = dso_sparse.dso_sparse_block_step_pallas(
-        cols[:Mk], vals[:Mk], y[:Mk], w, alpha[:Mk], gw, ga[:Mk],
-        tile_row_nnz[:Mk], tile_col_nnz, row_nnz[:Mk], col_nnz, scalars,
+    if blk_id is None:
+        cols, vals, blk_id = cols[None], vals[None], 0
+    return dso_sparse.dso_sparse_block_step_pallas(
+        cols, vals, jnp.asarray(blk_id, jnp.int32), y, w, alpha, gw, ga,
+        tile_row_nnz, tile_col_nnz, row_nnz, col_nnz, scalars,
         row_batches=row_batches, loss_name=loss_name, reg_name=reg_name,
-        interpret=interpret)
-    if Mk < M:  # truncated trailing rows pass through unchanged
-        a2 = jnp.concatenate([a2, alpha[Mk:]])
-        ga2 = jnp.concatenate([ga2, ga[Mk:]])
-    return w2, a2, gw2, ga2
+        use_adagrad=use_adagrad, interpret=interpret)
 
 
 def dso_bucketed_block_step(cols_fl, vals_fl, lut, cnt, y, w, alpha, gw, ga,
@@ -289,8 +272,9 @@ def dso_bucketed_block_step(cols_fl, vals_fl, lut, cnt, y, w, alpha, gw, ga,
 
     ``cols_fl``/``vals_fl`` (n_chunks, M, K_CHUNK) are the processor's
     whole flat buffer; ``lut`` (n_kc,)/``cnt`` () select this tile's
-    chunks.  Same truncation, interpret resolution, and Mosaic probe
-    gating as the uniform-K sparse wrapper.
+    chunks.  Same truncation and interpret resolution as the uniform-K
+    sparse wrapper; compiled, it is refused with a ``ValueError`` where the
+    ``mosaic_sparse_gather_error`` probe fails (its kernel still gathers).
     """
     interpret = _resolve_interpret(interpret)
     if not interpret:

@@ -101,6 +101,14 @@ class Span:
         self._t0 = tr._clock()
         return self
 
+    def set(self, **attrs):
+        """Add ``attrs`` to an entered span: they ride in its event and, as
+        stats, on its trace annotation (e.g. the backend ``solve_setup``
+        resolved, known only inside the span)."""
+        self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+
     def __exit__(self, *exc):
         tr = self._tracer
         stack = tr._stack

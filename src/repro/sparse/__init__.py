@@ -4,7 +4,7 @@ block-ELL grid tiles, and the nnz-proportional DSO path.
 Layout/format:      ``repro.sparse.format``   (CSRMatrix, SparseTile,
                                                SparseGridData, tilers)
 Out-of-core ingest: ``repro.sparse.ingest``   (two-pass libsvm -> CSR)
-Pallas kernel:      ``repro.kernels.dso_sparse`` (gather-based tile step)
+Pallas kernel:      ``repro.kernels.dso_sparse`` (one-hot tile step)
 Runners:            ``core.dso.run_dso_grid(impl='sparse')`` and
                     ``core.dso_dist.ShardedDSO(impl='sparse')``.
 """

@@ -80,6 +80,12 @@ LANE = 128     # lane multiple (last dim on TPU)
 #: row-nnz skew inflating K)
 SPARSE_DENSITY_THRESHOLD = 0.1
 
+#: widest block (columns) for which ``impl="auto"`` runs the uniform sparse
+#: layout's one-hot Pallas kernel on a TPU: its MXU work per slot grows with
+#: ceil(db / 128) and XLA's gather does not.  Provisional (128 lane rows):
+#: the only width measured on a chip so far is db = 5,240 (PERF.md).
+ONEHOT_MAX_DB = 16_384
+
 #: above this per-tile-K skew (k_raw.max() / median) the uniform max-K
 #: block-ELL grid wastes most of its padding on the few dense tiles and the
 #: K-bucketed ragged layout wins — the ``impl="auto"`` bucketing trigger

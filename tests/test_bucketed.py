@@ -399,9 +399,9 @@ def test_auto_upgrades_to_bucketed_on_skew():
     # skew never flips the dense side of the density threshold
     assert resolve_backend("auto", 0.5, k_skew=100.0).name == "dense_jnp"
     # pre-built bucketed grids resolve kernel selectors to their layout
-    assert resolve_backend_for_layout("auto", "bucketed").name \
+    assert resolve_backend_for_layout("auto", "bucketed", 512).name \
         == "sparse_bucketed_jnp"
-    assert resolve_backend_for_layout("pallas", "bucketed").name \
+    assert resolve_backend_for_layout("pallas", "bucketed", 512).name \
         == "sparse_bucketed_pallas"
 
 

@@ -45,11 +45,14 @@ def test_phase_a_agrees_on_cpu():
 
 
 def test_phase_a_sparse_pallas_runs_in_interpreter_on_cpu():
-    """Off the chip the probe lowers (interpreter), so the sparse Pallas
-    backends run instead of being refused."""
+    """Off the chip the one-hot kernel agrees with itself and the probe
+    lowers (interpreter), so the bucketed Pallas backend runs instead of
+    being refused."""
     cpu = jax.devices("cpu")[0]
-    out = chip_smoke.phase_a_sparse_pallas(cpu, problems=_tiny_problems())
-    assert out == {be: "ran" for be in chip_smoke.SPARSE_PALLAS_BACKENDS}
+    out = chip_smoke.phase_a_sparse_pallas(cpu, cpu, epochs=2,
+                                           problems=_tiny_problems())
+    assert out == {"sparse_pallas": "agrees",
+                   "sparse_bucketed_pallas": "ran"}
 
 
 def test_phase_b_primal_falls_on_cpu(capsys):
@@ -103,6 +106,28 @@ def test_phase_four_chips_on_host_devices(alpha, backend):
     assert "FOUR_OK 2" in out.stdout
     assert out.stdout.count(f"backend={backend} ") == 2
     assert out.stdout.count(" ok\n") == 2
+
+
+def test_four_chip_uniform_case_takes_the_onehot_kernel(monkeypatch):
+    """The second --four-chips case (real-sim's shape, uniform column
+    popularity) keeps ``auto`` on the uniform layout, and on a TPU its
+    blocks are narrow enough for the one-hot kernel (platform mocked)."""
+    from repro.engine.backends import (resolve_backend,
+                                       resolve_backend_for_layout)
+    from repro.kernels import ops
+    from repro.sparse.format import (csr_k_per_tile, pad_to_multiple,
+                                     tile_k_skew)
+
+    cfg = chip_smoke.REALSIM_UNIFORM
+    csr, _ = chip_smoke.powerlaw_csr(cfg["m"], cfg["d"], cfg["nnz_per_row"],
+                                     cfg["alpha"], seed=0)
+    skew = tile_k_skew(csr_k_per_tile(csr, cfg["p"]))
+    layout = resolve_backend("auto", csr.density, k_skew=skew).layout
+    assert layout == "sparse", skew
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    db = pad_to_multiple(cfg["d"], cfg["p"]) // cfg["p"]
+    assert resolve_backend_for_layout("auto", layout, db).name \
+        == "sparse_pallas"
 
 
 def test_dso_perf_child_phases_fail_loudly(monkeypatch):

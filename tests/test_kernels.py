@@ -10,6 +10,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops
+from repro.sparse.format import ONEHOT_MAX_DB
 from repro.kernels.ref import dso_tile_step_ref, ssd_scan_ref, swa_attention_ref
 
 RNG = np.random.default_rng(42)
@@ -201,24 +202,47 @@ def test_ssd_state_decay_invariant():
 
 def test_compiled_sparse_kernel_fails_loudly_without_mosaic_scatter(
         monkeypatch):
-    """ROADMAP "Mosaic-native scatter/gather" step 2: requesting the sparse
-    Pallas kernel COMPILED on a platform whose backend cannot lower its
-    scatter-add / 2-D gather raises a ValueError naming the sparse_jnp
-    fallback, not an opaque lowering error.  Platform mocked: _on_tpu True
-    makes interpret=None resolve to compiled, and the probe kernel then
-    hits this container's real (CPU) backend, which lacks the lowering."""
+    """ROADMAP "Mosaic-native scatter/gather" step 2: requesting the
+    bucketed sparse Pallas kernel COMPILED on a platform whose backend
+    cannot lower its scatter-add / 2-D gather raises a ValueError naming
+    the jnp fallback, not an opaque lowering error.  The uniform one-hot
+    kernel needs neither op: it is handed to the compiler with no probe
+    and no refusal.  Platform mocked: _on_tpu True makes interpret=None
+    resolve to compiled, and the probe kernel then hits this container's
+    real (CPU) backend, which lacks the lowering."""
+    from repro.kernels import dso_sparse
+
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     ops._mosaic_sparse_gather_error.cache_clear()
     try:
         z8 = jnp.zeros(8, jnp.float32)
-        with pytest.raises(ValueError, match="sparse_jnp"):
-            ops.dso_sparse_block_step(
-                jnp.zeros((8, 8), jnp.int32), jnp.zeros((8, 8), jnp.float32),
-                z8, z8, z8, z8, z8, jnp.ones(8), jnp.ones((1, 8)),
-                jnp.ones(8), jnp.ones(8),
-                jnp.asarray([0.5, 1e-3, 8.0, -31.6, 31.6], jnp.float32),
-                row_batches=1, loss_name="hinge", reg_name="l2")
-        # the one-kernel bucketed wrapper shares the gate (and names the
+        uniform_args = (
+            jnp.zeros((8, 8), jnp.int32), jnp.zeros((8, 8), jnp.float32),
+            z8, z8, z8, z8, z8, jnp.ones(8), jnp.ones((1, 8)), jnp.ones(8),
+            jnp.ones(8),
+            jnp.asarray([0.5, 1e-3, 8.0, -31.6, 31.6], jnp.float32))
+        uniform_kw = dict(row_batches=1, loss_name="hinge", reg_name="l2")
+
+        # the uniform kernel: no probe is consulted, nothing is refused,
+        # and the compiled (not interpreted) kernel is what gets called
+        def no_probe():
+            raise AssertionError("the uniform kernel consulted the probe")
+
+        called = {}
+
+        def compiled_kernel(*args, interpret, **kw):
+            called["interpret"] = interpret
+            return args[4], args[5], args[6], args[7]
+
+        monkeypatch.setattr(ops, "mosaic_sparse_gather_error", no_probe)
+        monkeypatch.setattr(dso_sparse, "dso_sparse_block_step_pallas",
+                            compiled_kernel)
+        ops.dso_sparse_block_step(*uniform_args, **uniform_kw)
+        assert called == {"interpret": False}
+        monkeypatch.undo()
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+        # the one-kernel bucketed wrapper keeps the gate (and names the
         # bit-identical jnp fallback)
         with pytest.raises(ValueError, match="sparse_bucketed_jnp"):
             ops.dso_bucketed_block_step(
@@ -230,13 +254,70 @@ def test_compiled_sparse_kernel_fails_loudly_without_mosaic_scatter(
                 jnp.asarray([0.5, 1e-3, 8.0, -31.6, 31.6], jnp.float32),
                 row_batches=1, loss_name="hinge", reg_name="l2")
         # explicit interpret=True must keep working under the mock
-        out = ops.dso_sparse_block_step(
-            jnp.zeros((8, 8), jnp.int32), jnp.zeros((8, 8), jnp.float32),
-            z8, z8, z8, z8, z8, jnp.ones(8), jnp.ones((1, 8)),
-            jnp.ones(8), jnp.ones(8),
-            jnp.asarray([0.5, 1e-3, 8.0, -31.6, 31.6], jnp.float32),
-            row_batches=1, loss_name="hinge", reg_name="l2",
-            interpret=True)
+        out = ops.dso_sparse_block_step(*uniform_args, interpret=True,
+                                        **uniform_kw)
         assert np.isfinite(np.asarray(out[0])).all()
     finally:
         ops._mosaic_sparse_gather_error.cache_clear()
+
+
+@pytest.mark.parametrize("on_tpu,db,want", [
+    (True, 5_240, "sparse_pallas"),
+    (True, ONEHOT_MAX_DB, "sparse_pallas"),
+    (True, ONEHOT_MAX_DB + 1, "sparse_jnp"),
+    (False, 5_240, "sparse_jnp"),
+])
+def test_auto_picks_onehot_kernel_on_tpu_for_narrow_blocks(
+        monkeypatch, on_tpu, db, want):
+    """``auto`` on a built uniform sparse grid: the one-hot Pallas kernel
+    where the computation runs on a TPU and the block is at most
+    ``ONEHOT_MAX_DB`` wide; XLA's gather (``sparse_jnp``) on any other
+    platform and for wider blocks.  ``resolve_backend`` picks the layout
+    only; the platform is mocked."""
+    from repro.engine.backends import (resolve_backend,
+                                       resolve_backend_for_layout)
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: on_tpu)
+    assert resolve_backend("auto", 0.001, k_skew=1.0).layout == "sparse"
+    assert resolve_backend_for_layout("auto", "sparse", db).name == want
+    # the other layouts and explicit kernels are untouched by the rule
+    assert resolve_backend_for_layout("auto", "bucketed", db).name \
+        == "sparse_bucketed_jnp"
+    assert resolve_backend_for_layout("auto", "dense", db).name \
+        == "dense_jnp"
+    assert resolve_backend_for_layout("jnp", "sparse", db).name \
+        == "sparse_jnp"
+
+
+@pytest.mark.parametrize("use_adagrad", [True, False])
+def test_auto_onehot_kernel_runs_both_step_rules_on_tpu(monkeypatch,
+                                                        use_adagrad):
+    """On a TPU, ``auto`` gives a narrow sparse problem the one-hot kernel
+    for the AdaGrad step and for the plain eta0/sqrt(t) step alike:
+    ``resolve_backend_and_build`` (behind ``solve`` and ``ShardedDSO``)
+    picks it from the built grid, and ``solve`` runs it to
+    ``sparse_jnp``'s trajectory.  Platform mocked as a TPU; the
+    kernel runs in the interpreter."""
+    from repro.data.synthetic import make_classification
+    from repro.engine import solve
+    from repro.engine.driver import resolve_backend_and_build
+    from repro.obs import RunRecorder
+
+    prob = make_classification(m=160, d=96, density=0.05, loss="hinge",
+                               lam=1e-3, seed=4)
+    kw = dict(p=4, epochs=3, eta0=0.5, use_adagrad=use_adagrad,
+              eval_hook=None)
+    want = solve(prob, backend="sparse_jnp", **kw)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_resolve_interpret", lambda interpret: True)
+    be, _ = resolve_backend_and_build(prob, "auto", 4, 1)
+    assert be.name == "sparse_pallas"
+    rec = RunRecorder()
+    got = solve(prob, backend="auto", obs=rec, **kw)
+    setup = [e for e in rec.events
+             if e["type"] == "span" and e["name"] == "solve_setup"]
+    assert [e["attrs"] for e in setup] == [{"backend": "sparse_pallas"}]
+    for a, b in ((got.w, want.w), (got.alpha, want.alpha)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.isfinite(a).all()
+        assert np.abs(a - b).max() <= 1e-5 * max(np.abs(b).max(), 1.0)

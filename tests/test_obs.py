@@ -373,6 +373,26 @@ def test_jsonl_spans_join_the_profiler_trace(tmp_path):
         assert (name, parent, solve_id) == (s["name"], s["parent"],
                                             s["solve"])
         assert abs(dur_ns / 1e9 - s["dur_s"]) < 1e-3
+    # the tile kernel the solve resolved rides on its set-up span
+    setup = [st for name, _, _, st in _xplane_host_events(str(tmp_path))
+             if name == "solve_setup"]
+    assert [st.get("backend") for st in setup] == ["dense_jnp"]
+
+
+@pytest.mark.parametrize("backend,want", [("auto", "sparse_jnp"),
+                                          ("sparse", "sparse_jnp"),
+                                          ("dense_jnp", "dense_jnp")])
+def test_solve_setup_names_the_backend(backend, want):
+    """``solve_setup`` carries the resolved backend's name as ``backend``,
+    whatever selector asked for it (``auto`` picks the sparse layout for
+    this problem; on the CPU, XLA's gather)."""
+    from repro.engine import solve
+
+    rec = RunRecorder()
+    solve(_prob(density=0.05), backend=backend, epochs=1, p=4, eta0=0.5,
+          eval_hook=None, obs=rec)
+    setup = [s for s in _spans(rec) if s["name"] == "solve_setup"]
+    assert [s["attrs"] for s in setup] == [{"backend": want}]
 
 
 def test_supervisor_chaos_stream_ordered(tmp_path):

@@ -5,7 +5,7 @@ already trusts:
 
   1. format     — CSR round-trips, ELL tile packing, and the grid tiler
                   reproducing ``make_grid_data``'s layout + statistics.
-  2. kernels    — the gather-based sparse Pallas kernel == the jnp sparse
+  2. kernels    — the one-hot sparse Pallas kernel == the jnp sparse
                   oracle == the dense block-step oracle.
   3. trajectory — ``run_dso_grid(impl='sparse')`` equals the dense
                   trajectory to <= 1e-5 across every loss/regularizer pair
@@ -248,6 +248,108 @@ def test_sparse_grid_matches_dense_trajectory(loss, reg):
     assert abs(h1[-1]["primal"] - h2[-1]["primal"]) < 1e-4
     if np.isfinite(h1[-1]["gap"]):   # hinge+l1 has no finite dual here
         assert abs(h1[-1]["gap"] - h2[-1]["gap"]) < 1e-4
+
+
+def _gathered_terms(cols, vals, w):
+    """Per-slot ``vals * w[cols]`` terms of ``X w``, gathered by the
+    one-hot kernel's own slot-row code (interpreter)."""
+    import jax
+    from jax.experimental import pallas as pl
+    from repro.kernels import dso_sparse
+
+    M, K = cols.shape
+    db = w.shape[0]
+    h = -(-(-(-db // 128)) // 16) * 16
+    w2 = jnp.pad(w, (0, h * 128 - db)).reshape(h, 128)
+    w3 = jnp.concatenate(dso_sparse._split3(w2), axis=0).astype(jnp.bfloat16)
+    mp = -(-M // 128) * 128
+    ct = jnp.pad(cols.T, ((0, 0), (0, mp - M)))
+    vt = jnp.pad(vals.T, ((0, 0), (0, mp - M)))
+
+    def kernel(w3_ref, c_ref, v_ref, t_ref):
+        for k in range(K):
+            val = v_ref[k:k + 1]
+            g, _ = dso_sparse._slot_row(w3_ref[...], c_ref[k:k + 1], val,
+                                        jnp.zeros_like(val), h)
+            t_ref[k:k + 1] = val * g
+
+    terms = pl.pallas_call(
+        kernel, grid=(mp // 128,),
+        in_specs=[pl.BlockSpec((3 * h, 128), lambda i: (0, 0)),
+                  pl.BlockSpec((K, 128), lambda i: (0, i)),
+                  pl.BlockSpec((K, 128), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((K, 128), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((K, mp), jnp.float32),
+        interpret=True)(w3, ct, vt)
+    return np.asarray(terms)[:, :M].T
+
+
+@pytest.mark.parametrize("use_adagrad", [True, False])
+@pytest.mark.parametrize("row_batches", [1, 2])
+@pytest.mark.parametrize("loss,reg", [("hinge", "l2"), ("logistic", "l2"),
+                                      ("square", "l1")])
+def test_onehot_kernel_matches_sparse_jnp(loss, reg, row_batches,
+                                          use_adagrad):
+    """The one-hot kernel against ``sparse_jnp`` on two processors (vmap)
+    reading tile 1 of a two-tile payload: 1,100 rows (a partial last row
+    chunk), db = 300 (not a multiple of 128), rows with padding slots
+    (val 0 at col 0) and a last group of eight slot rows that is padding
+    only, under the AdaGrad step and the plain step.  The gathered ``X w``
+    terms are bit-exact; w, alpha, gw, ga agree to 1e-6 relative (the sums
+    run in another order)."""
+    import jax
+    from repro.engine.backends import get_backend
+
+    P, M, db, K = 2, 1100, 300, 24
+    rng = np.random.default_rng(3)
+    nnz = rng.integers(0, 17, (P, 2, M))        # slots past nnz: padding
+    cols = np.zeros((P, 2, M, K), np.int32)
+    vals = np.zeros((P, 2, M, K), np.float32)
+    for idx in np.ndindex(P, 2, M):
+        n = nnz[idx]
+        cols[idx][:n] = np.sort(rng.choice(db, n, replace=False))
+        vals[idx][:n] = rng.normal(0, 0.3, n)
+    y = np.where(rng.random((P, M)) < 0.5, 1.0, -1.0).astype(np.float32)
+    if loss == "square":
+        y = rng.normal(0, 1, (P, M)).astype(np.float32)
+    alpha = (y * rng.random((P, M)) * 0.9).astype(np.float32)
+    w = rng.normal(0, 0.1, (P, db)).astype(np.float32)
+    gw = np.abs(rng.normal(0, 0.01, (P, db))).astype(np.float32)
+    ga = np.abs(rng.normal(0, 0.01, (P, M))).astype(np.float32)
+    rn = rng.integers(1, 40, (P, M)).astype(np.float32)
+    cn = rng.integers(1, 90, (P, db)).astype(np.float32)
+    trn = (vals[:, 1] != 0).sum(-1).astype(np.float32)
+    rb = M // row_batches
+    tcn = np.stack([np.stack([np.bincount(
+        cols[q, 1, s * rb:(s + 1) * rb][vals[q, 1, s * rb:(s + 1) * rb]
+                                         != 0], minlength=db)
+        for s in range(row_batches)]) for q in range(P)]).astype(np.float32)
+    meta = (jnp.float32(1e-3), jnp.float32(M * P), loss, reg, use_adagrad,
+            jnp.float32(-31.6), jnp.float32(31.6))
+    eta = jnp.float32(0.5)
+    blk = jnp.ones(P, jnp.int32)
+    common = [jnp.asarray(a) for a in (y, w, alpha, gw, ga, rn, cn, trn,
+                                       tcn)]
+
+    def run(name, block):
+        step = get_backend(name).block_step
+
+        def per_q(block, y, w, a, gw, ga, rn, cn, trn, tcn):
+            return step(meta, block, y, w, a, gw, ga, rn, cn, trn, tcn, eta,
+                        row_batches)
+        return [np.asarray(o) for o in jax.vmap(per_q)(block, *common)]
+
+    got = run("sparse_pallas", (jnp.asarray(cols), jnp.asarray(vals), blk))
+    want = run("sparse_jnp", (jnp.asarray(cols[:, 1]),
+                              jnp.asarray(vals[:, 1])))
+    for name, a, b in zip("w alpha gw ga".split(), got, want):
+        assert np.isfinite(a).all(), name
+        assert np.abs(a - b).max() <= 1e-6 * np.abs(b).max(), \
+            (name, np.abs(a - b).max(), np.abs(b).max())
+
+    terms = _gathered_terms(jnp.asarray(cols[0, 1]),
+                            jnp.asarray(vals[0, 1]), jnp.asarray(w[0]))
+    np.testing.assert_array_equal(terms, vals[0, 1] * w[0][cols[0, 1]])
 
 
 def test_sparse_pallas_matches_sparse_jnp_with_row_batches():

@@ -18,15 +18,19 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.engine.backends import resolve_backend_for_layout
 from repro.engine.data import DSOState, TileData
 from repro.engine.driver import run_epochs
-from repro.kernels import dso_update
+from repro.kernels import dso_sparse, dso_update
+from repro.sparse.format import ONEHOT_MAX_DB
 
 #: real-sim at p=4: m=72,309 and d=20,958 padded to 72,312 x 20,960; the
 #: flat chunk view of its K-bucketed grid (bucket widths 8/16/56, tile-K
 #: skew 5.1 at the power-law model of chip_smoke.py, seed 0) holds 13
 #: chunks of K_CHUNK=8 columns per processor, 7 for the widest tile
 REALSIM_P4 = dict(p=4, mb=18_078, db=5_240, n_chunks=13, n_kc=7)
+#: packed width of its uniform block-ELL grid (ids spread over the blocks)
+REALSIM_K = 32
 
 LOSS_REG = [("hinge", "l2"), ("logistic", "l2"), ("square", "l1")]
 
@@ -112,3 +116,116 @@ def test_run_epochs_bucketed_compiles_at_realsim_p4(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < 16e9, total
     assert np.isfinite(total)
+
+
+def _compile_onehot_kernel(one_chip, db, row_batches, use_adagrad=True):
+    p, mb, K = REALSIM_P4["p"], REALSIM_P4["mb"], REALSIM_K
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    step = jax.vmap(lambda *a: dso_sparse.dso_sparse_block_step_pallas(
+        *a, row_batches=row_batches, loss_name="hinge", reg_name="l2",
+        use_adagrad=use_adagrad, interpret=False))
+    return jax.jit(step).lower(
+        sds((p, p, mb, K), jnp.int32), sds((p, p, mb, K)),
+        sds((p,), jnp.int32), sds((p, mb)), sds((p, db)), sds((p, mb)),
+        sds((p, db)), sds((p, mb)), sds((p, mb)),
+        sds((p, row_batches, db)), sds((p, mb)), sds((p, db)),
+        sds((p, 5))).compile()
+
+
+def test_onehot_sparse_kernel_compiles_at_realsim_p4(one_chip):
+    """The one-hot sparse kernel at real-sim's uniform p=4 shape, vmapped
+    over the 4 processors as the grid simulator runs it: each reads its
+    active tile from its (4, 18078, 32) payload in place."""
+    compiled = _compile_onehot_kernel(one_chip, REALSIM_P4["db"], 1)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("row_batches,use_adagrad", [(1, True), (8, False),
+                                                     (4096, True)])
+def test_onehot_sparse_kernel_compiles_at_widest_auto_block(
+        one_chip, row_batches, use_adagrad):
+    """The widest block ``auto`` gives the one-hot kernel on a TPU
+    (``ONEHOT_MAX_DB`` columns, 128 lane rows of w), at real-sim's rows
+    and K, with one to 4,096 row batches (of four rows each): the tile's
+    per-batch column counts stay in HBM, so the fast memory the kernel
+    needs does not grow with ``row_batches`` and the compiler accepts
+    every count."""
+    compiled = _compile_onehot_kernel(one_chip, ONEHOT_MAX_DB, row_batches,
+                                      use_adagrad)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _realsim_uniform_run_epochs(one_chip, backend):
+    p, mb, db = (REALSIM_P4[k] for k in ("p", "mb", "db"))
+    K = REALSIM_K
+    d_pad, n = p * db, 5
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tile = TileData(
+        arrays=(sds((p, p, mb, K), jnp.int32), sds((p, p, mb, K))),
+        yg=sds((p, mb)), row_nnz_g=sds((p, mb)), col_nnz=sds((d_pad,)),
+        row_valid=sds((p, mb)), tile_col_nnz_g=sds((p, 1, d_pad)),
+        tile_row_nnz_g=sds((p, p, mb)))
+    state = DSOState(sds((p, db)), sds((p, db)), sds((p, mb)), sds((p, mb)),
+                     sds((), jnp.int32))
+    scalar = sds(())
+    return run_epochs.lower(
+        tile, state, sds((n, p, p), jnp.int32), sds((n,)), scalar, scalar,
+        scalar, scalar, backend=backend, loss_name="hinge", reg_name="l2",
+        use_adagrad=True, row_batches=1, p=p, db=db).compile()
+
+
+def test_run_epochs_auto_uniform_compiles_at_realsim_p4(topo, one_chip):
+    """``run_epochs`` with the backend ``auto`` resolves on a TPU for
+    real-sim's uniform grid: the one-hot kernel, inside the epoch scan,
+    with no more temporary memory than ``sparse_jnp`` at the same shape
+    (the guard of ``peak_hbm_bytes``: no tile is copied out of the grid)."""
+    with jax.default_device(topo.devices[0]):
+        backend = resolve_backend_for_layout("auto", "sparse",
+                                             REALSIM_P4["db"]).name
+        assert backend == "sparse_pallas"
+        compiled = _realsim_uniform_run_epochs(one_chip, backend)
+        baseline = _realsim_uniform_run_epochs(one_chip, "sparse_jnp")
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= baseline.memory_analysis().temp_size_in_bytes, temp
+
+
+def test_sharded_ring_onehot_compiles_on_v5e_2x2(topo):
+    """``ShardedDSO`` takes the one-hot kernel on a TPU at real-sim's
+    uniform shape: the overlapped cyclic ring over the four chips of a
+    v5e:2x2, one processor per chip (the kernel unbatched, inside
+    ``shard_map``).  The kernel's (K, M) view of each chip's payload is a
+    bitcast of how the chip stores it: the program's temporaries stay
+    below the size of one payload array per chip, so no copy of the grid
+    is made in any step."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.dso_dist import _epoch_shardmap
+
+    p, mb, db = (REALSIM_P4[k] for k in ("p", "mb", "db"))
+    K, d_pad, n = REALSIM_K, p * db, 2
+    mesh = Mesh(np.array(topo.devices[:p]), ("dso",))
+
+    def sds(shape, dtype=jnp.float32, spec=P("dso")):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    with jax.default_device(topo.devices[0]):
+        fn = _epoch_shardmap(mesh, p, db, "hinge", "l2", True, 1,
+                             backend_name="sparse_pallas")
+        compiled = fn.lower(
+            sds((p, p, mb, K), jnp.int32), sds((p, p, mb, K)),
+            sds((p, mb)), sds((p, mb)), sds((p, 1, d_pad)),
+            sds((p, p, mb)), sds((d_pad,), spec=P(None)),
+            sds((p, db)), sds((p, db)), sds((p, mb)), sds((p, mb)),
+            sds((n,), spec=P()), sds((n, p, p), jnp.int32, spec=P()),
+            *(sds((), spec=P()) for _ in range(4))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    payload_per_chip = p * mb * K * 4          # one of cols, vals
+    assert compiled.memory_analysis().temp_size_in_bytes < payload_per_chip
