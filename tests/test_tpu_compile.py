@@ -11,6 +11,7 @@ described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,10 @@ from repro.sparse.format import ONEHOT_MAX_DB
 REALSIM_P4 = dict(p=4, mb=18_078, db=5_240, n_chunks=13, n_kc=7)
 #: packed width of its uniform block-ELL grid (ids spread over the blocks)
 REALSIM_K = 32
+#: news20.binary at p=4: m=19,996 and d=1,355,191 padded to 19,996 x
+#: 1,355,192; its uniform block-ELL grid (tile-K skew 1.079 at the
+#: benchmark's Zipf model, data seed 0) is 160 slots wide
+NEWS20_P4 = dict(p=4, mb=4_999, db=338_798, K=160)
 
 LOSS_REG = [("hinge", "l2"), ("logistic", "l2"), ("square", "l1")]
 
@@ -158,10 +163,9 @@ def test_onehot_sparse_kernel_compiles_at_widest_auto_block(
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _realsim_uniform_run_epochs(one_chip, backend):
-    p, mb, db = (REALSIM_P4[k] for k in ("p", "mb", "db"))
-    K = REALSIM_K
-    d_pad, n = p * db, 5
+def _uniform_run_epochs(one_chip, backend, *, p, mb, db, K,
+                       loss="hinge", n=5):
+    d_pad = p * db
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -176,8 +180,14 @@ def _realsim_uniform_run_epochs(one_chip, backend):
     scalar = sds(())
     return run_epochs.lower(
         tile, state, sds((n, p, p), jnp.int32), sds((n,)), scalar, scalar,
-        scalar, scalar, backend=backend, loss_name="hinge", reg_name="l2",
+        scalar, scalar, backend=backend, loss_name=loss, reg_name="l2",
         use_adagrad=True, row_batches=1, p=p, db=db).compile()
+
+
+def _realsim_uniform_run_epochs(one_chip, backend):
+    return _uniform_run_epochs(
+        one_chip, backend, K=REALSIM_K,
+        **{k: REALSIM_P4[k] for k in ("p", "mb", "db")})
 
 
 def test_run_epochs_auto_uniform_compiles_at_realsim_p4(topo, one_chip):
@@ -194,6 +204,31 @@ def test_run_epochs_auto_uniform_compiles_at_realsim_p4(topo, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= baseline.memory_analysis().temp_size_in_bytes, temp
+
+
+def test_run_epochs_auto_wide_uniform_compiles_at_news20_p4(topo, one_chip):
+    """``run_epochs`` with the backend ``auto`` resolves on a TPU for
+    news20's uniform grid, whose 338,798-column blocks are wider than
+    ``ONEHOT_MAX_DB``: XLA's gather and scatter-add (``sparse_jnp``), with
+    the logistic loss, over a one-epoch chunk, within one v5e chip's 16 GB.
+    The compiled program's metadata names the gather's ops with the
+    ``xw_gather`` scope and the scatter-add's with ``xta_scatter``, both
+    inside ``tile_step``: the paths a profile gives ``gather_ms`` and
+    ``scatter_ms``."""
+    with jax.default_device(topo.devices[0]):
+        backend = resolve_backend_for_layout("auto", "sparse",
+                                             NEWS20_P4["db"]).name
+    assert NEWS20_P4["db"] > ONEHOT_MAX_DB and backend == "sparse_jnp"
+    compiled = _uniform_run_epochs(one_chip, backend, loss="logistic", n=1,
+                                   **NEWS20_P4)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < 16e9, total
+    paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    for scope in ("xw_gather", "xta_scatter"):
+        assert any(re.search(rf"tile_step\)?/(.+/)?{scope}/", p)
+                   for p in paths), scope
 
 
 def test_sharded_ring_onehot_compiles_on_v5e_2x2(topo):
