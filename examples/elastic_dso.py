@@ -21,14 +21,7 @@ wall-clock replanning escalation (lpt schedule, then live reshard).
 
 The chaos run also drives a ``repro.obs.RunRecorder``: every throughput
 sample, snapshot/restore/reshard span, and ledger event lands in ONE
-ordered JSONL run-event log (``--events-out``), rendered to a readable
-timeline (``--report-out``, via ``benchmarks.report run-report``) — the
-CI chaos artifact.  A ``TelemetrySpec`` rides the same run: per-(epoch,
-inner iteration, worker) device buffers drain into the log as
-``telemetry`` events, and the straggler heatmap rendered from them
-(``--heatmap-out``) must pin the injected slow worker as the
-wall-balance argmax row — device-side attribution agreeing with the
-fault plan.
+ordered JSONL run-event log (``--events-out``) — the CI chaos artifact.
 
 ``--async-writes`` runs the same scenarios with
 ``SnapshotStore(async_writes=True)``: the npz serialization + atomic
@@ -40,7 +33,7 @@ async writes invisible to recovery semantics.
 
     PYTHONPATH=src python examples/elastic_dso.py [--epochs N]
         [--fault-every K] [--ckpt-every K] [--async-writes]
-        [--chaos [--ledger-out F] [--events-out F] [--report-out F]]
+        [--chaos [--ledger-out F] [--events-out F]]
 """
 
 import argparse
@@ -52,7 +45,6 @@ import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(_REPO, "src"))
-sys.path.insert(0, _REPO)   # benchmarks.report renders the run report
 # 8 host devices BEFORE jax initializes — the mesh is a real 8-way shard_map
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
@@ -60,8 +52,7 @@ import numpy as np  # noqa: E402
 
 from repro.core.dso_dist import ShardedDSO, make_dso_mesh  # noqa: E402
 from repro.data.synthetic import make_classification  # noqa: E402
-from repro.obs import (RunRecorder, TelemetrySpec, render_heatmap,  # noqa: E402
-                       wall_balance)
+from repro.obs import RunRecorder  # noqa: E402
 from repro.runtime import (FaultEvent, SnapshotStore, Supervisor,  # noqa: E402
                            ledger_counts, periodic_crashes,
                            render_ledger_event)
@@ -94,10 +85,6 @@ def run_chaos(args):
                       meta=dict(run="elastic_dso_chaos", m=prob.m, d=prob.d,
                                 epochs=epochs, eta0=args.eta0,
                                 fault_plan=[ev.describe() for ev in plan]))
-    # the telemetry lane rides the same run: every chunk's device buffer
-    # drains into the event log, and the supervisor attributes its
-    # simulated straggler sleeps to the slow worker's row
-    tel = TelemetrySpec(obs=rec)
     with tempfile.TemporaryDirectory() as ckpt_dir:
         sup = Supervisor(SnapshotStore(ckpt_dir,
                                        async_writes=args.async_writes),
@@ -105,7 +92,7 @@ def run_chaos(args):
                          eta0=args.eta0, fault_plan=plan,
                          straggler_delay_s=0.05, replan=True,
                          straggler_factor=1.5, straggler_patience=1,
-                         reshard_to=4, obs=rec, telemetry=tel)
+                         reshard_to=4, obs=rec)
         opt, ledger = sup.run_sharded(prob, epochs, mesh=make_dso_mesh(8),
                                       impl="auto", schedule="cyclic",
                                       seed=5)
@@ -144,28 +131,9 @@ def run_chaos(args):
         with open(args.ledger_out, "w") as f:
             json.dump(out, f, indent=2, default=str)
         print(f"recovery ledger -> {args.ledger_out}")
-        # finalize the run-event log and render it to a readable timeline
         rec.close()
-        from benchmarks.report import run_report
-        with open(args.report_out, "w") as f:
-            f.write("## §Run report\n\n" + run_report(args.events_out)
-                    + "\n")
         print(f"run-event log -> {args.events_out} "
-              f"({len(rec.events)} events); report -> {args.report_out}")
-        # straggler heatmap: restrict to the p=8 chunks from the slow
-        # fault on (t0 >= 10) — the post-replan chunks run at p'=4 with
-        # the straggler shed, so they would dilute the attribution
-        heat = render_heatmap(tel, p=8, t0_min=10)
-        with open(args.heatmap_out, "w") as f:
-            f.write("## §Straggler heatmap (p=8 chunks, t0 >= 10)\n\n"
-                    + heat + "\n")
-        print(heat)
-        print(f"straggler heatmap -> {args.heatmap_out}")
-        bal, _ = wall_balance(tel, p=8, t0_min=10)
-        hot = int(np.argmax(bal.sum(axis=1)))
-        assert hot == 2, (
-            f"wall-balance argmax is worker {hot}, but the plan injected "
-            f"the straggler on worker 2")
+              f"({len(rec.events)} events)")
         # every fault class detected/acted on, and the run still converged
         assert counts.get("health", 0) >= 1, "NaN never detected"
         assert sup.store.quarantined, "corrupt snapshot never quarantined"
@@ -196,12 +164,6 @@ def main(argv=None):
     ap.add_argument("--ledger-out", default="elastic-chaos-ledger.json")
     ap.add_argument("--events-out", default="elastic-chaos-events.jsonl",
                     help="--chaos: run-event JSONL log (RunRecorder)")
-    ap.add_argument("--report-out", default="elastic-chaos-report.md",
-                    help="--chaos: rendered run report "
-                         "(benchmarks.report run-report)")
-    ap.add_argument("--heatmap-out", default="elastic-chaos-heatmap.md",
-                    help="--chaos: straggler heatmap rendered from the "
-                         "telemetry lane (obs.render_heatmap)")
     args = ap.parse_args(argv)
     if args.chaos:
         return run_chaos(args)

@@ -27,9 +27,7 @@ names raise ``ValueError``.
 from __future__ import annotations
 
 from repro.core.saddle import Problem
-from repro.engine.backends import (LEGACY_IMPLS,  # noqa: F401
-                                   resolve_backend,
-                                   resolve_backend_for_layout)
+from repro.engine.backends import LEGACY_IMPLS, resolve_backend  # noqa: F401
 # re-exports: the legacy flat-module surface of the layered engine
 from repro.engine.data import (DSOState, GridData, as_tile_data,  # noqa: F401
                                check_tile_stats, gather_alpha, gather_w,
@@ -37,9 +35,8 @@ from repro.engine.data import (DSOState, GridData, as_tile_data,  # noqa: F401
                                tile_dims)
 from repro.engine.data import eta_schedule as _eta_schedule  # noqa: F401
 from repro.engine.data import prob_meta as _prob_meta  # noqa: F401
-from repro.engine.driver import (SolveResult, run_epoch,  # noqa: F401
-                                 run_epochs, solve, solve_serial)
-from repro.engine.schedules import cyclic_perms
+from repro.engine.driver import (SolveResult, run_epochs,  # noqa: F401
+                                 solve, solve_serial)
 from repro.engine.update import (block_tile_step,  # noqa: F401
                                  sparse_tile_step)
 from repro.engine.update import eq8_apply as _eq8_apply  # noqa: F401
@@ -79,7 +76,7 @@ def run_dso_grid(prob: Problem, p: int = 4, epochs: int = 10,
                  eta0: float = 0.1, use_adagrad: bool = True,
                  row_batches: int = 1, alpha0: float = 0.0,
                  eval_every: int = 1, impl: str = "jnp",
-                 scan_epochs: bool = True, schedule: str = "cyclic"):
+                 schedule: str = "cyclic"):
     """Single-device simulation of Algorithm 1 with p processors.
 
     ``impl`` selects layout and kernel (see ``IMPLS`` / the engine backend
@@ -89,17 +86,15 @@ def run_dso_grid(prob: Problem, p: int = 4, epochs: int = 10,
     picking the sparse layout below the density threshold.  ``schedule``
     is any registered engine schedule ("cyclic" is Algorithm 1).
 
-    ``scan_epochs=True`` (default) runs each evaluation chunk of epochs as
-    one donated ``lax.scan`` dispatch; ``False`` keeps the legacy
-    one-dispatch-per-epoch loop (benchmark baseline). Identical math.
-    Each distinct chunk length traces once, so when ``eval_every`` does not
-    divide ``epochs`` the ragged final chunk costs one extra compile —
-    prefer ``epochs % eval_every == 0`` for long runs (the driver warns).
+    Each evaluation chunk of epochs runs as one donated ``lax.scan``
+    dispatch.  Each distinct chunk length traces once, so when
+    ``eval_every`` does not divide ``epochs`` the ragged final chunk
+    costs one extra compile — prefer ``epochs % eval_every == 0`` for
+    long runs (the driver warns).
     """
     res = solve(prob, backend=impl, schedule=schedule, p=p, epochs=epochs,
                 eta0=eta0, use_adagrad=use_adagrad, row_batches=row_batches,
-                alpha0=alpha0, eval_every=eval_every,
-                scan_epochs=scan_epochs)
+                alpha0=alpha0, eval_every=eval_every)
     return res.w, res.alpha, res.history
 
 
@@ -133,33 +128,3 @@ def run_dso_grid_from_data(data, *, loss_name: str, reg_name: str,
         return res.w, res.alpha, res.history
     return res.w, res.alpha
 
-
-# ------------------------------------------------------------------------
-# legacy jitted-epoch shims (benchmarks/dso_perf.py times these directly)
-# ------------------------------------------------------------------------
-
-
-def _impl_kw(data, impl, kw):
-    layout = as_tile_data(data).layout
-    backend = resolve_backend_for_layout(impl, layout, tile_dims(data)[2])
-    out = dict(kw)
-    out["backend"] = backend.name
-    return backend, out
-
-
-def _grid_epoch(data, state, eta_t, lam, m, w_lo, w_hi, *, impl="jnp",
-                **kw):
-    """One epoch, one dispatch (legacy path; see ``_grid_epochs``)."""
-    backend, kw = _impl_kw(data, impl, kw)
-    perm = cyclic_perms(1, kw["p"])[0]
-    return run_epoch(as_tile_data(data, bucketed_payload=backend.payload),
-                     state, perm, eta_t, lam, m, w_lo, w_hi, **kw)
-
-
-def _grid_epochs(data, state, etas, lam, m, w_lo, w_hi, *, impl="jnp",
-                 **kw):
-    """``len(etas)`` cyclic epochs in ONE donated-scan dispatch."""
-    backend, kw = _impl_kw(data, impl, kw)
-    perms = cyclic_perms(etas.shape[0], kw["p"])
-    return run_epochs(as_tile_data(data, bucketed_payload=backend.payload),
-                      state, perms, etas, lam, m, w_lo, w_hi, **kw)

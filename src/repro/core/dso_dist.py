@@ -41,8 +41,6 @@ bit-equality between the two for every backend x schedule combination.
 
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,10 +51,8 @@ from repro.engine.backends import get_backend
 from repro.engine.data import (DSOState, as_tile_data, check_tile_stats,
                                eta_schedule, init_state, prob_meta,
                                tile_dims)
-from repro.engine.driver import (TELEMETRY_FIELDS, inner_iteration,
-                                 resolve_backend_and_build, stage_block,
-                                 staged_step, telemetry_row,
-                                 warn_ragged_eval)
+from repro.engine.driver import (inner_iteration, resolve_backend_and_build,
+                                 stage_block, staged_step, warn_ragged_eval)
 from repro.engine.schedules import get_schedule
 
 
@@ -71,8 +67,7 @@ def make_dso_mesh(p: int | None = None) -> Mesh:
 def _epoch_shardmap(mesh: Mesh, p: int, db: int, loss_name: str,
                     reg_name: str, use_adagrad: bool, row_batches: int,
                     *, backend_name: str = "dense_jnp", ring: bool = True,
-                    n_data: int | None = None, overlap: bool = True,
-                    telemetry: bool = False):
+                    n_data: int | None = None, overlap: bool = True):
     """Builds the jitted sharded multi-epoch function for a fixed problem
     shape: ``etas`` (one step size per epoch) and ``perms`` (the schedule's
     (n, p, p) block permutations) drive a ``lax.scan`` over epochs INSIDE
@@ -95,20 +90,11 @@ def _epoch_shardmap(mesh: Mesh, p: int, db: int, loss_name: str,
     by all-gather + dynamic select, and the epoch ends by restoring the
     device-q-holds-block-q invariant.  (The p2p alternative is
     ``_epoch_shardmap_p2p``, traced per chunk from the host permutations.)
-
-    ``telemetry=True`` adds the device-resident telemetry lane: every body
-    also accumulates this device's per-(epoch, inner iteration)
-    ``engine.driver.TELEMETRY_FIELDS`` rows and the function returns a
-    fifth output stitched across the mesh to (n, p, p, F) — the SAME
-    [epoch, r, worker, field] layout the grid driver's
-    ``run_epochs_telemetry`` emits, so grid and sharded telemetry agree
-    exactly.  The rows only read before/after values: trajectories are
-    bit-identical with telemetry on or off.
     """
     backend = get_backend(backend_name)
     if n_data is None:
-        # the bucketed layout's payload length is data-dependent (two
-        # arrays per K-bucket + the index maps) — callers pass it in
+        # the bucketed layout's flat chunk view is four arrays — callers
+        # pass its length in
         n_data = 2 if backend.layout == "sparse" else 1
 
     def epochs_body(*args):
@@ -135,43 +121,20 @@ def _epoch_shardmap(mesh: Mesh, p: int, db: int, loss_name: str,
             return stage_block(backend, col_nnz, blk_id, arrays_q, yq,
                                tcnq, trnq, row_batches, db)
 
-        mb = yq.shape[0]
-        n_f = len(TELEMETRY_FIELDS)
-
-        def tel(tbuf, r, trn_blk, w_old, w_new, a_old, a_new, gw_new,
-                ga_new):
-            # this device's telemetry row for inner iteration r — only
-            # traced when telemetry is on (a static Python flag)
-            return tbuf.at[r].set(telemetry_row(w_old, w_new, a_old, a_new,
-                                                gw_new, ga_new, trn_blk))
-
-        def trn_of(blk_id):
-            return jax.lax.dynamic_slice(trnq, (blk_id, 0), (1, mb))[0]
-
-        def tbuf0():
-            return jnp.zeros((p, n_f), jnp.float32)
-
         def cyclic_epoch(carry, xs):
             eta_t, _ = xs
-            if telemetry:
-                carry = carry + (tbuf0(),)
 
             def inner(r, c):
-                w_blk, gw_blk, alpha_q, ga_q = c[:4]
+                w_blk, gw_blk, alpha_q, ga_q = c
                 blk_id = (q + r) % p                       # sigma(q, r)
                 w_new, a_new, gw_new, ga_new = step_block(
                     blk_id, w_blk, gw_blk, alpha_q, ga_q, eta_t)
-                out = ()
-                if telemetry:
-                    out = (tel(c[4], r, trn_of(blk_id), w_blk, w_new,
-                               alpha_q, a_new, gw_new, ga_new),)
                 # bulk synchronization: pass the block to the ring neighbour
                 w_new, gw_new = jax.lax.ppermute((w_new, gw_new), "dso",
                                                  ring_perm)
-                return (w_new, gw_new, a_new, ga_new) + out
+                return (w_new, gw_new, a_new, ga_new)
 
-            carry = jax.lax.fori_loop(0, p, inner, carry)
-            return ((carry[:4], carry[4]) if telemetry else (carry, None))
+            return jax.lax.fori_loop(0, p, inner, carry), None
 
         def cyclic_epoch_pipelined(carry, xs):
             # Double-buffered ring: the carry threads a one-slot staged
@@ -183,32 +146,21 @@ def _epoch_shardmap(mesh: Mesh, p: int, db: int, loss_name: str,
             # instead of two.  The consumed block is always sigma(q, r),
             # exactly the serial-shift driver's — bit-identical trajectory.
             eta_t, _ = xs
-            if telemetry:
-                carry = carry + (tbuf0(),)
 
             def inner(r, c):
-                w_blk, gw_blk, alpha_q, ga_q, staged = c[:5]
+                w_blk, gw_blk, alpha_q, ga_q, staged = c
                 w_new, a_new, gw_new, ga_new = staged_step(
                     backend, meta, staged, w_blk, gw_blk, alpha_q, ga_q,
                     arrays_q, yq, rnq, eta_t, row_batches)
-                out = ()
-                if telemetry:
-                    # staged[2] is the active tile's row-nnz slice — the
-                    # prefetched statistic doubles as the telemetry input
-                    out = (tel(c[5], r, staged[2], w_blk, w_new, alpha_q,
-                               a_new, gw_new, ga_new),)
                 buf = jax.lax.ppermute(jnp.stack([w_new, gw_new]), "dso",
                                        ring_perm)
                 staged = stage((q + r + 1) % p)   # prefetch sigma(q, r+1)
-                return (buf[0], buf[1], a_new, ga_new, staged) + out
+                return (buf[0], buf[1], a_new, ga_new, staged)
 
-            carry = jax.lax.fori_loop(0, p, inner, carry)
-            return ((carry[:5], carry[5]) if telemetry else (carry, None))
+            return jax.lax.fori_loop(0, p, inner, carry), None
 
         def shuffle_epoch(carry, xs):
             eta_t, perm_e = xs
-            if telemetry:
-                carry = carry + (tbuf0(),)
             # own[r] = holder map BEFORE inner iteration r (devices hold
             # their own block at epoch start); own[p] = after the last one
             own = jnp.concatenate([qs[None, :], perm_e.astype(jnp.int32)],
@@ -226,50 +178,37 @@ def _epoch_shardmap(mesh: Mesh, p: int, db: int, loss_name: str,
                 return w_all[inv[want]], gw_all[inv[want]]
 
             def inner(r, c):
-                w_blk, gw_blk, alpha_q, ga_q = c[:4]
+                w_blk, gw_blk, alpha_q, ga_q = c
                 w_blk, gw_blk = fetch((w_blk, gw_blk), r)
                 blk_id = perm_e[r, q]
                 w_new, a_new, gw_new, ga_new = step_block(
                     blk_id, w_blk, gw_blk, alpha_q, ga_q, eta_t)
-                out = ()
-                if telemetry:
-                    out = (tel(c[4], r, trn_of(blk_id), w_blk, w_new,
-                               alpha_q, a_new, gw_new, ga_new),)
-                return (w_new, gw_new, a_new, ga_new) + out
+                return (w_new, gw_new, a_new, ga_new)
 
             carry = jax.lax.fori_loop(0, p, inner, carry)
             # restore the epoch-start invariant: device q holds block q
-            w_blk, gw_blk, alpha_q, ga_q = carry[:4]
+            w_blk, gw_blk, alpha_q, ga_q = carry
             w_blk, gw_blk = fetch((w_blk, gw_blk), jnp.int32(p))
-            out = (w_blk, gw_blk, alpha_q, ga_q)
-            return ((out, carry[4]) if telemetry else (out, None))
+            return (w_blk, gw_blk, alpha_q, ga_q), None
 
         if ring and overlap:
             # the staged slot threads ACROSS epochs: the last iteration of
             # epoch e prefetches sigma(q, p) = q — exactly epoch e+1's
             # first block — so one stage(q) primes the whole chunk
             carry0 = (w_blk, gw_blk, alpha_q, ga_q, stage(q))
-            (w_blk, gw_blk, alpha_q, ga_q, _), tbufs = jax.lax.scan(
+            (w_blk, gw_blk, alpha_q, ga_q, _), _ = jax.lax.scan(
                 cyclic_epoch_pipelined, carry0, (etas, perms))
         else:
             epoch = cyclic_epoch if ring else shuffle_epoch
-            (w_blk, gw_blk, alpha_q, ga_q), tbufs = jax.lax.scan(
+            (w_blk, gw_blk, alpha_q, ga_q), _ = jax.lax.scan(
                 epoch, (w_blk, gw_blk, alpha_q, ga_q), (etas, perms))
-        out = (w_blk[None], gw_blk[None], alpha_q[None], ga_q[None])
-        if telemetry:
-            # (n, p, 1, F) per device; stitched to (n, p, p, F) on the
-            # worker axis by the out spec — the grid driver's layout
-            out = out + (tbufs[:, :, None, :],)
-        return out
+        return (w_blk[None], gw_blk[None], alpha_q[None], ga_q[None])
 
-    out_specs = (P("dso"), P("dso"), P("dso"), P("dso"))
-    if telemetry:
-        out_specs = out_specs + (P(None, None, "dso"),)
     sharded = jax.shard_map(
         epochs_body, mesh=mesh,
         in_specs=(P("dso"),) * (n_data + 4) + (P(None),)
         + (P("dso"),) * 4 + (P(), P(), P(), P(), P(), P()),
-        out_specs=out_specs,
+        out_specs=(P("dso"),) * 4,
         # pallas_call has no varying-manual-axes rule; the outputs are all
         # "dso"-sharded anyway, so the check adds nothing here
         check_vma="pallas" not in backend_name,
@@ -310,8 +249,7 @@ def _p2p_routes(perm_e: np.ndarray):
 def _epoch_shardmap_p2p(mesh: Mesh, p: int, db: int, loss_name: str,
                         reg_name: str, use_adagrad: bool, row_batches: int,
                         perms_host: np.ndarray, *,
-                        backend_name: str = "dense_jnp", n_data: int = 1,
-                        telemetry: bool = False):
+                        backend_name: str = "dense_jnp", n_data: int = 1):
     """The point-to-point twin of ``_epoch_shardmap(ring=False)``: the
     chunk's permutations are ALSO host values here, so every block move
     compiles to a static-pair ``ppermute`` — each device receives exactly
@@ -355,9 +293,6 @@ def _epoch_shardmap_p2p(mesh: Mesh, p: int, db: int, loss_name: str,
                                    gw_b, alpha_q, ga_q, arrays_q, yq, rnq,
                                    tcnq, trnq, eta_t, row_batches)
 
-        mb = yq.shape[0]
-        n_f = len(TELEMETRY_FIELDS)
-
         def make_epoch(route):
             def fetch(c, r_next):
                 # the p2p fetch: one static ppermute, switch-dispatched on
@@ -376,59 +311,39 @@ def _epoch_shardmap_p2p(mesh: Mesh, p: int, db: int, loss_name: str,
 
             def epoch(carry, xs):
                 eta_t, perm_e = xs
-                if telemetry:
-                    carry = carry + (jnp.zeros((p, n_f), jnp.float32),)
 
                 def inner(r, c):
-                    w_blk, gw_blk, alpha_q, ga_q = c[:4]
+                    w_blk, gw_blk, alpha_q, ga_q = c
                     w_blk, gw_blk = fetch((w_blk, gw_blk), r)
                     blk_id = perm_e[r, q]
                     w_new, a_new, gw_new, ga_new = step_block(
                         blk_id, w_blk, gw_blk, alpha_q, ga_q, eta_t)
-                    out = ()
-                    if telemetry:
-                        trn_blk = jax.lax.dynamic_slice(
-                            trnq, (blk_id, 0), (1, mb))[0]
-                        out = (c[4].at[r].set(telemetry_row(
-                            w_blk, w_new, alpha_q, a_new, gw_new, ga_new,
-                            trn_blk)),)
-                    return (w_new, gw_new, a_new, ga_new) + out
+                    return (w_new, gw_new, a_new, ga_new)
 
                 carry = jax.lax.fori_loop(0, p, inner, carry)
                 # restore the epoch-start invariant: device q holds block q
-                w_blk, gw_blk, alpha_q, ga_q = carry[:4]
+                w_blk, gw_blk, alpha_q, ga_q = carry
                 w_blk, gw_blk = fetch((w_blk, gw_blk), jnp.int32(p))
-                out = (w_blk, gw_blk, alpha_q, ga_q)
-                return ((out, carry[4]) if telemetry else (out, None))
+                return (w_blk, gw_blk, alpha_q, ga_q), None
 
             return epoch
 
         carry = (w_blk, gw_blk, alpha_q, ga_q)
         if uniform:
             # one traced epoch body reused for every epoch in the chunk
-            carry, tbufs = jax.lax.scan(make_epoch(routes[0]), carry,
-                                        (etas, perms))
+            carry, _ = jax.lax.scan(make_epoch(routes[0]), carry,
+                                    (etas, perms))
         else:
-            tb = []
             for e in range(n):
-                carry, tbuf_e = make_epoch(routes[e])(
-                    carry, (etas[e], perms[e]))
-                tb.append(tbuf_e)
-            tbufs = jnp.stack(tb) if telemetry else None
+                carry, _ = make_epoch(routes[e])(carry, (etas[e], perms[e]))
         w_blk, gw_blk, alpha_q, ga_q = carry
-        out = (w_blk[None], gw_blk[None], alpha_q[None], ga_q[None])
-        if telemetry:
-            out = out + (tbufs[:, :, None, :],)
-        return out
+        return (w_blk[None], gw_blk[None], alpha_q[None], ga_q[None])
 
-    out_specs = (P("dso"), P("dso"), P("dso"), P("dso"))
-    if telemetry:
-        out_specs = out_specs + (P(None, None, "dso"),)
     sharded = jax.shard_map(
         epochs_body, mesh=mesh,
         in_specs=(P("dso"),) * (n_data + 4) + (P(None),)
         + (P("dso"),) * 4 + (P(), P(), P(), P(), P(), P()),
-        out_specs=out_specs,
+        out_specs=(P("dso"),) * 4,
         check_vma="pallas" not in backend_name,
     )
     donate = tuple(range(n_data + 5, n_data + 9))   # w, gw, alpha, ga
@@ -460,16 +375,11 @@ class ShardedDSO:
                  row_batches: int = 1, use_adagrad: bool = True,
                  alpha0: float = 0.0, impl: str = "jnp",
                  schedule: str = "cyclic", seed: int = 0, obs=None,
-                 overlap: bool = True, comm: str = "auto",
-                 telemetry=None):
+                 overlap: bool = True, comm: str = "auto"):
         self.prob = prob
         # observability seam (duck-typed recorder or None; never required):
         # metrics() mirrors its eval scalars into obs gauges when attached
         self.obs = obs
-        # telemetry seam (duck-typed TelemetrySpec or None): the epoch
-        # functions grow the device-side telemetry output and run_epochs
-        # drains it per chunk (trajectories bit-identical either way)
-        self.telemetry = telemetry
         self.mesh = mesh or make_dso_mesh()
         self.p = self.mesh.devices.size
         self.backend, data = resolve_backend_and_build(prob, impl, self.p,
@@ -478,7 +388,7 @@ class ShardedDSO:
         self.schedule = get_schedule(schedule)
         self.key = jax.random.PRNGKey(seed)
         check_tile_stats(data, row_batches)
-        tile = as_tile_data(data, bucketed_payload=self.backend.payload)
+        tile = as_tile_data(data)
         _, self.mb, self.db = tile_dims(tile)
         state = init_state(prob, data, alpha0)
         self.use_adagrad = use_adagrad
@@ -527,8 +437,7 @@ class ShardedDSO:
         self._epochs_fn = (None if self._p2p else _epoch_shardmap(
             self.mesh, self.p, self.db, prob.loss_name, prob.reg_name,
             use_adagrad, row_batches, backend_name=self.backend.name,
-            ring=self.schedule.ring, n_data=n_data, overlap=self.overlap,
-            telemetry=self.telemetry is not None))
+            ring=self.schedule.ring, n_data=n_data, overlap=self.overlap))
 
     def _p2p_fn(self, perms_host: np.ndarray):
         """The jitted p2p chunk function for these host permutations,
@@ -542,18 +451,14 @@ class ShardedDSO:
                 self.mesh, self.p, self.db, self.prob.loss_name,
                 self.prob.reg_name, self.use_adagrad, self.row_batches,
                 perms_host, backend_name=self.backend.name,
-                n_data=self._n_data,
-                telemetry=self.telemetry is not None)
+                n_data=self._n_data)
         self._p2p_cache[key] = fn       # re-insert: most-recently-used
         while len(self._p2p_cache) > 8:
             self._p2p_cache.pop(next(iter(self._p2p_cache)))
         return fn
 
     def run_epochs(self, n: int, eta0: float = 0.1):
-        """Run ``n`` epochs in one donated-scan dispatch.  With a
-        telemetry spec attached the chunk's device buffer is drained here
-        (which syncs on the device->host fetch — the chunk wall it hands
-        the spec times completed epochs)."""
+        """Run ``n`` epochs in one donated-scan dispatch."""
         self.eta0_record = eta0
         t0 = self.epochs_done
         etas = eta_schedule(eta0, t0, n, self.use_adagrad)
@@ -562,22 +467,10 @@ class ShardedDSO:
         self.key, perms = self.schedule.draw(self.key, t0, n, self.p, **ctx)
         fn = (self._p2p_fn(np.asarray(perms)) if self._p2p
               else self._epochs_fn)
-        t_wall = time.perf_counter() if self.telemetry is not None else 0.0
-        out = fn(
+        self.w, self.gw, self.alpha, self.ga = fn(
             *self._data_shards, self.yg, self.rng_, self.tcn, self.trn,
             self.col_nnz, self.w, self.gw, self.alpha, self.ga, etas,
             perms, self.lam, self.m_f, self.w_lo, self.w_hi)
-        if self.telemetry is not None:
-            self.w, self.gw, self.alpha, self.ga, tbuf = out
-            jax.block_until_ready(tbuf)
-            transport = ("ring" if self.schedule.ring
-                         else ("p2p" if self._p2p else "allgather"))
-            self.telemetry.drain(
-                tbuf, t0=t0, etas=etas, perms=np.asarray(perms),
-                db=self.db, transport=transport,
-                wall_s=time.perf_counter() - t_wall)
-        else:
-            self.w, self.gw, self.alpha, self.ga = out
         self.epochs_done += n
 
     def epoch(self, eta0: float = 0.1):

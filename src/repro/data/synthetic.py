@@ -35,10 +35,9 @@ def make_classification(m: int = 2000, d: int = 500, density: float = 0.05,
 def powerlaw_columns(rng, m: int, d: int, nnz_per_row: int,
                      alpha: float) -> np.ndarray:
     """(m, nnz_per_row) column indices, ascending within each row, with
-    column j drawn ~ (j+1)^-alpha — the ONE power-law skew model shared by
-    the skewed Problem generator below and the benchmark's CSR generator
-    (benchmarks/dso_perf.py), so tests and gates measure the same
-    distribution."""
+    column j drawn ~ (j+1)^-alpha — the power-law skew model of the skewed
+    Problem generator below; ``chip_smoke.powerlaw_csr`` draws the same
+    distribution in bulk."""
     pop = np.arange(1, d + 1, dtype=np.float64) ** (-alpha)
     pop /= pop.sum()
     cols = np.empty((m, nnz_per_row), np.int64)

@@ -19,12 +19,11 @@ code-path *product* into a *sum*:
         |     column chunks + an int32 chunk_lut/chunk_cnt table mapping
         |     tile (q, b) -> its chunk list; impl="auto" picks it when
         |     tile_k_skew >= BUCKET_SKEW_THRESHOLD in the sparse regime)
-        |  as_tile_data(bucketed_payload="flat" | "buckets")
+        |  as_tile_data
         v
    TileData  (the common pytree: arrays=(Xg,) | (cols_g, vals_g) |
-        |     flat (cols_fl, vals_fl, chunk_lut, chunk_cnt) — or, for the
-        |     legacy _switch backends, per-bucket (cols, vals)... +
-        |     (bucket_id, bucket_pos) — labels, nnz stats, padding masks)
+        |     flat (cols_fl, vals_fl, chunk_lut, chunk_cnt) — labels,
+        |     nnz stats, padding masks)
         |
    +----+------------------- ENGINE ---------------------------------+
    |                                                                 |
@@ -38,9 +37,7 @@ code-path *product* into a *sum*:
    |    sparse_bucketed_pallas /                        nnz costs;   |
    |      (ONE kernel: scalar- |                        balanced=True|
    |       prefetched index    |                        -> draw gets |
-   |       map walks chunk_lut;|                        tile_nnz)    |
-   |       *_switch = legacy   |                                     |
-   |       per-bucket launch)  |                                     |
+   |       map walks chunk_lut)|                        tile_nnz)    |
    |         |                 |                fixed(perms)         |
    |         |                 |                  |  draw(key,t0,n,p |
    |         |                 |                  |       [,tile_nnz])
@@ -82,19 +79,7 @@ code-path *product* into a *sum*:
    |  entry, snapshot_save / restore / eval spans; obs=None (default)  |
    |  adds NO calls and NO allocations to the chunk loop and keeps     |
    |  trajectories bit-identical (the metrics-off contract, pinned by  |
-   |  tests/test_obs.py and the obs_overhead gate in BENCH_dso.json)   |
-   |                                                                   |
-   |  solve(..., telemetry=spec): the DEVICE-side lane — the chunk     |
-   |  runs run_epochs_telemetry, a sibling jitted scan whose extra     |
-   |  carry accumulates a (n, p, p, 5) buffer of per-(epoch, inner     |
-   |  iteration r, worker q) TELEMETRY_FIELDS (dw/dalpha update norms, |
-   |  tile rows/nnz, nonfinite probes), drained at every chunk         |
-   |  boundary into spec.drain() with the chunk's etas + perms (the    |
-   |  host prices comm bytes per transport there); requires            |
-   |  scan_epochs=True; telemetry=None compiles the SAME run_epochs as |
-   |  before — bit-identical, zero overhead.  driver.py keeps its own  |
-   |  literal TELEMETRY_FIELDS copy: the engine never imports          |
-   |  repro.obs (tuple equality pinned by tests/test_obs.py)           |
+   |  tests/test_obs.py)                                               |
    +-------------------------------------------------------------------+
 
    +--------------------- RUNTIME (repro/runtime) ---------------------+
@@ -152,9 +137,9 @@ from repro.engine.data import (DSOState, GridData, TileData, as_tile_data,
                                check_tile_stats, eta_schedule, gather_alpha,
                                gather_w, init_state, init_state_data,
                                make_grid_data, prob_meta, tile_dims)
-from repro.engine.driver import (SolveResult, inner_iteration, run_epoch,
-                                 run_epochs, solve, solve_serial,
-                                 stage_block, staged_step, warn_ragged_eval)
+from repro.engine.driver import (SolveResult, inner_iteration, run_epochs,
+                                 solve, solve_serial, stage_block,
+                                 staged_step, warn_ragged_eval)
 from repro.engine.evaluate import (make_csr_primal_eval, pd_gap_eval_hook,
                                    problem_eval_hook)
 from repro.engine.schedules import (SCHEDULES, Schedule, cyclic_perms,
@@ -168,7 +153,7 @@ __all__ = [
     "DSOState", "GridData", "TileData", "as_tile_data", "check_tile_stats",
     "eta_schedule", "gather_alpha", "gather_w", "init_state",
     "init_state_data", "make_grid_data", "prob_meta", "tile_dims",
-    "SolveResult", "inner_iteration", "run_epoch", "run_epochs", "solve",
+    "SolveResult", "inner_iteration", "run_epochs", "solve",
     "solve_serial", "stage_block", "staged_step", "warn_ragged_eval",
     "make_csr_primal_eval",
     "pd_gap_eval_hook", "problem_eval_hook",

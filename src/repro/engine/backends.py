@@ -1,4 +1,4 @@
-"""TileBackend registry: one ``block_step`` contract, five implementations.
+"""TileBackend registry: one ``block_step`` contract, seven implementations.
 
 A backend is the pairing of a *layout* (how the grid's tiles are stored:
 dense row shards or packed block-ELL) with a *kernel* (how the Eq.-(8)
@@ -35,25 +35,12 @@ Registered backends:
                           prefetched chunk lut drives the index map, no
                           ``lax.switch`` anywhere — bit-identical to
                           sparse_bucketed_jnp by construction
-  sparse_bucketed_jnp_switch / sparse_bucketed_pallas_switch
-                        — the legacy bucket dispatch: ``lax.switch`` over
-                          the tile's bucket into the uniform-K step at that
-                          bucket's packed width (kept as the comparison
-                          baseline; equal to the one-kernel pair to f32
-                          reduction order, not bitwise)
 
-Bucketed payload note: the one-kernel pair streams the flat chunk view
-``(cols_fl, vals_fl, chunk_lut, chunk_cnt)``; the ``_switch`` pair needs
-the per-bucket rectangles + (p, p) index maps.  ``TileBackend.payload``
-("flat" | "buckets") records which variant a backend consumes, and every
-driver passes it to ``as_tile_data(..., bucketed_payload=...)``.  Inside
-``shard_map`` (one device per processor) the active tile's scalar lut
-prefetch (or, for _switch, the scalar bucket index) means only that tile's
-``mb * K_bucket`` bytes stream from HBM — the layout's whole point.  Under
-the single-device grid simulator's vmap the switch lowers to a select that
-evaluates every branch, while the one-kernel path stays one dynamic-sliced
-stream — which is why it also wins wall-clock in the simulator
-(``benchmarks/dso_perf.py --bucketed-onekernel``).
+Bucketed payload note: the bucketed pair streams the flat chunk view
+``(cols_fl, vals_fl, chunk_lut, chunk_cnt)``.  Inside ``shard_map`` (one
+device per processor) the active tile's scalar lut prefetch means only
+that tile's ``mb * K_bucket`` bytes stream from HBM — the layout's whole
+point.
 
 Legacy ``impl`` selectors ("jnp", "pallas", "sparse", "sparse_pallas",
 "auto") resolve through ``resolve_backend``, ``auto``'s kernel once the
@@ -78,9 +65,6 @@ class TileBackend(NamedTuple):
     layout: str             # "dense" | "sparse" | "bucketed"
     select_block: Callable  # (arrays_q, blk_id, blk_cols, db) -> block tuple
     block_step: Callable    # see module docstring
-    payload: str = "flat"   # bucketed payload variant this backend consumes
-                            # ("flat" chunk view | "buckets" rectangles);
-                            # ignored by dense/sparse layouts
 
 
 # --------------------------------------------------------------- selects --
@@ -104,7 +88,7 @@ def _payload_select(arrays_q, blk_id, blk_cols, db):
     # block id added: the one-hot sparse kernel reads the tile in place
     # (the id drives its index map, so no tile is sliced out in HBM), and
     # the bucketed tile slice is width-dependent, picked by the step via
-    # its lut row (flat) or its lax.switch branch (buckets)
+    # its lut row
     return tuple(arrays_q) + (blk_id,)
 
 
@@ -186,64 +170,20 @@ _sparse_jnp_block_step = _make_jnp_block_step(_sparse_slice,
                                               sparse_tile_step)
 
 
-def _make_bucketed_block_step(sparse_block_step):
-    """Bucket dispatch over any sparse-layout block step: look up the
-    active tile's (bucket, slot), then ``lax.switch`` into the branch that
-    slices that bucket's (mb, K_k) tile and runs the wrapped step on it.
-    Branch outputs are K-independent (the updated state vectors), so the
-    switch is shape-legal even though every bucket has a different width.
-    """
-
-    def step(meta, block, y_q, w_blk, alpha_q, gw_blk, ga_q, rn_q,
-             col_nnz_blk, trn_blk, tcn_blk, eta_t, row_batches):
-        *payload, bid_q, pos_q, blk_id = block
-        n_buckets = len(payload) // 2
-        bid = jax.lax.dynamic_index_in_dim(bid_q, blk_id, keepdims=False)
-        pos = jax.lax.dynamic_index_in_dim(pos_q, blk_id, keepdims=False)
-        operands = (pos, y_q, w_blk, alpha_q, gw_blk, ga_q, rn_q,
-                    col_nnz_blk, trn_blk, tcn_blk, eta_t)
-
-        def make_branch(k):
-            cols_k, vals_k = payload[2 * k], payload[2 * k + 1]
-
-            def branch(ops_):
-                (pos, y_q, w_blk, alpha_q, gw_blk, ga_q, rn_q,
-                 col_nnz_blk, trn_blk, tcn_blk, eta_t) = ops_
-                _, mb, K = cols_k.shape
-                # a foreign-bucket pos is clamped by dynamic_slice; the
-                # garbage branch result is discarded by the switch/select
-                cols_blk = jax.lax.dynamic_slice(
-                    cols_k, (pos, 0, 0), (1, mb, K))[0]
-                vals_blk = jax.lax.dynamic_slice(
-                    vals_k, (pos, 0, 0), (1, mb, K))[0]
-                return sparse_block_step(
-                    meta, (cols_blk, vals_blk), y_q, w_blk, alpha_q,
-                    gw_blk, ga_q, rn_q, col_nnz_blk, trn_blk, tcn_blk,
-                    eta_t, row_batches)
-
-            return branch
-
-        return jax.lax.switch(
-            bid, [make_branch(k) for k in range(n_buckets)], operands)
-
-    return step
-
-
 def _sparse_pallas_block_step(meta, block, y_q, w_blk, alpha_q, gw_blk, ga_q,
                               rn_q, col_nnz_blk, trn_blk, tcn_blk, eta_t,
                               row_batches):
-    """``block`` is ``(cols_q, vals_q, blk_id)`` (the processor's payload
-    and its active tile) or, from the bucket switch, one sliced
-    ``(cols_blk, vals_blk)`` tile."""
+    """``block`` is ``(cols_q, vals_q, blk_id)``: the processor's payload
+    and its active tile."""
     from repro.kernels import ops
     lam, m, loss_name, reg_name, use_adagrad, w_lo, w_hi = meta
-    cols, vals, *blk_id = block
+    cols, vals, blk_id = block
     scalars = jnp.stack([eta_t, lam, m, w_lo, w_hi]).astype(jnp.float32)
     return ops.dso_sparse_block_step(
         cols, vals, y_q, w_blk, alpha_q, gw_blk, ga_q, trn_blk, tcn_blk,
         rn_q, col_nnz_blk, scalars, row_batches=row_batches,
         loss_name=loss_name, reg_name=reg_name, use_adagrad=use_adagrad,
-        blk_id=blk_id[0] if blk_id else None)
+        blk_id=blk_id)
 
 
 def _bucketed_flat_args(meta, block):
@@ -429,9 +369,3 @@ register_backend(TileBackend(
 register_backend(TileBackend(
     "sparse_bucketed_pallas", "bucketed", _payload_select,
     _make_bucketed_flat_block_step(use_pallas=True)))
-register_backend(TileBackend(
-    "sparse_bucketed_jnp_switch", "bucketed", _payload_select,
-    _make_bucketed_block_step(_sparse_jnp_block_step), payload="buckets"))
-register_backend(TileBackend(
-    "sparse_bucketed_pallas_switch", "bucketed", _payload_select,
-    _make_bucketed_block_step(_sparse_pallas_block_step), payload="buckets"))

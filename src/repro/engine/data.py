@@ -6,8 +6,7 @@ The p x p DSO grid exists in three layouts — dense row shards
 (``sparse.format.BucketedGridData``).  The engine does not care which:
 ``as_tile_data`` converts any of them into a ``TileData`` whose ``arrays``
 field carries the layout payload (``(Xg,)`` dense, ``(cols_g, vals_g)``
-sparse, and for bucketed either the flat chunk view + offset tables or the
-legacy per-bucket ``(cols, vals)`` pairs + (p, p) index maps — see
+sparse, and for bucketed the flat chunk view + its chunk tables — see
 ``TileData``) next to the layout-independent labels, scaling statistics,
 and padding masks.  Every backend's block step and the single epoch driver
 consume only ``TileData``.
@@ -56,18 +55,15 @@ class TileData(NamedTuple):
 
     ``arrays`` is the layout payload — ``(Xg,)`` for the dense backends,
     ``(cols_g, vals_g)`` for the block-ELL sparse backends, and for the
-    K-bucketed ragged backends one of two variants (``as_tile_data``'s
-    ``bucketed_payload``): the default ``"flat"`` chunk view
-    ``(cols_fl, vals_fl, chunk_lut, chunk_cnt)`` the one-kernel backends
-    stream, or the legacy ``"buckets"`` form ``(cols_0, vals_0, ...,
-    cols_{B-1}, vals_{B-1}, bucket_id, bucket_pos)`` the ``lax.switch``
-    backends dispatch over; everything else is identical between layouts
+    K-bucketed ragged backends the flat chunk view
+    ``(cols_fl, vals_fl, chunk_lut, chunk_cnt)`` they stream; everything
+    else is identical between layouts
     (and identical in VALUE too: all tilers reproduce ``make_grid_data``'s
     statistics exactly, which is what makes the trajectories match across
     backends).
     """
 
-    arrays: tuple          # (Xg,) | (cols_g, vals_g) | bucketed payload
+    arrays: tuple          # (Xg,) | (cols_g, vals_g) | flat chunk view
     yg: Array              # (p, mb)
     row_nnz_g: Array       # (p, mb)
     col_nnz: Array         # (d_pad,)
@@ -81,7 +77,7 @@ class TileData(NamedTuple):
             return "dense"
         if len(self.arrays) == 2:
             return "sparse"
-        return "bucketed"      # flat chunk view or per-bucket cols/vals
+        return "bucketed"      # the flat chunk view
 
 
 class DSOState(NamedTuple):
@@ -92,31 +88,14 @@ class DSOState(NamedTuple):
     epoch: Array     # scalar int32
 
 
-def as_tile_data(data, *, bucketed_payload: str = "flat") -> TileData:
+def as_tile_data(data) -> TileData:
     """``GridData`` | ``SparseGridData`` | ``BucketedGridData`` |
-    ``TileData`` -> ``TileData``.
-
-    ``bucketed_payload`` picks the bucketed layout's payload variant (each
-    backend requests its own via ``TileBackend.payload``): ``"flat"`` — the
-    device-resident flat chunk view the one-kernel backends stream;
-    ``"buckets"`` — the per-bucket rectangles (uploaded from their host
-    numpy form here) + index maps the legacy ``lax.switch`` backends
-    dispatch over.
-    """
+    ``TileData`` -> ``TileData``; the bucketed layout contributes its
+    device-resident flat chunk view."""
     if isinstance(data, TileData):
         return data
     if isinstance(data, BucketedGridData):
-        if bucketed_payload == "flat":
-            arrays = (data.cols_fl, data.vals_fl, data.chunk_lut,
-                      data.chunk_cnt)
-        elif bucketed_payload == "buckets":
-            arrays = tuple(jnp.asarray(a)
-                           for cv in zip(data.cols_b, data.vals_b)
-                           for a in cv) + (data.bucket_id, data.bucket_pos)
-        else:
-            raise ValueError(
-                f"bucketed_payload must be 'flat' or 'buckets', "
-                f"got {bucketed_payload!r}")
+        arrays = (data.cols_fl, data.vals_fl, data.chunk_lut, data.chunk_cnt)
     elif isinstance(data, SparseGridData):
         arrays = (data.cols_g, data.vals_g)
     else:

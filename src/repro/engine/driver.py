@@ -136,51 +136,19 @@ def inner_iteration(backend: TileBackend, meta, col_nnz, blk_id, w_blk,
                        arrays_q, y_q, rn_q, eta_t, row_batches)
 
 
-# ------------------------------------------------------ telemetry lane --
-#
-# Kept literally in sync with repro.obs.telemetry.TELEMETRY_FIELDS: the
-# engine never imports repro.obs (the telemetry= seam is duck-typed like
-# obs=/store=), so the buffer layout is defined on BOTH sides and a test
-# pins the two tuples equal.
-
-TELEMETRY_FIELDS = ("dw_norm", "dalpha_norm", "rows", "nnz", "nonfinite")
-
-
-def telemetry_row(w_old, w_new, a_old, a_new, gw_new, ga_new, trn_blk):
-    """One processor's telemetry vector for one inner iteration — the
-    device-side accumulation of ``TELEMETRY_FIELDS``.  ``trn_blk`` is the
-    active tile's per-row nnz (``tile_row_nnz_g[q, blk_id]``), a static
-    statistic: rows/nnz describe the REAL work of the (q, blk) tile, not
-    its padded shape.  Reads only before/after values — never feeds the
-    trajectory, which is what keeps telemetry-on runs bit-identical."""
-    dw = jnp.sqrt(jnp.sum(jnp.square(w_new - w_old)))
-    da = jnp.sqrt(jnp.sum(jnp.square(a_new - a_old)))
-    rows = jnp.sum((trn_blk > 0).astype(jnp.float32))
-    nnz = jnp.sum(trn_blk)
-    finite = (jnp.all(jnp.isfinite(w_new)) & jnp.all(jnp.isfinite(a_new))
-              & jnp.all(jnp.isfinite(gw_new)) & jnp.all(jnp.isfinite(ga_new)))
-    return jnp.stack([dw, da, rows, nnz,
-                      1.0 - finite.astype(jnp.float32)])
-
-
 # ---------------------------------------------------------- epoch body --
 
 
 def epoch_body(backend: TileBackend, data: TileData, state: DSOState, perm,
-               eta_t, meta, *, row_batches: int, p: int,
-               telemetry: bool = False):
+               eta_t, meta, *, row_batches: int, p: int):
     """One epoch under an explicit ``(p, p)`` permutation schedule:
     ``perm[r, q]`` = block owned by processor q at inner iteration r.
     All p processors update their disjoint blocks simultaneously (vmap) —
     Lemma 2's block-disjointness makes this equal to any serial order.
-
-    ``telemetry=True`` (static) additionally accumulates the per-(r, q)
-    ``TELEMETRY_FIELDS`` buffer and returns ``(state, buf)`` with ``buf``
-    of shape (p, p, F); the update math is byte-identical either way (the
-    telemetry rows only *read* before/after values).
     """
 
-    def apply(st: DSOState, blk_ids):
+    def inner(r, st: DSOState) -> DSOState:
+        blk_ids = perm[r]
         # gather the w blocks each processor owns this inner iteration
         w_owned = jnp.take(st.w_grid, blk_ids, axis=0)    # (p, db)
         gw_owned = jnp.take(st.gw_grid, blk_ids, axis=0)
@@ -199,44 +167,14 @@ def epoch_body(backend: TileBackend, data: TileData, state: DSOState, perm,
             data.tile_row_nnz_g)
         w_grid = st.w_grid.at[blk_ids].set(w_new)
         gw_grid = st.gw_grid.at[blk_ids].set(gw_new)
-        new = DSOState(w_grid, gw_grid, a_new, ga_new, st.epoch)
-        return new, (w_owned, w_new, st.alpha, a_new, gw_new, ga_new)
+        return DSOState(w_grid, gw_grid, a_new, ga_new, st.epoch)
 
-    if not telemetry:
-        def inner(r, st: DSOState) -> DSOState:
-            new, _ = apply(st, perm[r])
-            return new
-
-        state = jax.lax.fori_loop(0, p, inner, state)
-        return state._replace(epoch=state.epoch + 1)
-
-    def inner_tel(r, carry):
-        st, buf = carry
-        blk_ids = perm[r]
-        new, (w_o, w_n, a_o, a_n, gw_n, ga_n) = apply(st, blk_ids)
-        # the active tiles' per-row nnz: tile_row_nnz_g[q, blk_ids[q], :]
-        trn = jnp.take_along_axis(data.tile_row_nnz_g,
-                                  blk_ids[:, None, None], axis=1)[:, 0, :]
-        row = jax.vmap(telemetry_row)(w_o, w_n, a_o, a_n, gw_n, ga_n, trn)
-        return new, buf.at[r].set(row)
-
-    buf0 = jnp.zeros((p, p, len(TELEMETRY_FIELDS)), jnp.float32)
-    state, buf = jax.lax.fori_loop(0, p, inner_tel, (state, buf0))
-    return state._replace(epoch=state.epoch + 1), buf
+    state = jax.lax.fori_loop(0, p, inner, state)
+    return state._replace(epoch=state.epoch + 1)
 
 
 _EPOCH_STATICS = ("backend", "loss_name", "reg_name", "use_adagrad",
                   "row_batches", "p", "db")
-
-
-@functools.partial(jax.jit, static_argnames=_EPOCH_STATICS)
-def run_epoch(data: TileData, state: DSOState, perm, eta_t, lam, m, w_lo,
-              w_hi, *, backend, loss_name, reg_name, use_adagrad,
-              row_batches, p, db):
-    """One epoch, one dispatch (legacy / benchmark-baseline path)."""
-    meta = (lam, m, loss_name, reg_name, use_adagrad, w_lo, w_hi)
-    return epoch_body(get_backend(backend), data, state, perm, eta_t, meta,
-                      row_batches=row_batches, p=p)
 
 
 @functools.partial(jax.jit, static_argnames=_EPOCH_STATICS,
@@ -260,30 +198,6 @@ def run_epochs(data: TileData, state: DSOState, perms, etas, lam, m, w_lo,
 
     state, _ = jax.lax.scan(step, state, (perms, etas))
     return state
-
-
-@functools.partial(jax.jit, static_argnames=_EPOCH_STATICS,
-                   donate_argnums=(1,))
-def run_epochs_telemetry(data: TileData, state: DSOState, perms, etas, lam,
-                         m, w_lo, w_hi, *, backend, loss_name, reg_name,
-                         use_adagrad, row_batches, p, db):
-    """``run_epochs`` with the telemetry carry: same donated scan, same
-    update math, plus the per-(epoch, r, q) ``TELEMETRY_FIELDS`` buffer as
-    a second output of shape (n_epochs, p, p, F) — accumulated INSIDE the
-    scan, drained host-side at the chunk boundary.  A separate jitted
-    sibling (not a flag on ``run_epochs``) so the telemetry=None path's
-    compiled program and donated-scan memory profile are untouched."""
-    be = get_backend(backend)
-    meta = (lam, m, loss_name, reg_name, use_adagrad, w_lo, w_hi)
-
-    def step(st, xs):
-        perm_t, eta_t = xs
-        st, buf = epoch_body(be, data, st, perm_t, eta_t, meta,
-                             row_batches=row_batches, p=p, telemetry=True)
-        return st, buf
-
-    state, telem = jax.lax.scan(step, state, (perms, etas))
-    return state, telem
 
 
 # --------------------------------------------------- ragged-eval warning --
@@ -372,11 +286,11 @@ def _obs_eval(obs, entry):
 def solve(source, *, backend="auto", schedule="cyclic", p: int = 4,
           epochs: int = 10, eta0: float = 0.1, use_adagrad: bool = True,
           row_batches: int = 1, alpha0: float = 0.0, eval_every: int = 1,
-          seed: int = 0, eval_hook="auto", scan_epochs: bool = True,
-          loss_name: str | None = None, reg_name: str | None = None,
-          lam: float | None = None, m: int | None = None,
-          d: int | None = None, checkpoint_every: int = 0, store=None,
-          init=None, health=None, obs=None, telemetry=None) -> SolveResult:
+          seed: int = 0, eval_hook="auto", loss_name: str | None = None,
+          reg_name: str | None = None, lam: float | None = None,
+          m: int | None = None, d: int | None = None,
+          checkpoint_every: int = 0, store=None,
+          init=None, health=None, obs=None) -> SolveResult:
     """The one epoch driver behind grid / random / out-of-core execution.
 
     ``source`` is either a dense ``Problem`` (the grid data is built here,
@@ -392,8 +306,7 @@ def solve(source, *, backend="auto", schedule="cyclic", p: int = 4,
     objectives for a Problem source, no evaluation for data sources).
 
     Epochs between evaluation points run as ONE donated-scan dispatch
-    (``run_epochs``); ``scan_epochs=False`` keeps the legacy
-    one-dispatch-per-epoch loop (benchmark baseline).  Identical math.
+    (``run_epochs``).
 
     Elastic-runtime seam (``repro.runtime``): ``checkpoint_every=k`` adds
     chunk boundaries at every k-th GLOBAL epoch, and ``store`` (duck-typed,
@@ -437,24 +350,9 @@ def solve(source, *, backend="auto", schedule="cyclic", p: int = 4,
     hook raises.  ``obs=None`` (default) is a true no-op: no obs calls,
     no allocations, bit-identical trajectories.
 
-    Telemetry seam (``repro.obs.telemetry``): ``telemetry`` (duck-typed,
-    e.g. ``TelemetrySpec``) turns on the device-resident telemetry lane —
-    the chunk runs through ``run_epochs_telemetry``, which accumulates the
-    per-(epoch, inner iteration, processor) ``TELEMETRY_FIELDS`` buffer
-    INSIDE the donated epoch scan, and ``telemetry.drain(...)`` receives
-    it at every chunk boundary (with the chunk's etas, permutations, block
-    width and transport label — "ring" for the cyclic schedule, "p2p" for
-    general permutations, matching ``ShardedDSO``'s default routing).
-    The telemetry rows only read before/after values, so telemetry-on
-    trajectories are bit-identical to telemetry-off; ``telemetry=None``
-    (default) is a true no-op running the untouched ``run_epochs``.
-    Requires ``scan_epochs=True``.
     """
     if eval_every < 1:
         raise ValueError(f"eval_every must be >= 1, got {eval_every}")
-    if telemetry is not None and not scan_epochs:
-        raise ValueError("telemetry requires scan_epochs=True (the buffer "
-                         "is an extra carry of the donated epoch scan)")
     if checkpoint_every < 0:
         raise ValueError(
             f"checkpoint_every must be >= 0, got {checkpoint_every}")
@@ -504,15 +402,14 @@ def solve(source, *, backend="auto", schedule="cyclic", p: int = 4,
         if obs is not None:    # which tile kernel this solve runs
             span.set(backend=be.name)
         check_tile_stats(data, row_batches)
-        tile = as_tile_data(data, bucketed_payload=be.payload)
+        tile = as_tile_data(data)
         p_, mb_, db = tile_dims(tile)
         kw = dict(backend=be.name, loss_name=loss_name, reg_name=reg_name,
                   use_adagrad=use_adagrad, row_batches=row_batches, p=p_,
                   db=db)
 
         chunk = eval_every if eval_hook is not None else epochs
-        if scan_epochs:
-            warn_ragged_eval(epochs, chunk)
+        warn_ragged_eval(epochs, chunk)
         # balanced schedules (lpt) weigh the per-tile nnz; computed once
         sched_ctx = ({"tile_nnz":
                       np.asarray(tile.tile_row_nnz_g).sum(axis=-1)}
@@ -584,18 +481,8 @@ def solve(source, *, backend="auto", schedule="cyclic", p: int = 4,
                 span.__exit__(None, None, None)
                 span = _enter(obs, "chunk_dispatch")
                 t_chunk = time.perf_counter()
-            if telemetry is not None:
-                t_tel = time.perf_counter()
-                state, tbuf = run_epochs_telemetry(tile, state, perms, etas,
-                                                   lam_f, m_f, w_lo, w_hi,
-                                                   **kw)
-            elif scan_epochs:
-                state = run_epochs(tile, state, perms, etas, lam_f, m_f,
-                                   w_lo, w_hi, **kw)
-            else:
-                for k in range(n):
-                    state = run_epoch(tile, state, perms[k], etas[k], lam_f,
-                                      m_f, w_lo, w_hi, **kw)
+            state = run_epochs(tile, state, perms, etas, lam_f, m_f, w_lo,
+                               w_hi, **kw)
             if obs is not None:
                 span.__exit__(None, None, None)
                 span = _enter(obs, "chunk_wait")
@@ -604,14 +491,6 @@ def solve(source, *, backend="auto", schedule="cyclic", p: int = 4,
                 record_chunk(n, time.perf_counter() - t_chunk, eta_live)
                 span.__exit__(None, None, None)
                 span_chunk.__exit__(None, None, None)
-            if telemetry is not None:
-                # drain outside the span: the device->host copy is host obs
-                # work, not epoch time (the buffer fetch syncs the chunk)
-                jax.block_until_ready(state)
-                telemetry.drain(tbuf, t0=t, etas=etas,
-                                perms=np.asarray(perms), db=db,
-                                transport="ring" if sched.ring else "p2p",
-                                wall_s=time.perf_counter() - t_tel)
             t_new = t + n
             failure = None
             if health is not None:

@@ -85,9 +85,9 @@ class Schedule(NamedTuple):
 def cyclic_perms(n: int, p: int):
     """(n, p, p) cyclic schedule: perms[e, r, q] = (q + r) mod p.
 
-    Cached: the array is deterministic in (n, p) and the legacy per-epoch
-    dispatch path (``core.dso._grid_epoch``) asks for it every call — the
-    cache keeps that path free of repeated device dispatches.
+    Cached: the array is deterministic in (n, p) and the cyclic schedule
+    asks for it every chunk — the cache keeps the chunk loop free of
+    repeated device dispatches.
     """
     r = jnp.arange(p, dtype=jnp.int32)
     perm = (r[:, None] + r[None, :]) % p

@@ -29,15 +29,12 @@ Fused single-pass kernel (``_fused_tile_kernel``) — data flow per grid step
 
 HBM traffic per tile step: X is streamed ONCE (4*M*D bytes) instead of the
 two-pass version's twice (once per kernel) — the dominant term of the
-paper's (|Omega| T_u / p + T_c) T epoch cost. Measured by the roofline
-model in benchmarks/dso_perf.py (repo-root BENCH_dso.json) for a
-1024x1024 f32 tile with (256, 512) blocks: 4.25 MB/step fused vs 8.44
-MB/step two-pass — 1.99x less traffic, asymptotically 2x as M*D grows
-relative to the M + D vector terms. The per-tile nonzero counts
-(n_j per column, n_i per row) are *precomputed* by the callers
-(``ops.dso_tile_step`` / ``core.dso.make_grid_data``) and passed in as
-vectors instead of being re-derived from X with ``(x != 0).sum(...)`` on
-every step of every epoch.
+paper's (|Omega| T_u / p + T_c) T epoch cost: asymptotically 2x less
+traffic as M*D grows relative to the M + D vector terms.  The per-tile
+nonzero counts (n_j per column, n_i per row) are *precomputed* by the
+callers (``ops.dso_tile_step`` / ``core.dso.make_grid_data``) and passed
+in as vectors instead of being re-derived from X with
+``(x != 0).sum(...)`` on every step of every epoch.
 
 ``_fused_block_kernel`` additionally folds the ``row_batches`` sub-scan of
 ``core/dso._inner_iteration`` into the kernel grid: row tiles become
@@ -58,10 +55,8 @@ scatter-add of X^T alpha run as factored one-hot matmuls on the MXU:
                        └─> scatter add vals*alpha at cols -> X^T a  (1, bd)
     alpha (bm, 1) ─────┘       └ w update, w_st advances (sequential)
 
-At density 0.05 (4096^2, p=4 grid) that is ~6x less HBM traffic per tile
-step than this file's dense fused kernel (dso_sparse gate in
-BENCH_dso.json); both variants share ``_primal_update``/``_dual_update``
-below, so the Eq.-(8) math is written once.
+Both variants share ``_primal_update``/``_dual_update`` below, so the
+Eq.-(8) math is written once.
 
 K-bucketed ragged layout (``sparse.format.BucketedGridData``, backends
 ``sparse_bucketed_jnp``/``sparse_bucketed_pallas``) — the uniform layout
@@ -83,22 +78,14 @@ SINGLE Pallas launch whose scalar-prefetched index map walks the table
 
 so a tile step streams 8*mb*K_bucket bytes (its own width) instead of
 8*mb*max-K, and the resident grid shrinks from p^2*mb*max-K to
-sum_k slots_k*mb*K_k — epoch cost tracks real nnz, not max-K padding
-(dso_sparse_skewed gate in BENCH_dso.json: >= 3x on both).  The
-trajectory is identical to ``sparse_jnp`` (same statistics, same Eq.-8
-math; padding slots contribute exact zeros at every width), and
+sum_k slots_k*mb*K_k — epoch bytes track real nnz, not max-K padding.
+The trajectory is identical to ``sparse_jnp`` (same statistics, same
+Eq.-8 math; padding slots contribute exact zeros at every width), and
 bit-identical to ``sparse_bucketed_jnp``, whose jnp twin runs the same
-staged math.  The legacy per-bucket ``lax.switch`` dispatch survives as
-``sparse_bucketed_{jnp,pallas}_switch`` (payload="buckets": rectangular
-per-bucket cols/vals (p, slots_k, mb, K_k) + bucket_id/bucket_pos maps)
-— one launch per bucket, and under the grid simulator's vmap the switch
-lowers to a select that executes EVERY bucket's branch (dso_onekernel
-gate in BENCH_dso.json: one-kernel >= 1.3x faster per epoch at tile-K
-skew >= 4).
+staged math.
 
 The legacy two-pass kernels are kept as ``dso_tile_step_pallas_twopass``
-for regression tests and the fused-vs-two-pass benchmark
-(benchmarks/dso_perf.py; see repo-root BENCH_dso.json).
+for the fused-vs-two-pass regression test (tests/test_dso_fused.py).
 
 Block shapes default to (256, 512) float32 — 512 KiB per X block, well under
 VMEM, with the MXU-aligned 128-multiple on both axes.

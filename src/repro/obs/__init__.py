@@ -20,15 +20,6 @@ summary dict:
                  typed LedgerEvent stream (record_ledger), so health and
                  replan decisions land between the throughput samples
                  that motivated them.
-   telemetry.py  TelemetrySpec: the DEVICE-side lane — per-(epoch, inner
-                 iteration r, worker q) buffers of update norms, rows/nnz
-                 processed, and nonfinite flags accumulated as an extra
-                 carry INSIDE the jitted epoch scan, drained host-side at
-                 chunk boundaries into ``type="telemetry"`` events; comm
-                 bytes per slot priced from the schedule's permutations
-                 (ring / p2p routes / allgather).  Heatmap renderers
-                 (nnz_throughput, wall_balance) fold the stream into the
-                 per-tile matrices ``report.py --section heatmap`` shows.
 
 Seams (all duck-typed ``obs=``, default ``None`` — the layers below never
 import this package):
@@ -47,14 +38,6 @@ import this package):
   serving.DecodeEngine(obs=)       serve_batch spans, request/token
                                    counters, tokens/s gauge
 
-plus the device lane (duck-typed ``telemetry=``, default ``None``):
-
-  engine.solve(..., telemetry=spec)        grid scan telemetry carry
-  ShardedDSO(..., telemetry=spec)          sharded scan telemetry carry
-  runtime.Supervisor(..., telemetry=spec)  threads the spec through every
-                                           rebuild/reshard AND attributes
-                                           simulated straggler sleeps
-
 Event schema — one JSON object per line, ``seq`` (monotone int) and
 ``ts`` (seconds since recorder construction) on every event:
 
@@ -65,17 +48,9 @@ Event schema — one JSON object per line, ``seq`` (monotone int) and
       "parent", "solve"[, "attrs"]}
   {"seq", "ts", "type": "ledger", "kind", "epoch", "action",
       "epochs_lost", "retry", ...detail fields}
-  {"seq", "ts", "type": "telemetry", "kind": "chunk", "t0", "epochs",
-      "p", "db", "transport": "ring"|"p2p"|"allgather", "wall_s",
-      "eta": [per-epoch], "nonfinite": int, and per-(epoch, r, q) nested
-      lists "dw_norm", "dalpha_norm", "rows", "nnz", "comm_bytes"}
-  {"seq", "ts", "type": "telemetry", "kind": "delay", "worker",
-      "seconds", "t0", "epochs"}   (host-attributed straggler wall time)
 
-``benchmarks/report.py --section run-report --events <log.jsonl>``
-renders a log into the human-readable scaling/recovery report, and
-``examples/elastic_dso.py --chaos`` writes one per run (uploaded as the
-CI chaos artifact).
+``examples/elastic_dso.py --chaos --events-out <log.jsonl>`` writes one
+per run (uploaded as the CI chaos artifact).
 
 METRICS-OFF CONTRACT: every seam defaults to ``obs=None`` and guards all
 instrumentation behind ``if obs is not None``.  With ``obs=None`` the
@@ -90,15 +65,10 @@ emits, and PERF.md keeps the traced cost measured on the chip.
 from repro.obs.metrics import (Counter, Gauge, Histogram, Metric,
                                MetricRegistry)
 from repro.obs.recorder import RunRecorder, iter_events, read_events
-from repro.obs.telemetry import (TELEMETRY_FIELDS, TelemetrySpec,
-                                 comm_bytes_matrix, nnz_throughput,
-                                 render_heatmap, wall_balance)
 from repro.obs.trace import SpanTracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Metric", "MetricRegistry",
     "RunRecorder", "iter_events", "read_events",
-    "TELEMETRY_FIELDS", "TelemetrySpec", "comm_bytes_matrix",
-    "nnz_throughput", "render_heatmap", "wall_balance",
     "SpanTracer",
 ]

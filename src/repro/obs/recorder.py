@@ -2,9 +2,8 @@
 
 Every update lands in ONE ordered in-memory event list (monotone ``seq``,
 relative ``ts`` seconds from recorder construction) and is written as one
-JSONL line per event — the run-event log the acceptance criteria, the
-chaos example, and ``benchmarks/report.py run-report`` consume.  The three
-producers:
+JSONL line per event — the run-event log the tests and the chaos example
+read back.  The three producers:
 
   obs.metrics   — ``rec.metrics.gauge("rows_per_s").set(...)`` (the
                   registry is bound to the recorder at construction)

@@ -72,20 +72,10 @@ imports it):
         |   epoch_chunk land as timed spans next to the per-chunk
         |   throughput gauges — one ordered run-event JSONL stream
         v
-        obs.RunRecorder ──> benchmarks/report.py --section run-report
+        obs.RunRecorder ──> the JSONL run-event log (obs.read_events)
 
-        Supervisor(..., telemetry=spec)  the device-side lane rides the
-        |   same seam: the spec threads into every ShardedDSO the
-        |   supervisor builds (rebuilds after crashes, replans, live
-        |   reshards included), chunk device buffers drain into the
-        |   event log, and each simulated straggler sleep is attributed
-        |   to the slow worker (spec.attribute_delay) so the wall-
-        |   balance heatmap pins the fault on that worker's row
-        v
-        obs.TelemetrySpec ──> benchmarks/report.py --section heatmap
-
-``render_ledger_event`` / ``render_ledger`` are the one human-readable
-rendering of that ledger, shared by the examples and the run report.
+``render_ledger_event`` is the one human-readable rendering of a ledger
+event, shared by the examples.
 
 Nothing here re-implements solver math: snapshots capture exactly what the
 epoch driver threads between chunks, which is why resume can promise 0.0
@@ -95,7 +85,7 @@ drift instead of "close enough".
 from repro.runtime.health import (HealthError, HealthGuard, LedgerEvent,
                                   NaNInjector, WallClockMonitor, all_finite,
                                   ledger_counts, objective_regression,
-                                  render_ledger, render_ledger_event)
+                                  render_ledger_event)
 from repro.runtime.reshard import reshard, reshard_state, retile
 from repro.runtime.resume import check_resumable, resume, solve_kwargs
 from repro.runtime.snapshot import (DSOSnapshot, SnapshotIntegrityError,
@@ -112,7 +102,7 @@ __all__ = [
     "save_pytree", "save_snapshot", "verify_pytree",
     "HealthError", "HealthGuard", "LedgerEvent", "NaNInjector",
     "WallClockMonitor", "all_finite", "ledger_counts",
-    "objective_regression", "render_ledger", "render_ledger_event",
+    "objective_regression", "render_ledger_event",
     "check_resumable", "resume", "solve_kwargs",
     "reshard", "reshard_state", "retile",
     "FaultEvent", "Supervisor", "make_fault_plan", "periodic_crashes",

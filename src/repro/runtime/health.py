@@ -6,7 +6,7 @@ resharding).  This module is the half that survives faults nobody planned:
 * **Probes** — ``all_finite`` is the jitted numerical-health probe: one
   fused ``isfinite``-reduce over every leaf of a state pytree (the
   ``DSOState`` at a chunk boundary costs a few KB of reads, so the probe
-  is ~free next to an epoch — gated <= 2% in BENCH_dso.json).
+  is ~free next to an epoch).
   ``objective_regression`` is the host-side monitor over the evaluation
   history: an objective that climbs a ratio above its best-so-far (plus an
   absolute slack for noise around convergence) marks the trajectory
@@ -166,16 +166,6 @@ def render_ledger_event(ev) -> str:
     if detail:
         bits.append(" ".join(f"{k}={v}" for k, v in detail.items()))
     return " ".join(bits)
-
-
-def render_ledger(ledger, *, prefix: str = "  [ledger] ") -> str:
-    """A whole recovery ledger as one printable block (plus the kind
-    counts on the last line); empty ledgers render as 'no events'."""
-    if not ledger:
-        return f"{prefix}no events"
-    lines = [prefix + render_ledger_event(ev) for ev in ledger]
-    lines.append(f"{prefix}counts: {ledger_counts(ledger)}")
-    return "\n".join(lines)
 
 
 # ---------------------------------------------------------------- chaos --

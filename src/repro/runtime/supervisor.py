@@ -141,7 +141,7 @@ class Supervisor:
                  max_restores: int = 5, regression_ratio: float | None = None,
                  replan: bool = False, straggler_factor: float = 1.8,
                  straggler_patience: int = 1, lpt_relief: float = 0.5,
-                 reshard_to: int | None = None, obs=None, telemetry=None):
+                 reshard_to: int | None = None, obs=None):
         if checkpoint_every < 1:
             raise ValueError(
                 f"checkpoint_every must be >= 1, got {checkpoint_every}")
@@ -168,12 +168,6 @@ class Supervisor:
         # ledger event, snapshot/restore/reshard span, and per-chunk
         # throughput gauge lands in ONE ordered run-event stream
         self.obs = obs
-        # device-telemetry seam (duck-typed obs.TelemetrySpec, or None):
-        # threaded into every ShardedDSO built along the way — rebuilds
-        # and reshards included — so the drained per-(epoch, r, q) stream
-        # stays continuous across replans; simulated straggler sleeps are
-        # attributed to the slow worker so wall-balance shows the fault
-        self.telemetry = telemetry
         self.log: list = []
         self.history: list = []
         # recovery bookkeeping: which snapshot we last restored from and
@@ -436,8 +430,6 @@ class Supervisor:
             # every solver built along the way (rebuilds included, via
             # dso_kw) mirrors its eval metrics into the same recorder
             dso_kw.setdefault("obs", self.obs)
-        if self.telemetry is not None:
-            dso_kw.setdefault("telemetry", self.telemetry)
         opt = ShardedDSO(prob, mesh, **dso_kw)
         record_chunk = None
         if self.obs is not None:
@@ -478,14 +470,7 @@ class Supervisor:
             opt.run_epochs(n, self.eta0)
             opt.wait()
             if self._slow is not None and self.straggler_delay_s:
-                delay = self.straggler_delay_s * n * self._relief
-                if delay and self.telemetry is not None:
-                    # the simulated sleep is a host-side stand-in for the
-                    # slow worker's wall time: attribute it so the
-                    # wall-balance heatmap pins the fault on that row
-                    self.telemetry.attribute_delay(self._slow, delay,
-                                                   t0=t, epochs=n)
-                time.sleep(delay)
+                time.sleep(self.straggler_delay_s * n * self._relief)
             dt = time.perf_counter() - t0
             if record_chunk is not None:
                 record_chunk(n, dt, self.eta0)

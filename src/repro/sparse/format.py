@@ -277,8 +277,7 @@ class BucketedGridData(NamedTuple):
     statistics match the uniform layouts' exactly.
 
     The per-bucket rectangles are HOST-side numpy (inspection,
-    ``grid_to_csr``, and the legacy ``lax.switch`` backends, which upload
-    them on demand).  What lives on DEVICE is the *flat chunk view*: every
+    ``SparseTile`` access, ``grid_to_csr`` and the ref oracle).  What lives on DEVICE is the *flat chunk view*: every
     bucket width is a multiple of ``K_CHUNK``, so each tile is
     ``K_k // K_CHUNK`` consecutive (mb, K_CHUNK) chunks and the whole grid
     packs into ONE ragged buffer ``cols_fl``/``vals_fl`` of shape

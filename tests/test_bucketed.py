@@ -200,18 +200,18 @@ def _run_backend(prob, backend, p, schedule="cyclic", row_batches=1):
 
 
 def _assert_onekernel_identity(prob, p, schedule="cyclic", row_batches=1):
-    """The PR 8 trajectory gate: one-kernel Pallas == flat jnp BITWISE
-    (shared staged math), and both within 1e-5 of the legacy lax.switch
-    dispatch (same math at per-bucket widths — f32 reduction order may
-    differ)."""
+    """The trajectory gate: one-kernel Pallas == flat jnp BITWISE (shared
+    staged math), and both within 1e-5 of the uniform-K layout
+    (``sparse_jnp``: the same math at the grid's max width — f32
+    reduction order may differ)."""
     kw = dict(p=p, schedule=schedule, row_batches=row_batches)
     w_jnp, a_jnp = _run_backend(prob, "sparse_bucketed_jnp", **kw)
     w_pal, a_pal = _run_backend(prob, "sparse_bucketed_pallas", **kw)
     np.testing.assert_array_equal(w_pal, w_jnp)
     np.testing.assert_array_equal(a_pal, a_jnp)
-    w_sw, a_sw = _run_backend(prob, "sparse_bucketed_pallas_switch", **kw)
-    np.testing.assert_allclose(w_pal, w_sw, atol=1e-5)
-    np.testing.assert_allclose(a_pal, a_sw, atol=1e-5)
+    w_uni, a_uni = _run_backend(prob, "sparse_jnp", **kw)
+    np.testing.assert_allclose(w_pal, w_uni, atol=1e-5)
+    np.testing.assert_allclose(a_pal, a_uni, atol=1e-5)
 
 
 @pytest.mark.parametrize("loss,reg", LOSS_REG_PAIRS)

@@ -51,8 +51,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 ALL_BACKENDS = ("dense_jnp", "dense_pallas_fused", "dense_pallas_block",
                 "sparse_jnp", "sparse_pallas", "sparse_bucketed_jnp",
-                "sparse_bucketed_pallas", "sparse_bucketed_jnp_switch",
-                "sparse_bucketed_pallas_switch")
+                "sparse_bucketed_pallas")
 
 
 def _prob(m=64, d=40, density=0.2, seed=0, loss="hinge"):
@@ -109,21 +108,20 @@ def test_sharded_accepts_auto_with_density_threshold():
 
 
 @pytest.mark.parametrize("schedule", ["cyclic", "random"])
-def test_backend_schedule_equivalence_matrix(schedule):
-    """Every registered backend follows the same trajectory (<= 1e-5)
-    under every schedule — layouts and kernels only change the arithmetic
-    order, never the update sequence."""
+@pytest.mark.parametrize("name", ALL_BACKENDS[1:])
+def test_backend_schedule_equivalence_matrix(name, schedule):
+    """Every registered backend follows the reference backend's trajectory
+    (<= 1e-5) under every schedule — layouts and kernels only change the
+    arithmetic order, never the update sequence."""
     prob = _prob(m=64, d=48, density=0.2, seed=3)
-    ref = solve(prob, backend="dense_jnp", schedule=schedule, p=2,
-                epochs=2, eta0=0.5, row_batches=2, seed=5)
-    for name in ALL_BACKENDS[1:]:
-        res = solve(prob, backend=name, schedule=schedule, p=2, epochs=2,
-                    eta0=0.5, row_batches=2, seed=5)
-        np.testing.assert_allclose(np.asarray(res.w), np.asarray(ref.w),
-                                   atol=1e-5, err_msg=f"{name}/{schedule} w")
-        np.testing.assert_allclose(np.asarray(res.alpha),
-                                   np.asarray(ref.alpha), atol=1e-5,
-                                   err_msg=f"{name}/{schedule} alpha")
+    kw = dict(schedule=schedule, p=2, epochs=2, eta0=0.5, row_batches=2,
+              seed=5)
+    ref = solve(prob, backend="dense_jnp", **kw)
+    res = solve(prob, backend=name, **kw)
+    np.testing.assert_allclose(np.asarray(res.w), np.asarray(ref.w),
+                               atol=1e-5, err_msg=f"{name}/{schedule} w")
+    np.testing.assert_allclose(np.asarray(res.alpha), np.asarray(ref.alpha),
+                               atol=1e-5, err_msg=f"{name}/{schedule} alpha")
 
 
 def test_random_wrapper_matches_engine_stream():
@@ -331,158 +329,3 @@ def test_ragged_eval_chunk_warns_once_with_suggestion():
         run_dso_grid(prob, p=2, epochs=6, eta0=0.5, eval_every=3)
     assert not [w for w in rec if issubclass(w.category, RuntimeWarning)
                 and "eval_every" in str(w.message)]
-
-
-# ------------------------------------------------------- telemetry lane --
-
-
-class _DuckTelemetry:
-    """Minimal duck-typed ``telemetry=`` spec — the seam is duck-typed
-    like ``obs=``/``store=``, so the engine must accept anything with a
-    ``drain`` method (it never imports repro.obs)."""
-
-    def __init__(self):
-        self.chunks = []
-
-    def drain(self, buf, **kw):
-        self.chunks.append((np.asarray(buf), kw))
-
-
-@pytest.mark.parametrize("schedule", ["cyclic", "lpt"])
-@pytest.mark.parametrize("backend", ["dense_jnp", "sparse_bucketed_jnp"])
-def test_telemetry_trajectory_bit_identical(backend, schedule):
-    """telemetry= only observes: trajectories with the telemetry carry on
-    and off are BIT-identical (max|diff| = 0.0) — the lane's acceptance
-    contract.  The telemetry scan is a sibling jitted program; the
-    telemetry=None path compiles the same run_epochs as before."""
-    prob = _prob(m=48, d=32, density=0.3, seed=1)
-    kw = dict(backend=backend, schedule=schedule, p=4, epochs=5,
-              eval_every=2, eta0=0.5, seed=3)
-    off = solve(prob, **kw)
-    tel = _DuckTelemetry()
-    on = solve(prob, telemetry=tel, **kw)
-    assert float(np.abs(np.asarray(off.w) - np.asarray(on.w)).max()) == 0.0
-    assert float(np.abs(np.asarray(off.alpha)
-                        - np.asarray(on.alpha)).max()) == 0.0
-    # one drained (n, p, p, F) buffer per evaluation chunk (2 + 2 + 1)
-    assert [c[0].shape[0] for c in tel.chunks] == [2, 2, 1]
-    assert all(c[0].shape[1:] == (4, 4, 5) for c in tel.chunks)
-    assert [c[1]["t0"] for c in tel.chunks] == [0, 2, 4]
-
-
-def test_telemetry_requires_scan_epochs():
-    with pytest.raises(ValueError, match="telemetry"):
-        solve(_prob(), p=4, epochs=2, telemetry=_DuckTelemetry(),
-              scan_epochs=False)
-
-
-def test_telemetry_values_match_serial_oracle():
-    """Every drained (epoch, r, q) slot equals an eager serial-replay
-    recomputation: update norms to float tolerance, rows/nnz/nonfinite
-    exactly (they are static tile stats / finite probes)."""
-    from repro.engine.driver import TELEMETRY_FIELDS, run_epochs_telemetry
-
-    prob = _prob(m=24, d=16, density=0.4, seed=3)
-    p, n_epochs = 2, 2
-    data = make_grid_data(prob, p)
-    state = init_state_data(prob.loss_name, data)
-    perms = np.asarray(cyclic_perms(n_epochs, p))
-    lam, m_f, _, _, _, w_lo, w_hi = prob_meta(prob)
-    etas = jnp.full((n_epochs,), jnp.float32(0.5))
-    _, buf = run_epochs_telemetry(
-        as_tile_data(data), state, jnp.asarray(perms), etas, lam, m_f,
-        w_lo, w_hi, backend="dense_jnp", loss_name=prob.loss_name,
-        reg_name=prob.reg_name, use_adagrad=True, row_batches=1, p=p,
-        db=data.db)
-    buf = np.asarray(buf)
-    assert buf.shape == (n_epochs, p, p, len(TELEMETRY_FIELDS))
-
-    be = get_backend("dense_jnp")
-    meta = prob_meta(prob)
-    # the jitted driver donates the state buffers — rebuild the (pure,
-    # deterministic) initial state for the eager replay
-    state = init_state_data(prob.loss_name, data)
-    w_grid, gw_grid = state.w_grid, state.gw_grid
-    alpha, ga = state.alpha, state.ga
-    trn_all = np.asarray(data.tile_row_nnz_g)
-    for e in range(n_epochs):
-        for r in range(p):
-            # blocks and rows are disjoint across q within an inner
-            # iteration (Lemma 2), so in-place serial application is the
-            # parallel step
-            for q in range(p):
-                b = int(perms[e][r, q])
-                w_b, a_q, gw_b, ga_q = inner_iteration(
-                    be, meta, data.col_nnz, b, w_grid[b], gw_grid[b],
-                    alpha[q], ga[q], (data.Xg[q],), data.yg[q],
-                    data.row_nnz_g[q], data.tile_col_nnz_g[q],
-                    data.tile_row_nnz_g[q], jnp.float32(0.5), 1)
-                dw = float(np.linalg.norm(
-                    np.asarray(w_b) - np.asarray(w_grid[b])))
-                da = float(np.linalg.norm(
-                    np.asarray(a_q) - np.asarray(alpha[q])))
-                trn = trn_all[q, b]
-                slot = buf[e, r, q]
-                np.testing.assert_allclose(slot[0], dw, atol=1e-5,
-                                           rtol=1e-4, err_msg=(e, r, q))
-                np.testing.assert_allclose(slot[1], da, atol=1e-5,
-                                           rtol=1e-4, err_msg=(e, r, q))
-                assert slot[2] == float((trn > 0).sum()), (e, r, q)
-                assert slot[3] == float(trn.sum()), (e, r, q)
-                assert slot[4] == 0.0, (e, r, q)
-                w_grid = w_grid.at[b].set(w_b)
-                gw_grid = gw_grid.at[b].set(gw_b)
-                alpha = alpha.at[q].set(a_q)
-                ga = ga.at[q].set(ga_q)
-
-
-TELEMETRY_SHARD_SCRIPT = textwrap.dedent("""
-    import numpy as np
-    from repro.data.synthetic import make_classification
-    from repro.engine import solve
-    from repro.core.dso_dist import ShardedDSO
-
-    class Spec:
-        def __init__(self):
-            self.chunks = []
-        def drain(self, buf, **kw):
-            self.chunks.append((np.asarray(buf), kw))
-
-    prob = make_classification(m=48, d=96, density=0.2, loss='hinge',
-                               lam=1e-3, seed=0)
-    for schedule in ('cyclic', 'lpt'):
-        tg = Spec()
-        solve(prob, backend='sparse_bucketed_jnp', schedule=schedule, p=4,
-              epochs=3, eta0=0.5, seed=3, telemetry=tg)
-        ts = Spec()
-        opt = ShardedDSO(prob, impl='sparse_bucketed_jnp',
-                         schedule=schedule, seed=3, telemetry=ts)
-        opt.run_epochs(3, 0.5)
-        opt.wait()
-        g = np.concatenate([c[0] for c in tg.chunks])
-        s = np.concatenate([c[0] for c in ts.chunks])
-        assert g.shape == s.shape, (g.shape, s.shape)
-        # static stats + finite flags agree exactly; update norms to f32
-        # reassociation tolerance (grid-vs-sharded trajectories themselves
-        # only agree to ~1e-7)
-        assert np.array_equal(g[..., 2:], s[..., 2:]), schedule
-        assert np.abs(g[..., :2] - s[..., :2]).max() < 1e-6, schedule
-        trans_g = {c[1]['transport'] for c in tg.chunks}
-        trans_s = {c[1]['transport'] for c in ts.chunks}
-        assert trans_g == trans_s, (schedule, trans_g, trans_s)
-    print('TELEMETRY_MATCH')
-""")
-
-
-def test_telemetry_grid_matches_sharded():
-    """The sharded per-device telemetry buffers, stitched over the mesh,
-    agree with the grid driver's buffers slot by slot (subprocess with 4
-    host devices, like the other shard_map tests)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    out = subprocess.run([sys.executable, "-c", TELEMETRY_SHARD_SCRIPT],
-                         env=env, capture_output=True, text=True,
-                         timeout=600)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "TELEMETRY_MATCH" in out.stdout

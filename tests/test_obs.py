@@ -6,7 +6,7 @@ loop), and the pinned number of events one chunk records.
 The contract under test (obs/__init__.py): every ``obs=`` seam defaults to
 ``None`` and guards all instrumentation behind ``if obs is not None``;
 with a recorder attached, every metric sample, span, and ledger event
-lands in ONE ordered JSONL stream the run report can render.
+lands in ONE ordered JSONL stream.
 """
 
 import json
@@ -460,8 +460,6 @@ def test_metrics_off_is_true_noop(monkeypatch):
     # every span site (solve, solve_setup, the chunk's children, eval,
     # eval_gather, restore, snapshot_save) opens through _enter
     monkeypatch.setattr(drv, "_enter", boom)
-    # telemetry=None must likewise never compile/enter the telemetry scan
-    monkeypatch.setattr(drv, "run_epochs_telemetry", boom)
     prob = _prob()
     res = drv.solve(prob, epochs=3, p=4, eta0=0.5)
     assert len(res.history) == 3
@@ -469,19 +467,24 @@ def test_metrics_off_is_true_noop(monkeypatch):
     assert len(res.history) == 2
 
 
-def test_metrics_off_bit_identical(tmp_path):
-    """The recorder only observes: trajectories with obs on and off are
-    bit-identical."""
+@pytest.mark.parametrize("schedule", ["cyclic", "lpt"])
+@pytest.mark.parametrize("backend", ["dense_jnp", "sparse_jnp",
+                                     "sparse_pallas", "sparse_bucketed_jnp"])
+def test_traced_solve_bit_identical(tmp_path, backend, schedule):
+    """The recorder only observes: a solve with the recorder opened the
+    way the benchmark's traced runs open it (``jax_annotations=True``)
+    and one with ``obs=None`` give bit-identical w, alpha and history."""
     from repro.engine import solve
     prob = _prob()
-    kw = dict(epochs=6, p=4, eta0=0.5, eval_every=2, seed=0)
+    kw = dict(backend=backend, schedule=schedule, epochs=6, p=4, eta0=0.5,
+              eval_every=2, seed=0)
     r_off = solve(prob, **kw)
-    with RunRecorder(str(tmp_path / "run.jsonl")) as rec:
+    with RunRecorder(str(tmp_path / "run.jsonl"),
+                     jax_annotations=True) as rec:
         r_on = solve(prob, obs=rec, **kw)
     assert bool((np.asarray(r_off.w) == np.asarray(r_on.w)).all())
     assert bool((np.asarray(r_off.alpha) == np.asarray(r_on.alpha)).all())
-    assert [h["primal"] for h in r_off.history] == \
-        [h["primal"] for h in r_on.history]
+    assert r_off.history == r_on.history
 
 
 def test_recorder_overhead_amortized(tmp_path):
@@ -489,192 +492,24 @@ def test_recorder_overhead_amortized(tmp_path):
     one chunk emits: the four chunk spans (epoch_chunk, chunk_schedule,
     chunk_dispatch, chunk_wait), the five throughput samples (rows_per_s,
     nnz_per_s, packed_bytes_per_s, eta, epoch_s) and, at an evaluation,
-    eval and eval_gather — plus one telemetry event when the lane drains.
-    A solve adds meta, solve_setup and solve once.  Growth shows here in
-    review; the time it costs is a chip reading (PERF.md: traced solver
-    time per epoch against the parent's, budget 2%)."""
+    eval and eval_gather.  A solve adds meta, solve_setup and solve once.
+    Growth shows here in review; the time it costs is a chip reading
+    (PERF.md: traced solver time per epoch against the parent's, budget
+    2%)."""
     from repro.engine import solve
-    from repro.obs import TelemetrySpec
 
     prob = _prob()
     kw = dict(p=4, eta0=0.5, eval_every=2, seed=0,
               eval_hook=lambda t, w, alpha: {"epoch": t})
     for chunks in (1, 3):
-        for lane in (False, True):
-            rec = RunRecorder(str(tmp_path / "run.jsonl"))
-            solve(prob, epochs=2 * chunks, obs=rec,
-                  telemetry=TelemetrySpec(obs=rec) if lane else None, **kw)
-            rec.close()
-            kinds = {}
-            for e in rec.events:
-                kinds[e["type"]] = kinds.get(e["type"], 0) + 1
-            want = {"meta": 1, "span": 2 + 6 * chunks, "metric": 5 * chunks}
-            if lane:
-                want["telemetry"] = chunks
-            assert kinds == want, (chunks, lane)
-
-
-# ------------------------------------------------------------ run report --
-
-
-def test_run_report_renders_chaos_log(tmp_path):
-    from benchmarks.report import run_report
-    path = str(tmp_path / "run.jsonl")
-    rec = RunRecorder(path, meta=dict(run="unit"))
-    record = None
-    rec.metrics.counter("ingest.rows").inc(10)
-    with rec.span("epoch_chunk", epochs=2):
-        rec.metrics.gauge("rows_per_s").set(1e6)
-        rec.metrics.gauge("eval.primal").set(0.5)
-    rec.metrics.gauge("eval.primal").set(0.25)
-    rec.record_ledger(dict(kind="crash", epoch=2, action="restore",
-                           epochs_lost=1, retry=1))
-    rec.close()
-    del record
-    text = run_report(path)
-    assert "rows_per_s" in text and "1.00M" in text
-    assert "eval.primal: 0.5 -> 0.25" in text
-    assert "epoch_chunk" in text
-    assert "crash@2 restore" in text
-    assert "ingest.rows: 10" in text
-
-
-def test_report_cli_run_report(tmp_path):
-    import subprocess
-    path = str(tmp_path / "run.jsonl")
-    with RunRecorder(path) as rec:
-        rec.metrics.gauge("rows_per_s").set(42.0)
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.report", "--section",
-         "run-report", "--events", path],
-        capture_output=True, text=True, cwd=REPO,
-        env=dict(os.environ, PYTHONPATH=os.path.join(REPO, "src")))
-    assert out.returncode == 0, out.stderr
-    assert "Run report" in out.stdout and "rows_per_s" in out.stdout
-
-
-# ------------------------------------------------------- telemetry lane --
-
-
-def test_telemetry_fields_literal_sync():
-    """engine.driver carries its own literal copy of TELEMETRY_FIELDS so
-    the engine never imports repro.obs — the two tuples must stay
-    identical (this test is the sync contract)."""
-    from repro.engine import driver
-    from repro.obs import TELEMETRY_FIELDS
-    assert driver.TELEMETRY_FIELDS == TELEMETRY_FIELDS
-    assert TELEMETRY_FIELDS == ("dw_norm", "dalpha_norm", "rows", "nnz",
-                                "nonfinite")
-
-
-def test_comm_bytes_matrix_ring_and_allgather():
-    from repro.obs import comm_bytes_matrix
-    p, db = 4, 16
-    blk = 2 * 4 * db
-    perms = np.tile(np.arange(p), (2, p, 1))
-    ring = comm_bytes_matrix(perms, db, "ring")
-    assert ring.shape == (2, p, p)
-    assert (ring == blk).all()          # one ppermute per inner iteration
-    ag = comm_bytes_matrix(perms, db, "allgather")
-    # p payloads per fetch; the end-of-epoch restore folds into row p-1
-    assert (ag[:, : p - 1] == blk * p).all()
-    assert (ag[:, p - 1] == 2 * blk * p).all()
-    with pytest.raises(ValueError, match="transport"):
-        comm_bytes_matrix(perms, db, "smoke-signals")
-
-
-def test_comm_bytes_matrix_p2p_hand_case():
-    """p=2, epoch perm [[0,1],[1,0]]: the first route is the identity
-    (elided), the swap before r=1 moves both blocks, and the end-of-epoch
-    restore swaps them back into the last row -> [[0, 0], [2blk, 2blk]]."""
-    from repro.obs import comm_bytes_matrix
-    db = 8
-    blk = 2 * 4 * db
-    out = comm_bytes_matrix([[[0, 1], [1, 0]]], db, "p2p")
-    np.testing.assert_array_equal(
-        out, [[[0.0, 0.0], [2.0 * blk, 2.0 * blk]]])
-
-
-def test_telemetry_spec_drain_schema_and_validation(tmp_path):
-    from repro.obs import TelemetrySpec, iter_events
-    path = str(tmp_path / "ev.jsonl")
-    rec = RunRecorder(path)
-    tel = TelemetrySpec(obs=rec)
-    with pytest.raises(ValueError, match="telemetry buffer"):
-        tel.drain(np.zeros((2, 2, 2, 3)), t0=0, etas=[0.5, 0.5],
-                  perms=np.tile(np.arange(2), (2, 2, 1)), db=4,
-                  transport="ring")
-    buf = np.zeros((2, 2, 2, 5), np.float32)
-    buf[..., 3] = 7.0
-    buf[1, 0, 1, 4] = 1.0                    # one nonfinite probe fired
-    tel.drain(buf, t0=4, etas=[0.5, 0.25],
-              perms=np.tile(np.arange(2), (2, 2, 1)), db=4,
-              transport="ring", wall_s=0.125)
-    tel.attribute_delay(1, 0.75, t0=5, epochs=2)
-    rec.close()
-    assert tel.nonfinite_total() == 1
-    evs = [e for e in iter_events(path) if e.get("type") == "telemetry"]
-    kinds = [e["kind"] for e in evs]
-    assert kinds == ["chunk", "delay"]
-    chunk = evs[0]
-    assert chunk["t0"] == 4 and chunk["epochs"] == 2 and chunk["p"] == 2
-    assert chunk["transport"] == "ring" and chunk["nonfinite"] == 1
-    assert chunk["eta"] == [0.5, 0.25]
-    assert np.asarray(chunk["nnz"]).shape == (2, 2, 2)
-    assert np.asarray(chunk["comm_bytes"]).shape == (2, 2, 2)
-    want = {"type": "telemetry", "kind": "delay", "worker": 1,
-            "seconds": 0.75, "t0": 5, "epochs": 2}
-    assert {k: evs[1][k] for k in want} == want    # recorder adds seq/ts
-
-
-def _toy_spec(slow_worker=2, p=4):
-    """Two drained chunks with flat nnz plus one attributed straggler
-    delay inside the second chunk's epoch window."""
-    from repro.obs import TelemetrySpec
-    tel = TelemetrySpec()
-    perms = np.tile(np.arange(p), (2, p, 1))
-    for t0 in (0, 2):
-        buf = np.ones((2, p, p, 5), np.float32)
-        buf[..., 4] = 0.0
-        tel.drain(buf, t0=t0, etas=[0.5, 0.5], perms=perms, db=4,
-                  transport="ring", wall_s=0.4)
-    tel.attribute_delay(slow_worker, 3.0, t0=2, epochs=2)
-    tel.attribute_delay(slow_worker, 3.0, t0=99, epochs=1)  # out of range
-    return tel
-
-
-def test_wall_balance_pins_attributed_straggler():
-    from repro.obs import wall_balance
-    tel = _toy_spec(slow_worker=2)
-    mat, t0s = wall_balance(tel)
-    assert t0s == [0, 2] and mat.shape == (4, 2)
-    # flat nnz -> wall split evenly; the delay lands whole on worker 2's
-    # row for the chunk containing t0=2 only (the t0=99 record matches no
-    # chunk and is dropped)
-    np.testing.assert_allclose(mat[:, 0], 0.1)
-    np.testing.assert_allclose(mat[[0, 1, 3], 1], 0.1)
-    np.testing.assert_allclose(mat[2, 1], 0.1 + 3.0)
-    assert int(np.argmax(mat.sum(axis=1))) == 2
-
-
-def test_render_heatmap_from_event_generator(tmp_path):
-    """render_heatmap folds a one-shot iter_events generator into BOTH
-    matrices (throughput + wall balance) — the generator must be
-    normalized once, not consumed twice."""
-    from repro.obs import TelemetrySpec, iter_events, render_heatmap
-    path = str(tmp_path / "ev.jsonl")
-    src = _toy_spec(slow_worker=1)
-    with RunRecorder(path) as rec:
-        tel = TelemetrySpec(obs=rec)
-        for c in src.chunks:
-            tel.drain(c.buf, t0=c.t0, etas=c.etas,
-                      perms=np.tile(np.arange(c.p), (c.epochs, c.p, 1)),
-                      db=c.db, transport=c.transport, wall_s=c.wall_s)
-        tel.attribute_delay(1, 3.0, t0=2, epochs=2)
-    text = render_heatmap(iter_events(path))
-    assert "(no telemetry)" not in text
-    assert "nnz throughput" in text and "wall balance" in text
-    assert "argmax worker: 1" in text
+        rec = RunRecorder(str(tmp_path / "run.jsonl"))
+        solve(prob, epochs=2 * chunks, obs=rec, **kw)
+        rec.close()
+        kinds = {}
+        for e in rec.events:
+            kinds[e["type"]] = kinds.get(e["type"], 0) + 1
+        want = {"meta": 1, "span": 2 + 6 * chunks, "metric": 5 * chunks}
+        assert kinds == want, chunks
 
 
 def test_iter_events_is_lazy_and_tolerates_truncation(tmp_path):
@@ -712,30 +547,3 @@ def test_histogram_quantiles_exact_then_deterministic():
     snap.histogram("s").observe(2.0)
     entry = snap.snapshot()["s"]
     assert entry["p50"] == entry["p90"] == entry["p99"] == 2.0
-
-
-def test_history_ledger_and_trends_regression_flag(tmp_path):
-    """benchmarks history ledger round trip: two appended records where a
-    'higher is better' gate drops >20% must surface in --section trends
-    as a REGRESSION."""
-    from benchmarks.dso_perf import append_history
-    from benchmarks.report import trends_report
-    path = str(tmp_path / "history.jsonl")
-    old = {"dso_sparse": {"gate": {"traffic_ratio_dense_over_sparse": 6.0,
-                                   "threshold": 2.0, "pass": True}},
-           "obs_overhead": {"gate": {"obs_overhead_per_epoch": 0.001,
-                                     "pass": True}}}
-    new = {"dso_sparse": {"gate": {"traffic_ratio_dense_over_sparse": 4.0,
-                                   "threshold": 2.0, "pass": True}},
-           "obs_overhead": {"gate": {"obs_overhead_per_epoch": 0.0011,
-                                     "pass": True}}}
-    assert append_history(old, path=path)["gates"][
-        "dso_sparse"]["traffic_ratio_dense_over_sparse"] == 6.0
-    append_history(new, path=path)
-    text = trends_report(path)
-    assert "dso_sparse.traffic_ratio_dense_over_sparse" in text
-    assert "REGRESSION" in text
-    # thresholds are config, not measurements -> never trended
-    assert "dso_sparse.threshold" not in text
-    # a 10% drift on a 'lower' gate stays inside the 20% tolerance
-    assert text.count("REGRESSION") == 1

@@ -1,8 +1,4 @@
-"""Reporting layer: roofline report, dryrun table, collective parser."""
-
-import os
-
-import pytest
+"""Reporting layer: dryrun table, collective parser."""
 
 from repro.launch.dryrun import _group_size, _shape_bytes, parse_collectives
 
@@ -33,22 +29,3 @@ def test_parse_collectives_kinds_and_wire():
     b = 16 * 1024 * 4
     assert abs(out["all-reduce"]["wire_bytes"] - 2 * b * 3 / 4) < 1
     assert out["__top_ops__"][0]["kind"] == "all-reduce"
-
-
-ROOFLINE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks", "results", "roofline")
-
-
-@pytest.mark.skipif(not os.path.isdir(ROOFLINE_DIR),
-                    reason="roofline artifacts not generated")
-def test_roofline_report_renders():
-    import sys
-    sys.path.insert(0, os.path.dirname(ROOFLINE_DIR.rsplit("/results", 1)[0]))
-    from benchmarks.roofline import report
-    md = report()
-    lines = md.strip().split("\n")
-    assert len(lines) >= 3  # header + separator + at least one record
-    assert all(l.startswith("|") for l in lines)
-    # DSO tile-step schema: every record row names its dominant term
-    assert all(any(t in l for t in ("compute", "memory", "collective"))
-               for l in lines[2:])
